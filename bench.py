@@ -53,13 +53,6 @@ HIST = os.environ.get("BENCH_HIST", "0") == "1"
 # when nothing fails (same bar as telemetry). Neutral masks are
 # bitwise-identity, so the measured delta is pure mask arithmetic.
 FAULTS = os.environ.get("BENCH_FAULTS", "0") == "1"
-# BENCH_SECTIONS=0 skips the per-section profile rep appended to the
-# JSON output: by default one rep of each window-step section
-# (profiling.BENCH_SECTIONS) is timed AFTER the measured run, so every
-# BENCH_r*.json records WHERE the per-window budget went, not just the
-# headline events/s (tools/compare_runs.py --bench diffs two such
-# records section by section)
-SECTIONS = os.environ.get("BENCH_SECTIONS", "1") == "1"
 TELEMETRY_DIR = os.environ.get("BENCH_TELEMETRY_DIR", "telemetry-bench")
 HARVEST_EVERY = int(os.environ.get("BENCH_HARVEST_EVERY", "32"))
 EGRESS_CAP = int(os.environ.get("BENCH_EGRESS_CAP", "16"))
@@ -794,32 +787,9 @@ def _regression_guard(value: float, fingerprint: dict):
             "regressed": ratio < 0.8}
 
 
-def bench_sections(kernel: str) -> dict | None:
-    """One profiled rep of each window-step section at the bench shape
-    (outside the timed run): section name -> min ms. The same
-    measurement substrate as tools/profile_plane.py, at reps=1 — a
-    trend line for the BENCH_r*.json trajectory, not a benchmark."""
-    from shadow_tpu.tpu import profiling
-
-    rep = profiling.profile_sections(
-        N_HOSTS, reps=1, rr_enabled=False, kernel=kernel,
-        n_nodes=N_NODES, egress_cap=EGRESS_CAP, ingress_cap=INGRESS_CAP,
-        sections=profiling.BENCH_SECTIONS)
-    return {name: vals["min_ms"] for name, vals in rep["sections"].items()}
-
-
 def main():
     (tpu_rate, events, telemetry_info, kernel_info, capacity_info,
      driver_info) = bench_tpu()
-    # sections are recorded for the default XLA kernel only: a pallas
-    # run off-TPU would re-time every section in interpret mode (slow
-    # and not the trajectory being tracked)
-    sections = (bench_sections("xla")
-                if SECTIONS and kernel_info["used"] == "xla" else None)
-    if sections is not None:
-        # surface the chained-driver amortization next to the section
-        # times so compare_runs --bench diffs it like any other cost
-        sections["windows_per_sync"] = driver_info["windows_per_sync"]
     worlds_info = bench_tpu_worlds(tpu_rate) if N_WORLDS > 0 else None
     memo_info = bench_memo() if MEMO else None
     cpu_rate = bench_cpu_baseline()
@@ -844,7 +814,6 @@ def main():
                                 if compiled_rate else None),
                 "compiled_events_per_sec": round(compiled_rate, 1),
                 "hosts": N_HOSTS,
-                "sections": sections,
                 "prior_round": guard,
                 "baseline": (
                     "vs_baseline: this repo's Python object plane (64-host "

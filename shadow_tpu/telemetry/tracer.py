@@ -83,6 +83,43 @@ SPAN_MODES = ("execute", "replay", "ffwd", "ensemble")
 DRIVER_PID = 1_000_000
 
 
+class phase:
+    """One driver phase as a `jax.profiler` span, and as wall ms for the
+    run ledger when a `RunTracer` is given.
+
+        with phase("chain.launch", tracer, r0=r0, r1=r1) as p:
+            ...
+        p.ms  # elapsed wall ms (0.0 without a tracer)
+
+    The span (`jax.profiler.TraceAnnotation`, with `args` as its
+    arguments) is recorded only while a profiler trace runs, on the
+    clock the device trace shares; otherwise it costs one no-op host
+    call. `t0` is the tracer's clock at entry, so that a ledger span can
+    start where the profiler span starts. Reads no device value."""
+
+    __slots__ = ("_ann", "_tracer", "t0", "ms")
+
+    def __init__(self, name: str, tracer: "RunTracer | None" = None,
+                 **args):
+        import jax.profiler
+
+        self._ann = jax.profiler.TraceAnnotation(name, **args)
+        self._tracer = tracer
+        self.t0 = 0.0
+        self.ms = 0.0
+
+    def __enter__(self) -> "phase":
+        self._ann.__enter__()
+        if self._tracer is not None:
+            self.t0 = self._tracer.clock()
+        return self
+
+    def __exit__(self, *exc):
+        if self._tracer is not None:
+            self.ms = (self._tracer.clock() - self.t0) * 1e3
+        return self._ann.__exit__(*exc)
+
+
 def backend_fingerprint() -> dict:
     """The same (platform, device_kind) fingerprint bench.py stamps on
     its records — computed lazily so importing the tracer never pulls

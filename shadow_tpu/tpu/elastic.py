@@ -288,6 +288,17 @@ def drive_chained_windows(state, extras, chain_fn, *, n_rounds: int,
     presence-invisible: tracer-on and tracer-off runs are
     digest-identical (the trace-parity CI gate).
 
+    Profiler spans (docs/observability.md "Profiler spans and scopes"):
+    each chain span runs inside a `chain.span` profiler span, and its
+    phases inside `chain.memo` (snapshot, key, lookup, record),
+    `chain.launch` (the chain dispatch, with the overflow read-back
+    under ``policy``), `chain.upload`, `chain.hook` and
+    `chain.checkpoint`, each with ``r0``/``r1`` as arguments
+    (`telemetry/tracer.phase`). They cost nothing measurable when no
+    `jax.profiler` trace runs. The ledger's `dispatch_ms` / `memo_ms` /
+    `hook_ms` are the wall times of the same `chain.launch` /
+    `chain.memo` / `chain.hook` phases.
+
     ``checkpointer`` (a `faults/runstate.RunCheckpointer`,
     docs/robustness.md "Resumable runs") spills the FULL carry to an
     atomic file at its own cadence: its checkpoint instants join the
@@ -302,6 +313,8 @@ def drive_chained_windows(state, extras, chain_fn, *, n_rounds: int,
     """
     import jax.numpy as jnp
 
+    from ..telemetry.tracer import phase
+
     if memo is not None and per_round is not None and memo_span_salt is None:
         raise ValueError(
             "drive_chained_windows: memo with per_round inputs needs a "
@@ -314,113 +327,121 @@ def drive_chained_windows(state, extras, chain_fn, *, n_rounds: int,
     host_carry = None  # memo's host mirror of (state, extras)
     stale = False      # device carry behind host_carry (hits pending)
 
-    def _upload():
+    def _upload(r0, r1):
         nonlocal state, extras, stale
-        state, extras = memo.to_device(host_carry)
+        with phase("chain.upload", r0=r0, r1=r1):
+            state, extras = memo.to_device(host_carry)
         stale = False
 
-    def _maybe_checkpoint(r1):
+    def _maybe_checkpoint(r0, r1):
         # fires at the span end, after on_chain: the carry saved is
         # exactly the carry the next span starts from. The memo host
         # mirror, when authoritative, is saved as-is (no device sync).
         if checkpointer is None or not checkpointer.due(r1, n_rounds):
             return
         carry = host_carry if host_carry is not None else (state, extras)
-        checkpointer.save(r1, carry, host=host_carry is not None,
-                          tracer=tracer)
+        with phase("chain.checkpoint", r0=r0, r1=r1):
+            checkpointer.save(r1, carry, host=host_carry is not None,
+                              tracer=tracer)
 
     for r0, r1 in chain_spans(n_rounds, chain_len,
                               start_round=start_round,
                               boundaries=boundaries):
-        t0 = tracer.clock() if tracer is not None else 0.0
-        salt_hex = None
-        pre_walk = None
-        salt = b""
-        if memo_span_salt is not None \
-                and (memo is not None or tracer is not None):
-            salt = memo_span_salt(r0, r1)
-            if tracer is not None:
-                # the span's external-input fingerprint (the fault
-                # schedule's) — host bytes, hashed before the loop ran
-                salt_hex = salt.hex()
-        if memo is not None:
-            if host_carry is None:
-                host_carry = memo.snapshot(state, extras)
-            key, pre_walk = memo.key(host_carry, r0, r1, span_salt=salt)
-            entry = memo.lookup(key)
-            if entry is not None:
-                host_carry = memo.replay(entry, host_carry)
-                stale = True
-                mode, hook_ms = "ffwd", 0.0
-                if on_chain is not None:
-                    mode = "replay"
-                    _upload()
-                    th = tracer.clock() if tracer is not None else 0.0
-                    replaced = on_chain(r1, state, extras)
-                    if tracer is not None:
-                        hook_ms = (tracer.clock() - th) * 1e3
-                    if replaced is not None:
-                        state, extras = replaced
-                        host_carry = None  # device is authoritative
+        with phase("chain.span", tracer, r0=r0, r1=r1) as whole:
+            salt_hex = None
+            pre_walk = None
+            salt = b""
+            if memo_span_salt is not None \
+                    and (memo is not None or tracer is not None):
+                salt = memo_span_salt(r0, r1)
                 if tracer is not None:
-                    tracer.span(r0, r1, mode=mode, t0=t0,
-                                hook_ms=hook_ms, span_salt=salt_hex)
-                _maybe_checkpoint(r1)
-                continue
-            if stale:
-                _upload()
-        rids = jnp.arange(r0, r1, dtype=jnp.int32)
-        pr = per_round(r0, r1) if per_round is not None else None
-        growth = None
-        if policy is None:
-            state, extras, _eg, _in = chain_fn(state, extras, rids, pr)
-        else:
-            def attempt(st, _ex=extras, _rids=rids, _pr=pr):
-                st2, ex2, eg, inn = chain_fn(st, _ex, _rids, _pr)
-                return (st2, ex2), eg, inn
+                    # the span's external-input fingerprint (the fault
+                    # schedule's) — host bytes, hashed before the loop ran
+                    salt_hex = salt.hex()
+            memo_ms = 0.0
+            if memo is not None:
+                with phase("chain.memo", tracer, r0=r0, r1=r1) as pm:
+                    if host_carry is None:
+                        host_carry = memo.snapshot(state, extras)
+                    key, pre_walk = memo.key(host_carry, r0, r1,
+                                             span_salt=salt)
+                    entry = memo.lookup(key)
+                    if entry is not None:
+                        host_carry = memo.replay(entry, host_carry)
+                        stale = True
+                memo_ms = pm.ms
+                if entry is not None:
+                    mode, hook_ms = "ffwd", 0.0
+                    if on_chain is not None:
+                        mode = "replay"
+                        _upload(r0, r1)
+                        with phase("chain.hook", tracer, r0=r0,
+                                   r1=r1) as ph:
+                            replaced = on_chain(r1, state, extras)
+                        hook_ms = ph.ms
+                        if replaced is not None:
+                            state, extras = replaced
+                            host_carry = None  # device is authoritative
+                    if tracer is not None:
+                        tracer.span(r0, r1, mode=mode, t0=whole.t0,
+                                    memo_ms=memo_ms, hook_ms=hook_ms,
+                                    span_salt=salt_hex)
+                    _maybe_checkpoint(r0, r1)
+                    continue
+                if stale:
+                    _upload(r0, r1)
+            growth = None
+            with phase("chain.launch", tracer, r0=r0, r1=r1) as pl:
+                rids = jnp.arange(r0, r1, dtype=jnp.int32)
+                pr = per_round(r0, r1) if per_round is not None else None
+                if policy is None:
+                    state, extras, _eg, _in = chain_fn(state, extras, rids,
+                                                       pr)
+                else:
+                    def attempt(st, _ex=extras, _rids=rids, _pr=pr):
+                        st2, ex2, eg, inn = chain_fn(st, _ex, _rids, _pr)
+                        return (st2, ex2), eg, inn
 
-            n_events = len(policy.trajectory.events)
-            try:
-                out, _used = run_elastic_window(
-                    state, attempt, policy, time_ns=r0 * int(window_ns),
-                    host_names=host_names)
-            except CapacityError as e:
-                # under chained execution the overflow is observed per
-                # CHAIN, so the span is the precise blame unit — attach
-                # it here so every driver's error report names it
-                # without a side channel
-                e.chain_span = (r0, r1)
-                raise
-            state, extras = out
-            # the span's committed capacity decisions (growth / drop /
-            # exhaustion) — already-host trajectory dicts, by slice
-            growth = policy.trajectory.events[n_events:]
-        dispatch_ms = ((tracer.clock() - t0) * 1e3
-                       if tracer is not None else 0.0)
-        memo_ms = 0.0
-        if memo is not None:
-            tm = tracer.clock() if tracer is not None else 0.0
-            host_carry = memo.snapshot(state, extras)
-            memo.record(key, pre_walk, host_carry, span_len=r1 - r0)
+                    n_events = len(policy.trajectory.events)
+                    try:
+                        out, _used = run_elastic_window(
+                            state, attempt, policy,
+                            time_ns=r0 * int(window_ns),
+                            host_names=host_names)
+                    except CapacityError as e:
+                        # under chained execution the overflow is
+                        # observed per CHAIN, so the span is the precise
+                        # blame unit — attach it here so every driver's
+                        # error report names it without a side channel
+                        e.chain_span = (r0, r1)
+                        raise
+                    state, extras = out
+                    # the span's committed capacity decisions (growth /
+                    # drop / exhaustion) — already-host trajectory
+                    # dicts, by slice
+                    growth = policy.trajectory.events[n_events:]
+            if memo is not None:
+                with phase("chain.memo", tracer, r0=r0, r1=r1) as pm:
+                    host_carry = memo.snapshot(state, extras)
+                    memo.record(key, pre_walk, host_carry,
+                                span_len=r1 - r0)
+                memo_ms += pm.ms
+            hook_ms = 0.0
+            if on_chain is not None:
+                with phase("chain.hook", tracer, r0=r0, r1=r1) as ph:
+                    replaced = on_chain(r1, state, extras)
+                hook_ms = ph.ms
+                if replaced is not None:
+                    state, extras = replaced
+                    host_carry = None
             if tracer is not None:
-                memo_ms = (tracer.clock() - tm) * 1e3
-        hook_ms = 0.0
-        if on_chain is not None:
-            th = tracer.clock() if tracer is not None else 0.0
-            replaced = on_chain(r1, state, extras)
-            if tracer is not None:
-                hook_ms = (tracer.clock() - th) * 1e3
-            if replaced is not None:
-                state, extras = replaced
-                host_carry = None
-        if tracer is not None:
-            tracer.span(r0, r1, mode="execute", t0=t0,
-                        dispatch_ms=dispatch_ms, memo_ms=memo_ms,
-                        hook_ms=hook_ms, growth=growth,
-                        span_salt=salt_hex)
-        _maybe_checkpoint(r1)
+                tracer.span(r0, r1, mode="execute", t0=whole.t0,
+                            dispatch_ms=pl.ms, memo_ms=memo_ms,
+                            hook_ms=hook_ms, growth=growth,
+                            span_salt=salt_hex)
+            _maybe_checkpoint(r0, r1)
     if stale:
-        _upload()
+        _upload(r0, r1)
     return state, extras
 
 
@@ -489,7 +510,9 @@ def drive_ensemble(states, extras, chain_fn, *, n_rounds: int,
     the whole ensemble); returning a (states, extras) pair replaces
     the carried values, returning None keeps them. ``tracer`` records
     one ``mode="ensemble"`` run-ledger span per batched chain (same
-    zero-sync contract as :func:`drive_chained_windows`).
+    zero-sync contract as :func:`drive_chained_windows`), and each chain
+    opens the same `chain.span` / `chain.launch` / `chain.hook` /
+    `chain.checkpoint` profiler spans.
     ``checkpointer`` spills the batched per-world carries into ONE
     runstate file per cadence (docs/robustness.md "Resumable runs" —
     ensemble kill/resume parity is the solo theorem applied
@@ -497,6 +520,8 @@ def drive_ensemble(states, extras, chain_fn, *, n_rounds: int,
     """
     import jax
     import jax.numpy as jnp
+
+    from ..telemetry.tracer import phase
 
     # jit OUTSIDE the vmap: one compiled batched program per chain
     # length (the final partial chain retraces once), dispatched W
@@ -513,25 +538,24 @@ def drive_ensemble(states, extras, chain_fn, *, n_rounds: int,
     for r0, r1 in chain_spans(n_rounds, chain_len,
                               start_round=start_round,
                               boundaries=boundaries):
-        t0 = tracer.clock() if tracer is not None else 0.0
-        rids = jnp.arange(r0, r1, dtype=jnp.int32)
-        pr = per_round(r0, r1) if per_round is not None else None
-        states, extras, _eg, _in = vchain(states, extras, rids, pr)
-        dispatch_ms = ((tracer.clock() - t0) * 1e3
-                       if tracer is not None else 0.0)
-        hook_ms = 0.0
-        if on_chain is not None:
-            th = tracer.clock() if tracer is not None else 0.0
-            replaced = on_chain(r1, states, extras)
+        with phase("chain.span", tracer, r0=r0, r1=r1) as whole:
+            with phase("chain.launch", tracer, r0=r0, r1=r1) as pl:
+                rids = jnp.arange(r0, r1, dtype=jnp.int32)
+                pr = per_round(r0, r1) if per_round is not None else None
+                states, extras, _eg, _in = vchain(states, extras, rids, pr)
+            hook_ms = 0.0
+            if on_chain is not None:
+                with phase("chain.hook", tracer, r0=r0, r1=r1) as ph:
+                    replaced = on_chain(r1, states, extras)
+                hook_ms = ph.ms
+                if replaced is not None:
+                    states, extras = replaced
             if tracer is not None:
-                hook_ms = (tracer.clock() - th) * 1e3
-            if replaced is not None:
-                states, extras = replaced
-        if tracer is not None:
-            tracer.span(r0, r1, mode="ensemble", t0=t0,
-                        dispatch_ms=dispatch_ms, hook_ms=hook_ms)
-        if checkpointer is not None and checkpointer.due(r1, n_rounds):
-            checkpointer.save(r1, (states, extras), tracer=tracer)
+                tracer.span(r0, r1, mode="ensemble", t0=whole.t0,
+                            dispatch_ms=pl.ms, hook_ms=hook_ms)
+            if checkpointer is not None and checkpointer.due(r1, n_rounds):
+                with phase("chain.checkpoint", r0=r0, r1=r1):
+                    checkpointer.save(r1, (states, extras), tracer=tracer)
     return states, extras
 
 

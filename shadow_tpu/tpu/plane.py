@@ -833,121 +833,122 @@ def ingest_rows(state: NetPlaneState, dst: jax.Array, nbytes: jax.Array,
     hop for every sampled appended packet. Both are pure reads over
     values the merge already materialized and append to the return
     after metrics/guards: (state'[, metrics'][, guards'][, hist']
-    [, flightrec'])."""
-    N, CE = state.eg_dst.shape
-    if send_rel is None:
-        send_rel = jnp.zeros_like(seq)
-    if clamp_rel is None:
-        clamp_rel = jnp.full_like(seq, NO_CLAMP)
-    if sock is None:
-        sock = jnp.zeros_like(seq)
+    [, flightrec']). Its ops carry the named scope `ingest_rows`."""
+    with jax.named_scope("ingest_rows"):
+        N, CE = state.eg_dst.shape
+        if send_rel is None:
+            send_rel = jnp.zeros_like(seq)
+        if clamp_rel is None:
+            clamp_rel = jnp.full_like(seq, NO_CLAMP)
+        if sock is None:
+            sock = jnp.zeros_like(seq)
 
-    cat = lambda a, b: jnp.concatenate([a, b], axis=1)
+        cat = lambda a, b: jnp.concatenate([a, b], axis=1)
 
-    def merge(state: NetPlaneState) -> NetPlaneState:
-        valid_all = cat(state.eg_valid, valid)
-        W = valid_all.shape[1]
-        if packed_sort:
-            # stable valid-first order == sort by (validity, column rank);
-            # the rank rides in the key's low bits, so the single sorted
-            # array IS the permutation
-            rank = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32), (N, W))
-            key = jax.lax.sort(_pack_rank_key(valid_all, rank, W),
-                               dimension=1, is_stable=True)
-            perm = (key & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)[:, :CE]
-            take = lambda a, b: jnp.take_along_axis(cat(a, b), perm, axis=1)
-            dst_m = take(state.eg_dst, dst)
-            bytes_m = take(state.eg_bytes, nbytes)
-            prio_m = take(state.eg_prio, prio)
-            seq_m = take(state.eg_seq, seq)
-            ctrl_m = take(state.eg_ctrl, ctrl)
-            tsend_m = take(state.eg_tsend, send_rel)
-            clamp_m = take(state.eg_clamp, clamp_rel)
-            sock_m = take(state.eg_sock, sock)
-            valid_m = take(state.eg_valid, valid)
-            overflow = jnp.maximum(
-                valid_all.sum(axis=1, dtype=jnp.int32) - CE, 0)
-        else:
-            inv = (~valid_all).astype(jnp.int32)
-            # stable sort by validity alone: existing entries (columns
-            # < CE, front-packed) stay ahead of the new ones, new entries
-            # keep column order
-            (_, dst_f, bytes_f, prio_f, seq_f, ctrl_f, tsend_f, clamp_f,
-             # shadowlint: disable=SL403 -- pre-diet variadic reference
-             sock_f, valid_f) = _row_sort(
-                inv, cat(state.eg_dst, dst), cat(state.eg_bytes, nbytes),
-                cat(state.eg_prio, prio), cat(state.eg_seq, seq),
-                cat(state.eg_ctrl, ctrl), cat(state.eg_tsend, send_rel),
-                cat(state.eg_clamp, clamp_rel), cat(state.eg_sock, sock),
-                valid_all, keys=1,
+        def merge(state: NetPlaneState) -> NetPlaneState:
+            valid_all = cat(state.eg_valid, valid)
+            W = valid_all.shape[1]
+            if packed_sort:
+                # stable valid-first order == sort by (validity, column rank);
+                # the rank rides in the key's low bits, so the single sorted
+                # array IS the permutation
+                rank = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32), (N, W))
+                key = jax.lax.sort(_pack_rank_key(valid_all, rank, W),
+                                   dimension=1, is_stable=True)
+                perm = (key & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)[:, :CE]
+                take = lambda a, b: jnp.take_along_axis(cat(a, b), perm, axis=1)
+                dst_m = take(state.eg_dst, dst)
+                bytes_m = take(state.eg_bytes, nbytes)
+                prio_m = take(state.eg_prio, prio)
+                seq_m = take(state.eg_seq, seq)
+                ctrl_m = take(state.eg_ctrl, ctrl)
+                tsend_m = take(state.eg_tsend, send_rel)
+                clamp_m = take(state.eg_clamp, clamp_rel)
+                sock_m = take(state.eg_sock, sock)
+                valid_m = take(state.eg_valid, valid)
+                overflow = jnp.maximum(
+                    valid_all.sum(axis=1, dtype=jnp.int32) - CE, 0)
+            else:
+                inv = (~valid_all).astype(jnp.int32)
+                # stable sort by validity alone: existing entries (columns
+                # < CE, front-packed) stay ahead of the new ones, new entries
+                # keep column order
+                (_, dst_f, bytes_f, prio_f, seq_f, ctrl_f, tsend_f, clamp_f,
+                 # shadowlint: disable=SL403 -- pre-diet variadic reference
+                 sock_f, valid_f) = _row_sort(
+                    inv, cat(state.eg_dst, dst), cat(state.eg_bytes, nbytes),
+                    cat(state.eg_prio, prio), cat(state.eg_seq, seq),
+                    cat(state.eg_ctrl, ctrl), cat(state.eg_tsend, send_rel),
+                    cat(state.eg_clamp, clamp_rel), cat(state.eg_sock, sock),
+                    valid_all, keys=1,
+                )
+                overflow = jnp.maximum(
+                    valid_f.sum(axis=1, dtype=jnp.int32) - CE, 0)
+                dst_m, bytes_m, prio_m = dst_f[:, :CE], bytes_f[:, :CE], \
+                    prio_f[:, :CE]
+                seq_m, ctrl_m, tsend_m = seq_f[:, :CE], ctrl_f[:, :CE], \
+                    tsend_f[:, :CE]
+                clamp_m, sock_m, valid_m = clamp_f[:, :CE], sock_f[:, :CE], \
+                    valid_f[:, :CE]
+            return state._replace(
+                eg_dst=dst_m, eg_bytes=bytes_m, eg_prio=prio_m, eg_seq=seq_m,
+                eg_ctrl=ctrl_m, eg_tsend=tsend_m, eg_clamp=clamp_m,
+                eg_sock=sock_m, eg_valid=valid_m,
+                n_overflow_dropped=state.n_overflow_dropped + overflow,
             )
-            overflow = jnp.maximum(
-                valid_f.sum(axis=1, dtype=jnp.int32) - CE, 0)
-            dst_m, bytes_m, prio_m = dst_f[:, :CE], bytes_f[:, :CE], \
-                prio_f[:, :CE]
-            seq_m, ctrl_m, tsend_m = seq_f[:, :CE], ctrl_f[:, :CE], \
-                tsend_f[:, :CE]
-            clamp_m, sock_m, valid_m = clamp_f[:, :CE], sock_f[:, :CE], \
-                valid_f[:, :CE]
-        return state._replace(
-            eg_dst=dst_m, eg_bytes=bytes_m, eg_prio=prio_m, eg_seq=seq_m,
-            eg_ctrl=ctrl_m, eg_tsend=tsend_m, eg_clamp=clamp_m,
-            eg_sock=sock_m, eg_valid=valid_m,
-            n_overflow_dropped=state.n_overflow_dropped + overflow,
-        )
 
-    if not gate_idle:
-        new_state = merge(state)
-    else:
-        new_state = jax.lax.cond(valid.any(), merge, lambda st: st, state)
-    overflow_delta = new_state.n_overflow_dropped - state.n_overflow_dropped
-    if guards is not None:
-        guards = guards_plane.check_ingest(
-            guards,
-            occ_before=state.eg_valid.sum(axis=1, dtype=jnp.int32),
-            occ_after=new_state.eg_valid.sum(axis=1, dtype=jnp.int32),
-            incoming=valid.sum(axis=1, dtype=jnp.int32),
-            overflow=overflow_delta)
-    out = (new_state,)
-    if metrics is not None:
-        # overflow delta via the state counter: identical through both
-        # gate branches (the idle branch's delta is zero by construction)
-        out += (metrics._replace(
-            drop_ring_full=metrics.drop_ring_full + overflow_delta),)
-    if guards is not None:
-        out += (guards,)
-    if hist is not None:
-        # queue-depth sample at the append point (post-merge egress
-        # occupancy) — pure read, nothing feeds back
-        out += (hist._replace(hist_qdepth=histo.accum_depth(
-            hist.hist_qdepth,
-            new_state.eg_valid.sum(axis=1, dtype=jnp.int32))),)
-    if flightrec is not None:
-        # `ingest` hop per sampled ACCEPTED packet, stamped with the
-        # UPCOMING window's counter (appends ride between windows) and
-        # the emission offset relative to its start. Overflow-dropped
-        # batch entries never entered the ring, so they record no hop
-        # — their loss is the aggregate drop_ring_full counter, and a
-        # phantom `ingest` would read as "queued" to a trace reader.
-        # Accepted = the first (CE - occupancy) valid entries per row,
-        # exactly the prefix the merge keeps (new entries append after
-        # the existing ones in column order).
-        rows = jnp.broadcast_to(
-            jnp.arange(N, dtype=jnp.int32)[:, None], valid.shape)
-        samp = flightrec_mod.sample_mask(flightrec, rows, seq)
-        valid_i = valid.astype(jnp.int32)
-        new_rank = jnp.cumsum(valid_i, axis=1) - valid_i
-        free = (jnp.int32(CE)
-                - state.eg_valid.sum(axis=1, dtype=jnp.int32))
-        accepted = valid & (new_rank < free[:, None])
-        flat = lambda a: a.reshape(-1)
-        flightrec = flightrec_mod.record_events(
-            flightrec,
-            jnp.full((valid.size,), flightrec_mod.HOP_INGEST, jnp.int32),
-            flat(rows), flat(seq), flat(dst), flat(send_rel),
-            flat(accepted & samp))
-        out += (flightrec,)
-    return out if len(out) > 1 else new_state
+        if not gate_idle:
+            new_state = merge(state)
+        else:
+            new_state = jax.lax.cond(valid.any(), merge, lambda st: st, state)
+        overflow_delta = new_state.n_overflow_dropped - state.n_overflow_dropped
+        if guards is not None:
+            guards = guards_plane.check_ingest(
+                guards,
+                occ_before=state.eg_valid.sum(axis=1, dtype=jnp.int32),
+                occ_after=new_state.eg_valid.sum(axis=1, dtype=jnp.int32),
+                incoming=valid.sum(axis=1, dtype=jnp.int32),
+                overflow=overflow_delta)
+        out = (new_state,)
+        if metrics is not None:
+            # overflow delta via the state counter: identical through both
+            # gate branches (the idle branch's delta is zero by construction)
+            out += (metrics._replace(
+                drop_ring_full=metrics.drop_ring_full + overflow_delta),)
+        if guards is not None:
+            out += (guards,)
+        if hist is not None:
+            # queue-depth sample at the append point (post-merge egress
+            # occupancy) — pure read, nothing feeds back
+            out += (hist._replace(hist_qdepth=histo.accum_depth(
+                hist.hist_qdepth,
+                new_state.eg_valid.sum(axis=1, dtype=jnp.int32))),)
+        if flightrec is not None:
+            # `ingest` hop per sampled ACCEPTED packet, stamped with the
+            # UPCOMING window's counter (appends ride between windows) and
+            # the emission offset relative to its start. Overflow-dropped
+            # batch entries never entered the ring, so they record no hop
+            # — their loss is the aggregate drop_ring_full counter, and a
+            # phantom `ingest` would read as "queued" to a trace reader.
+            # Accepted = the first (CE - occupancy) valid entries per row,
+            # exactly the prefix the merge keeps (new entries append after
+            # the existing ones in column order).
+            rows = jnp.broadcast_to(
+                jnp.arange(N, dtype=jnp.int32)[:, None], valid.shape)
+            samp = flightrec_mod.sample_mask(flightrec, rows, seq)
+            valid_i = valid.astype(jnp.int32)
+            new_rank = jnp.cumsum(valid_i, axis=1) - valid_i
+            free = (jnp.int32(CE)
+                    - state.eg_valid.sum(axis=1, dtype=jnp.int32))
+            accepted = valid & (new_rank < free[:, None])
+            flat = lambda a: a.reshape(-1)
+            flightrec = flightrec_mod.record_events(
+                flightrec,
+                jnp.full((valid.size,), flightrec_mod.HOP_INGEST, jnp.int32),
+                flat(rows), flat(seq), flat(dst), flat(send_rel),
+                flat(accepted & samp))
+            out += (flightrec,)
+        return out if len(out) > 1 else new_state
 
 
 # ---------------------------------------------------------------------------
@@ -1433,27 +1434,32 @@ def _route_scatter(sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
                 "reference")
         from . import pallas_route
 
-        return pallas_route.route_place(
-            sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
-            in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
-            in_valid_c, n_valid_in)
+        with jax.named_scope("routing_place"):
+            return pallas_route.route_place(
+                sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
+                in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
+                in_valid_c, n_valid_in)
     CI = in_src_c.shape[1]
     if packed_sort:
-        row_perm, o_pos, offsets, take_n, overflowed = _routing_rank(
-            sent, eg_dst, eg_seq, deliver_rel, n_valid_in, CI)
-        merged = _routing_place(
-            row_perm, o_pos, offsets, take_n, n_valid_in, eg_seq,
-            eg_bytes, eg_sock, deliver_rel, in_deliver_c, in_src_c,
-            in_seq_c, in_sock_c, in_bytes_c, in_valid_c)
+        with jax.named_scope("routing_rank"):
+            row_perm, o_pos, offsets, take_n, overflowed = _routing_rank(
+                sent, eg_dst, eg_seq, deliver_rel, n_valid_in, CI)
+        with jax.named_scope("routing_place"):
+            merged = _routing_place(
+                row_perm, o_pos, offsets, take_n, n_valid_in, eg_seq,
+                eg_bytes, eg_sock, deliver_rel, in_deliver_c, in_src_c,
+                in_seq_c, in_sock_c, in_bytes_c, in_valid_c)
         return (*merged, overflowed)
-    (flat_idx, ok, o_deliver, o_src, o_seq, o_bytes, o_sock,
-     overflowed) = _routing_rank_legacy(
-        sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel, n_valid_in,
-        CI)
-    merged = _routing_place_legacy(
-        flat_idx, ok, o_deliver, o_src, o_seq, o_bytes, o_sock,
-        in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
-        in_valid_c)
+    with jax.named_scope("routing_rank"):
+        (flat_idx, ok, o_deliver, o_src, o_seq, o_bytes, o_sock,
+         overflowed) = _routing_rank_legacy(
+            sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
+            n_valid_in, CI)
+    with jax.named_scope("routing_place"):
+        merged = _routing_place_legacy(
+            flat_idx, ok, o_deliver, o_src, o_seq, o_bytes, o_sock,
+            in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
+            in_valid_c)
     return (*merged, overflowed)
 
 
@@ -1760,13 +1766,20 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
             "fallback in faults/healing.py does this automatically)")
     N, CE = state.eg_dst.shape
 
+    # Each numbered section runs under a `jax.named_scope`, named after
+    # `tpu/profiling.py`'s section where one exists. The scope rides the
+    # ops' HLO metadata (`op_name`) into a profiler trace, where
+    # `benchmark/scopes.py` adds up device time per section; it adds
+    # no equation and no op.
+
     # --- 1. rebase clocks + refill token buckets -----------------------
-    in_deliver = jnp.where(state.in_valid, state.in_deliver_rel - shift_ns,
-                           I32_MAX)
-    balance, tb_rem_ns = _refill_tokens(state, params, shift_ns,
-                                        faults=faults)
-    rt = codel.rebase_router_state(state.router, shift_ns, params.dn_rate,
-                                   params.dn_cap)
+    with jax.named_scope("rebase_refill"):
+        in_deliver = jnp.where(state.in_valid, state.in_deliver_rel - shift_ns,
+                               I32_MAX)
+        balance, tb_rem_ns = _refill_tokens(state, params, shift_ns,
+                                            faults=faults)
+        rt = codel.rebase_router_state(state.router, shift_ns, params.dn_rate,
+                                       params.dn_cap)
 
     # --- 2. egress: qdisc order, token-bucket gate ----------------------
     # Two qdiscs (`network_interface.c:205-303`, `QDiscMode`): FIFO sends
@@ -1776,86 +1789,84 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
     # Send times / clamps of leftover packets were taken relative to the
     # window they were ingested in; rebase them too.
     row_perm_fused = None
-    if kernel == "pallas_fused":
-        from . import pallas_pipeline
+    with jax.named_scope("egress_order"):
+        if kernel == "pallas_fused":
+            from . import pallas_pipeline
 
-        (eg_prio, eg_sock, eg_dst, eg_bytes, eg_seq, eg_ctrl, eg_tsend,
-         eg_clamp, eg_valid, sendable, spent,
-         row_perm_fused) = pallas_pipeline.egress_rank_stage(
-            state.eg_valid, state.eg_prio, state.eg_bytes,
-            state.eg_tsend, state.eg_clamp, state.eg_dst, state.eg_seq,
-            state.eg_sock, state.eg_ctrl, balance, shift_ns)
-        balance = balance - spent
-        rr_sent = state.rr_sent
-    elif kernel == "pallas":
-        from . import pallas_egress
+            (eg_prio, eg_sock, eg_dst, eg_bytes, eg_seq, eg_ctrl, eg_tsend,
+             eg_clamp, eg_valid, sendable, spent,
+             row_perm_fused) = pallas_pipeline.egress_rank_stage(
+                state.eg_valid, state.eg_prio, state.eg_bytes,
+                state.eg_tsend, state.eg_clamp, state.eg_dst, state.eg_seq,
+                state.eg_sock, state.eg_ctrl, balance, shift_ns)
+            balance = balance - spent
+            rr_sent = state.rr_sent
+        elif kernel == "pallas":
+            from . import pallas_egress
 
-        (perm, eg_bytes, eg_tsend, eg_clamp, eg_valid,
-         sendable, spent) = pallas_egress.egress_order_gate(
-            state.eg_valid, state.eg_prio, state.eg_bytes, state.eg_tsend,
-            state.eg_clamp, balance, shift_ns)
-        take = lambda a: jnp.take_along_axis(a, perm, axis=1)
-        eg_prio, eg_sock, eg_dst = (take(state.eg_prio),
-                                    take(state.eg_sock),
-                                    take(state.eg_dst))
-        eg_seq, eg_ctrl = take(state.eg_seq), take(state.eg_ctrl)
-        balance = balance - spent
-        rr_sent = state.rr_sent
-    else:
-        eg_tsend_rb = jnp.where(state.eg_valid, state.eg_tsend - shift_ns, 0)
-        eg_clamp_rb = jnp.where(
-            state.eg_valid & (state.eg_clamp != NO_CLAMP),
-            state.eg_clamp - shift_ns, state.eg_clamp,
-        )
-        qkey1, qkey2, rr_aux = _qdisc_keys(state, params,
-                                           rr_enabled=rr_enabled)
-        (eg_prio, eg_sock, eg_dst, eg_bytes, eg_seq, eg_ctrl, eg_tsend,
-         eg_clamp, eg_valid) = _egress_order(
-            state, qkey1, qkey2, eg_tsend_rb, eg_clamp_rb,
-            rr_enabled=rr_enabled, packed_sort=packed_sort)
-        if faults is not None:
-            # 2f. a crashed / link-down host transmits nothing: its queued
-            # egress drops HERE, before the token gate (dead hosts spend
-            # no bandwidth), counted once per slot — the slots leave the
-            # queue, so a multi-window outage never double-counts
-            up_src = (faults.host_alive & faults.link_up)[:, None]
-            fault_purged = eg_valid & ~up_src
-            eg_valid = eg_valid & up_src
-        sendable, balance = _token_gate(eg_valid, eg_bytes, balance)
-        rr_sent = (_rr_advance(eg_sock, eg_valid, sendable, rr_aux)
-                   if rr_enabled else state.rr_sent)
+            (perm, eg_bytes, eg_tsend, eg_clamp, eg_valid,
+             sendable, spent) = pallas_egress.egress_order_gate(
+                state.eg_valid, state.eg_prio, state.eg_bytes, state.eg_tsend,
+                state.eg_clamp, balance, shift_ns)
+            take = lambda a: jnp.take_along_axis(a, perm, axis=1)
+            eg_prio, eg_sock, eg_dst = (take(state.eg_prio),
+                                        take(state.eg_sock),
+                                        take(state.eg_dst))
+            eg_seq, eg_ctrl = take(state.eg_seq), take(state.eg_ctrl)
+            balance = balance - spent
+            rr_sent = state.rr_sent
+        else:
+            eg_tsend_rb = jnp.where(state.eg_valid, state.eg_tsend - shift_ns, 0)
+            eg_clamp_rb = jnp.where(
+                state.eg_valid & (state.eg_clamp != NO_CLAMP),
+                state.eg_clamp - shift_ns, state.eg_clamp,
+            )
+            qkey1, qkey2, rr_aux = _qdisc_keys(state, params,
+                                               rr_enabled=rr_enabled)
+            (eg_prio, eg_sock, eg_dst, eg_bytes, eg_seq, eg_ctrl, eg_tsend,
+             eg_clamp, eg_valid) = _egress_order(
+                state, qkey1, qkey2, eg_tsend_rb, eg_clamp_rb,
+                rr_enabled=rr_enabled, packed_sort=packed_sort)
+            if faults is not None:
+                # 2f. a crashed / link-down host transmits nothing: its queued
+                # egress drops HERE, before the token gate (dead hosts spend
+                # no bandwidth), counted once per slot — the slots leave the
+                # queue, so a multi-window outage never double-counts
+                up_src = (faults.host_alive & faults.link_up)[:, None]
+                fault_purged = eg_valid & ~up_src
+                eg_valid = eg_valid & up_src
+            sendable, balance = _token_gate(eg_valid, eg_bytes, balance)
+            rr_sent = (_rr_advance(eg_sock, eg_valid, sendable, rr_aux)
+                       if rr_enabled else state.rr_sent)
 
     # --- 3. loss sampling + latency lookup ------------------------------
-    if faults is not None:
-        sent, lost, corrupt, rng_counter, deliver_rel = _loss_latency(
-            state, params, rng_root, eg_dst, eg_ctrl, eg_tsend, eg_clamp,
-            sendable, window_ns, no_loss=no_loss, faults=faults)
-        # 3f. routing toward a crashed / link-down destination drops (the
-        # fault withdraws the route); packets already in the dst's ingress
-        # ring are untouched — the crash does not reach into the wire
-        up = faults.host_alive & faults.link_up
-        dst_ok = up[jnp.clip(eg_dst, 0, N - 1)] & (eg_dst >= 0) \
-            & (eg_dst < N)
-        blocked_dst = sent & ~dst_ok & (eg_dst >= 0) & (eg_dst < N)
-        sent = sent & dst_ok
-        # per-host fault-drop attribution: purge + corruption to the
-        # SOURCE (its packets died on its own NIC), routing blocks to
-        # the DESTINATION (the crash that ate them is the dst's)
-        fault_drops = (
-            fault_purged.sum(axis=1, dtype=jnp.int32)
-            + corrupt.sum(axis=1, dtype=jnp.int32)
-            + jnp.zeros((N,), jnp.int32).at[
-                jnp.clip(eg_dst, 0, N - 1).reshape(-1)].add(
-                blocked_dst.reshape(-1), mode="drop")
-        )
-    else:
-        sent, lost, rng_counter, deliver_rel = _loss_latency(
-            state, params, rng_root, eg_dst, eg_ctrl, eg_tsend, eg_clamp,
-            sendable, window_ns, no_loss=no_loss)
-
-    # egress queue keeps only what didn't go out (compacted after routing,
-    # which still indexes this ordering)
-    eg_valid_left = eg_valid & ~sendable
+    with jax.named_scope("loss_latency"):
+        if faults is not None:
+            sent, lost, corrupt, rng_counter, deliver_rel = _loss_latency(
+                state, params, rng_root, eg_dst, eg_ctrl, eg_tsend, eg_clamp,
+                sendable, window_ns, no_loss=no_loss, faults=faults)
+            # 3f. routing toward a crashed / link-down destination drops (the
+            # fault withdraws the route); packets already in the dst's ingress
+            # ring are untouched — the crash does not reach into the wire
+            up = faults.host_alive & faults.link_up
+            dst_ok = up[jnp.clip(eg_dst, 0, N - 1)] & (eg_dst >= 0) \
+                & (eg_dst < N)
+            blocked_dst = sent & ~dst_ok & (eg_dst >= 0) & (eg_dst < N)
+            sent = sent & dst_ok
+            # per-host fault-drop attribution: purge + corruption to the
+            # SOURCE (its packets died on its own NIC), routing blocks to
+            # the DESTINATION (the crash that ate them is the dst's)
+            fault_drops = (
+                fault_purged.sum(axis=1, dtype=jnp.int32)
+                + corrupt.sum(axis=1, dtype=jnp.int32)
+                + jnp.zeros((N,), jnp.int32).at[
+                    jnp.clip(eg_dst, 0, N - 1).reshape(-1)].add(
+                    blocked_dst.reshape(-1), mode="drop")
+            )
+        else:
+            sent, lost, rng_counter, deliver_rel = _loss_latency(
+                state, params, rng_root, eg_dst, eg_ctrl, eg_tsend, eg_clamp,
+                sendable, window_ns, no_loss=no_loss)
 
     # --- 4 + 5. compact surviving ingress, then route sent packets into
     # destination ingress queues. Routing happens BEFORE the due check
@@ -1863,17 +1874,19 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
     # (integrated transport: sent last round, clamped to this window's
     # start) is released THIS round, matching the CPU plane's
     # push-then-execute ordering.
-    (in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
-     in_valid_c, n_valid_in) = _compact_ingress(
-        state, in_deliver, packed_sort=packed_sort)
+    with jax.named_scope("ingress_compact"):
+        (in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
+         in_valid_c, n_valid_in) = _compact_ingress(
+            state, in_deliver, packed_sort=packed_sort)
     if kernel == "pallas_fused":
         from . import pallas_route
 
-        (in_src_m, in_seq_m, in_sock_m, in_bytes_m, in_deliver_m,
-         in_valid_m, overflowed) = pallas_route.route_place(
-            sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
-            in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
-            in_valid_c, n_valid_in, row_perm_fused)
+        with jax.named_scope("routing_place"):
+            (in_src_m, in_seq_m, in_sock_m, in_bytes_m, in_deliver_m,
+             in_valid_m, overflowed) = pallas_route.route_place(
+                sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
+                in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
+                in_valid_c, n_valid_in, row_perm_fused)
     else:
         (in_src_m, in_seq_m, in_sock_m, in_bytes_m, in_deliver_m,
          in_valid_m, overflowed) = _route_scatter(
@@ -1885,171 +1898,181 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
 
     # --- 5b. destination side: release what this window hands the hosts --
     if router_aqm:
-        # Full inbound pipeline: stored times are router-arrival times.
-        # FIFO order at the router = (arrival, src, seq), the same order
-        # the CPU plane's event queue feeds route_incoming_packet.
-        inv_m = (~in_valid_m).astype(jnp.int32)
-        arr_key = jnp.where(in_valid_m, in_deliver_m, I32_MAX)
-        (_, arr_s, src_s2, seq_s2, sock_s2, bytes_s2, valid_s2) = _row_sort(
-            inv_m, arr_key, in_src_m, in_seq_m, in_sock_m, in_bytes_m,
-            in_valid_m, keys=4,
-        )
-        rt2, rstatus, r_dt, co_mask, co_t, c_idx = codel.router_drain(
-            arr_s, bytes_s2, window_ns, params.dn_rate, params.dn_cap, rt,
-        )
-        # a row entry cached at window end leaves the queue: its identity
-        # moves into the router scalars until the relay resumes
-        new_cached = c_idx >= 0
-        ci = jnp.clip(c_idx, 0, CI - 1)[:, None]
-        take = lambda a: jnp.take_along_axis(a, ci, axis=1)[:, 0]
-        rt2 = rt2._replace(
-            cached_src=jnp.where(new_cached, take(src_s2), rt.cached_src),
-            cached_seq=jnp.where(new_cached, take(seq_s2), rt.cached_seq),
-            cached_sock=jnp.where(new_cached, take(sock_s2), rt.cached_sock),
-        )
-        # delivered = forwarded row entries + (maybe) the prior window's
-        # relay-cached packet, presented in (deliver_t, src, seq) order
-        fwd_rows = rstatus == codel.STATUS_DELIVERED
-        d_mask0 = jnp.concatenate([fwd_rows, co_mask[:, None]], axis=1)
-        d_src0 = jnp.concatenate([src_s2, rt.cached_src[:, None]], axis=1)
-        d_seq0 = jnp.concatenate([seq_s2, rt.cached_seq[:, None]], axis=1)
-        d_sock0 = jnp.concatenate([sock_s2, rt.cached_sock[:, None]], axis=1)
-        d_bytes0 = jnp.concatenate([bytes_s2, rt.cached_bytes[:, None]],
-                                   axis=1)
-        d_t0 = jnp.concatenate(
-            [jnp.where(fwd_rows, r_dt, I32_MAX),
-             jnp.where(co_mask, co_t, I32_MAX)[:, None]], axis=1)
-        (_, d_t, d_src, d_seq, d_sock, d_bytes, d_due) = _row_sort(
-            (~d_mask0).astype(jnp.int32), d_t0, d_src0, d_seq0, d_sock0,
-            d_bytes0, d_mask0, keys=4,
-        )
-        delivered = {
-            "mask": d_due, "src": d_src, "seq": d_seq, "sock": d_sock,
-            "bytes": d_bytes, "deliver_rel": d_t,
-        }
-        due = d_due  # for the n_delivered counter
-        # surviving queue = the untouched FIFO suffix, re-front-packed
-        keep = valid_s2 & (rstatus == codel.STATUS_QUEUED)
-        if packed_sort:
-            # sort-diet form: ONE (validity | arrival) packed key +
-            # permutation (kept arrivals are real times < I32_MAX, so
-            # the pack is exactly the (~keep, key) order)
-            perm_keep = _row_perm_sort(_pack_time_key(keep, arr_s))
-            take_keep = lambda a: jnp.take_along_axis(a, perm_keep, axis=1)
-            in_deliver_new = take_keep(jnp.where(keep, arr_s, I32_MAX))
-            in_src_new, in_seq_new = take_keep(src_s2), take_keep(seq_s2)
-            in_sock_new, in_bytes_new = (take_keep(sock_s2),
-                                         take_keep(bytes_s2))
-            in_valid_new = take_keep(keep)
-        else:
-            (_, in_deliver_new, in_src_new, in_seq_new, in_sock_new,
-             # shadowlint: disable=SL403 -- pre-diet variadic reference
-             in_bytes_new, in_valid_new) = _row_sort(
-                (~keep).astype(jnp.int32), jnp.where(keep, arr_s, I32_MAX),
-                src_s2, seq_s2, sock_s2, bytes_s2, keep, keys=2,
+        with jax.named_scope("codel_drain"):
+            # Full inbound pipeline: stored times are router-arrival times.
+            # FIFO order at the router = (arrival, src, seq), the same order
+            # the CPU plane's event queue feeds route_incoming_packet.
+            inv_m = (~in_valid_m).astype(jnp.int32)
+            arr_key = jnp.where(in_valid_m, in_deliver_m, I32_MAX)
+            (_, arr_s, src_s2, seq_s2, sock_s2, bytes_s2, valid_s2) = _row_sort(
+                inv_m, arr_key, in_src_m, in_seq_m, in_sock_m, in_bytes_m,
+                in_valid_m, keys=4,
             )
-        rt_out = rt2
+            rt2, rstatus, r_dt, co_mask, co_t, c_idx = codel.router_drain(
+                arr_s, bytes_s2, window_ns, params.dn_rate, params.dn_cap, rt,
+            )
+            # a row entry cached at window end leaves the queue: its identity
+            # moves into the router scalars until the relay resumes
+            new_cached = c_idx >= 0
+            ci = jnp.clip(c_idx, 0, CI - 1)[:, None]
+            take = lambda a: jnp.take_along_axis(a, ci, axis=1)[:, 0]
+            rt2 = rt2._replace(
+                cached_src=jnp.where(new_cached, take(src_s2), rt.cached_src),
+                cached_seq=jnp.where(new_cached, take(seq_s2), rt.cached_seq),
+                cached_sock=jnp.where(new_cached, take(sock_s2), rt.cached_sock),
+            )
+            # delivered = forwarded row entries + (maybe) the prior window's
+            # relay-cached packet, presented in (deliver_t, src, seq) order
+            fwd_rows = rstatus == codel.STATUS_DELIVERED
+            d_mask0 = jnp.concatenate([fwd_rows, co_mask[:, None]], axis=1)
+            d_src0 = jnp.concatenate([src_s2, rt.cached_src[:, None]], axis=1)
+            d_seq0 = jnp.concatenate([seq_s2, rt.cached_seq[:, None]], axis=1)
+            d_sock0 = jnp.concatenate([sock_s2, rt.cached_sock[:, None]], axis=1)
+            d_bytes0 = jnp.concatenate([bytes_s2, rt.cached_bytes[:, None]],
+                                       axis=1)
+            d_t0 = jnp.concatenate(
+                [jnp.where(fwd_rows, r_dt, I32_MAX),
+                 jnp.where(co_mask, co_t, I32_MAX)[:, None]], axis=1)
+            (_, d_t, d_src, d_seq, d_sock, d_bytes, d_due) = _row_sort(
+                (~d_mask0).astype(jnp.int32), d_t0, d_src0, d_seq0, d_sock0,
+                d_bytes0, d_mask0, keys=4,
+            )
+            delivered = {
+                "mask": d_due, "src": d_src, "seq": d_seq, "sock": d_sock,
+                "bytes": d_bytes, "deliver_rel": d_t,
+            }
+            due = d_due  # for the n_delivered counter
+            # surviving queue = the untouched FIFO suffix, re-front-packed
+            keep = valid_s2 & (rstatus == codel.STATUS_QUEUED)
+            if packed_sort:
+                # sort-diet form: ONE (validity | arrival) packed key +
+                # permutation (kept arrivals are real times < I32_MAX, so
+                # the pack is exactly the (~keep, key) order)
+                perm_keep = _row_perm_sort(_pack_time_key(keep, arr_s))
+                take_keep = lambda a: jnp.take_along_axis(a, perm_keep, axis=1)
+                in_deliver_new = take_keep(jnp.where(keep, arr_s, I32_MAX))
+                in_src_new, in_seq_new = take_keep(src_s2), take_keep(seq_s2)
+                in_sock_new, in_bytes_new = (take_keep(sock_s2),
+                                             take_keep(bytes_s2))
+                in_valid_new = take_keep(keep)
+            else:
+                (_, in_deliver_new, in_src_new, in_seq_new, in_sock_new,
+                 # shadowlint: disable=SL403 -- pre-diet variadic reference
+                 in_bytes_new, in_valid_new) = _row_sort(
+                    (~keep).astype(jnp.int32), jnp.where(keep, arr_s, I32_MAX),
+                    src_s2, seq_s2, sock_s2, bytes_s2, keep, keys=2,
+                )
+            rt_out = rt2
     else:
-        (delivered, due, in_deliver_new, in_src_new, in_seq_new,
-         in_sock_new, in_bytes_new, in_valid_new) = _release_due(
-            in_deliver_m, in_src_m, in_seq_m, in_sock_m, in_bytes_m,
-            in_valid_m, window_ns, packed_sort=packed_sort)
-        rt_out = rt
+        with jax.named_scope("release_due"):
+            (delivered, due, in_deliver_new, in_src_new, in_seq_new,
+             in_sock_new, in_bytes_new, in_valid_new) = _release_due(
+                in_deliver_m, in_src_m, in_seq_m, in_sock_m, in_bytes_m,
+                in_valid_m, window_ns, packed_sort=packed_sort)
+            rt_out = rt
 
     # --- 6. compact leftover egress so rows stay front-packed for ingest
-    (eg_prio_c, eg_dst_c, eg_bytes_c, eg_seq_c, eg_ctrl_c, eg_tsend_c,
-     eg_clamp_c, eg_sock_c, eg_valid_c) = _compact_egress(
-        eg_prio, eg_dst, eg_bytes, eg_seq, eg_ctrl, eg_tsend, eg_clamp,
-        eg_sock, eg_valid_left, packed_sort=packed_sort)
+    with jax.named_scope("egress_compact"):
+        # egress queue keeps only what didn't go out (compacted after
+        # routing, which still indexes this ordering)
+        eg_valid_left = eg_valid & ~sendable
+        (eg_prio_c, eg_dst_c, eg_bytes_c, eg_seq_c, eg_ctrl_c, eg_tsend_c,
+         eg_clamp_c, eg_sock_c, eg_valid_c) = _compact_egress(
+            eg_prio, eg_dst, eg_bytes, eg_seq, eg_ctrl, eg_tsend, eg_clamp,
+            eg_sock, eg_valid_left, packed_sort=packed_sort)
 
     # --- 7. stats + next-event reduction --------------------------------
-    per_host_in_next = jnp.where(in_valid_new, in_deliver_new,
-                                 I32_MAX).min(axis=1)
-    if router_aqm:
-        # a relay-cached packet blocks its whole row until the resume fires
-        per_host_in_next = jnp.where(rt_out.has_cached, rt_out.resume,
-                                     per_host_in_next)
-    next_event = jnp.minimum(
-        per_host_in_next.min(),
-        jnp.where(eg_valid_c.any(), window_ns, I32_MAX),
-    )
+    with jax.named_scope("next_event"):
+        per_host_in_next = jnp.where(in_valid_new, in_deliver_new,
+                                     I32_MAX).min(axis=1)
+        if router_aqm:
+            # a relay-cached packet blocks its whole row until the resume fires
+            per_host_in_next = jnp.where(rt_out.has_cached, rt_out.resume,
+                                         per_host_in_next)
+        next_event = jnp.minimum(
+            per_host_in_next.min(),
+            jnp.where(eg_valid_c.any(), window_ns, I32_MAX),
+        )
 
-    new_state = state._replace(
-        eg_dst=eg_dst_c, eg_bytes=eg_bytes_c, eg_prio=eg_prio_c,
-        eg_seq=eg_seq_c, eg_ctrl=eg_ctrl_c, eg_tsend=eg_tsend_c,
-        eg_clamp=eg_clamp_c, eg_sock=eg_sock_c, eg_valid=eg_valid_c,
-        in_src=in_src_new, in_bytes=in_bytes_new, in_seq=in_seq_new,
-        in_sock=in_sock_new, in_deliver_rel=in_deliver_new,
-        in_valid=in_valid_new,
-        tb_balance=balance, tb_rem_ns=tb_rem_ns, rng_counter=rng_counter,
-        rr_sent=rr_sent, router=rt_out,
-        n_sent=state.n_sent + sent.sum(axis=1, dtype=jnp.int32),
-        n_loss_dropped=state.n_loss_dropped + lost.sum(axis=1, dtype=jnp.int32),
-        n_overflow_dropped=state.n_overflow_dropped + overflowed,
-        n_delivered=state.n_delivered + due.sum(axis=1, dtype=jnp.int32),
-        **({"n_fault_dropped": state.n_fault_dropped + fault_drops}
-           if faults is not None else {}),
-    )
+        new_state = state._replace(
+            eg_dst=eg_dst_c, eg_bytes=eg_bytes_c, eg_prio=eg_prio_c,
+            eg_seq=eg_seq_c, eg_ctrl=eg_ctrl_c, eg_tsend=eg_tsend_c,
+            eg_clamp=eg_clamp_c, eg_sock=eg_sock_c, eg_valid=eg_valid_c,
+            in_src=in_src_new, in_bytes=in_bytes_new, in_seq=in_seq_new,
+            in_sock=in_sock_new, in_deliver_rel=in_deliver_new,
+            in_valid=in_valid_new,
+            tb_balance=balance, tb_rem_ns=tb_rem_ns, rng_counter=rng_counter,
+            rr_sent=rr_sent, router=rt_out,
+            n_sent=state.n_sent + sent.sum(axis=1, dtype=jnp.int32),
+            n_loss_dropped=state.n_loss_dropped + lost.sum(axis=1, dtype=jnp.int32),
+            n_overflow_dropped=state.n_overflow_dropped + overflowed,
+            n_delivered=state.n_delivered + due.sum(axis=1, dtype=jnp.int32),
+            **({"n_fault_dropped": state.n_fault_dropped + fault_drops}
+               if faults is not None else {}),
+        )
     if metrics is not None:
         # --- 8. telemetry accumulation (static; compiled out when off) --
-        metrics = _accumulate_metrics(
-            metrics, state, sent, lost, due, overflowed, delivered,
-            in_valid_m, rt_out.dropped - state.router.dropped,
-            fault_drops if faults is not None
-            else jnp.zeros((N,), jnp.int32), eg_bytes)
+        with jax.named_scope("metrics"):
+            metrics = _accumulate_metrics(
+                metrics, state, sent, lost, due, overflowed, delivered,
+                in_valid_m, rt_out.dropped - state.router.dropped,
+                fault_drops if faults is not None
+                else jnp.zeros((N,), jnp.int32), eg_bytes)
     if guards is not None:
         # --- 9. guard plane (static; compiled out when off) -------------
         # pure reads over values the step already materialized; nothing
         # here can perturb the simulation stream (docs/determinism.md)
-        arrivals = jnp.zeros((N,), jnp.int32).at[
-            jnp.clip(eg_dst, 0, N - 1).reshape(-1)].add(
-            sent.reshape(-1), mode="drop")
-        eg_left = sendable.sum(axis=1, dtype=jnp.int32)
-        if faults is not None:
-            eg_left = eg_left + fault_purged.sum(axis=1, dtype=jnp.int32)
-        cached_zero = jnp.zeros((N,), jnp.int32)
-        guards = guards_plane.check_window(
-            guards,
-            state=state,
-            eg_occ_in=state.eg_valid.sum(axis=1, dtype=jnp.int32),
-            eg_left_this_window=eg_left,
-            in_occ_in=state.in_valid.sum(axis=1, dtype=jnp.int32),
-            arrivals=arrivals,
-            overflowed=overflowed,
-            delivered=due.sum(axis=1, dtype=jnp.int32),
-            qdisc_delta=(rt_out.dropped - state.router.dropped
-                         if router_aqm else cached_zero),
-            cached_in=(state.router.has_cached.astype(jnp.int32)
-                       if router_aqm else cached_zero),
-            cached_out=(rt_out.has_cached.astype(jnp.int32)
-                        if router_aqm else cached_zero),
-            new_state=new_state,
-            rng_delta=rng_counter - state.rng_counter,
-            egress_cap=CE, shift_ns=shift_ns, window_ns=window_ns)
+        with jax.named_scope("guards"):
+            arrivals = jnp.zeros((N,), jnp.int32).at[
+                jnp.clip(eg_dst, 0, N - 1).reshape(-1)].add(
+                sent.reshape(-1), mode="drop")
+            eg_left = sendable.sum(axis=1, dtype=jnp.int32)
+            if faults is not None:
+                eg_left = eg_left + fault_purged.sum(axis=1, dtype=jnp.int32)
+            cached_zero = jnp.zeros((N,), jnp.int32)
+            guards = guards_plane.check_window(
+                guards,
+                state=state,
+                eg_occ_in=state.eg_valid.sum(axis=1, dtype=jnp.int32),
+                eg_left_this_window=eg_left,
+                in_occ_in=state.in_valid.sum(axis=1, dtype=jnp.int32),
+                arrivals=arrivals,
+                overflowed=overflowed,
+                delivered=due.sum(axis=1, dtype=jnp.int32),
+                qdisc_delta=(rt_out.dropped - state.router.dropped
+                             if router_aqm else cached_zero),
+                cached_in=(state.router.has_cached.astype(jnp.int32)
+                           if router_aqm else cached_zero),
+                cached_out=(rt_out.has_cached.astype(jnp.int32)
+                            if router_aqm else cached_zero),
+                new_state=new_state,
+                rng_delta=rng_counter - state.rng_counter,
+                egress_cap=CE, shift_ns=shift_ns, window_ns=window_ns)
     if hist is not None:
         # --- 10. latency/depth histograms (static; compiled out when
         # off) — pure reads over already-materialized values, like the
         # metrics section (docs/observability.md "Distributions and
         # the flight recorder")
-        hist = PlaneHistograms(
-            # deliver - send: wire latency + the round-barrier clamp,
-            # attributed to the DESTINATION (the consumer's view —
-            # "p99 delivery latency under incast" is a per-receiver
-            # question); int32 scatter-adds commute exactly
-            hist_delivery_ns=histo.accum_scatter(
-                hist.hist_delivery_ns, eg_dst,
-                histo.bucket_index(deliver_rel - eg_tsend), sent),
-            # egress sojourn: a packet carried from k windows back has
-            # a negative rebased send time; -tsend is exactly how long
-            # it waited for bandwidth (fresh sends land in bucket 0)
-            hist_sojourn_ns=histo.accum_rows(
-                hist.hist_sojourn_ns,
-                histo.bucket_index(-eg_tsend), sent),
-            hist_qdepth=histo.accum_depth(
-                hist.hist_qdepth,
-                state.eg_valid.sum(axis=1, dtype=jnp.int32)
-                + in_valid_m.sum(axis=1, dtype=jnp.int32)),
-        )
+        with jax.named_scope("hist"):
+            hist = PlaneHistograms(
+                # deliver - send: wire latency + the round-barrier clamp,
+                # attributed to the DESTINATION (the consumer's view —
+                # "p99 delivery latency under incast" is a per-receiver
+                # question); int32 scatter-adds commute exactly
+                hist_delivery_ns=histo.accum_scatter(
+                    hist.hist_delivery_ns, eg_dst,
+                    histo.bucket_index(deliver_rel - eg_tsend), sent),
+                # egress sojourn: a packet carried from k windows back has
+                # a negative rebased send time; -tsend is exactly how long
+                # it waited for bandwidth (fresh sends land in bucket 0)
+                hist_sojourn_ns=histo.accum_rows(
+                    hist.hist_sojourn_ns,
+                    histo.bucket_index(-eg_tsend), sent),
+                hist_qdepth=histo.accum_depth(
+                    hist.hist_qdepth,
+                    state.eg_valid.sum(axis=1, dtype=jnp.int32)
+                    + in_valid_m.sum(axis=1, dtype=jnp.int32)),
+            )
     if flightrec is not None:
         # --- 11. sampled flight recorder (static; compiled out when
         # off): per-hop events for the ~1/K packets whose (src, seq)
@@ -2061,62 +2084,63 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
         # pure function of the event stream. Ring-overflow drops at
         # routing are aggregate-counted only (metrics drop_ring_full);
         # a per-slot overflow identity is not materialized.
-        rows_e = jnp.broadcast_to(
-            jnp.arange(N, dtype=jnp.int32)[:, None], eg_dst.shape)
-        samp_eg = flightrec_mod.sample_mask(flightrec, rows_e, eg_seq)
-        flat = lambda a: a.reshape(-1)
-        kind_of = lambda k, ref: jnp.full((ref.size,), k, jnp.int32)
-        ev_kind = [kind_of(flightrec_mod.HOP_ROUTED, eg_dst),
-                   kind_of(flightrec_mod.HOP_DROP_LOSS, eg_dst)]
-        ev_src = [flat(rows_e), flat(rows_e)]
-        ev_seq = [flat(eg_seq), flat(eg_seq)]
-        ev_dst = [flat(eg_dst), flat(eg_dst)]
-        ev_t = [flat(eg_tsend), flat(eg_tsend)]
-        ev_mask = [flat(sent & samp_eg), flat(lost & samp_eg)]
-        if faults is not None:
-            # every fault-drop class the step distinguishes: source
-            # purge (crashed/link-down sender), burst corruption, AND
-            # the destination-blocked route withdrawal — a sampled
-            # packet eaten by its destination's crash must record a
-            # drop_fault hop, not silently vanish from the hop stream
-            # while metrics.drop_fault counts it
-            ev_kind.append(kind_of(flightrec_mod.HOP_DROP_FAULT, eg_dst))
-            ev_src.append(flat(rows_e))
-            ev_seq.append(flat(eg_seq))
-            ev_dst.append(flat(eg_dst))
-            ev_t.append(flat(eg_tsend))
-            ev_mask.append(flat(
-                (fault_purged | corrupt | blocked_dst) & samp_eg))
-        d_rows = jnp.broadcast_to(
-            jnp.arange(N, dtype=jnp.int32)[:, None],
-            delivered["mask"].shape)
-        samp_d = flightrec_mod.sample_mask(
-            flightrec, delivered["src"], delivered["seq"])
-        ev_kind.append(kind_of(flightrec_mod.HOP_DELIVERED,
-                               delivered["mask"]))
-        ev_src.append(flat(delivered["src"]))
-        ev_seq.append(flat(delivered["seq"]))
-        ev_dst.append(flat(d_rows))
-        ev_t.append(flat(delivered["deliver_rel"]))
-        ev_mask.append(flat(delivered["mask"] & samp_d))
-        if router_aqm:
-            a_rows = jnp.broadcast_to(
-                jnp.arange(N, dtype=jnp.int32)[:, None], src_s2.shape)
-            samp_a = flightrec_mod.sample_mask(flightrec, src_s2, seq_s2)
-            ev_kind.append(kind_of(flightrec_mod.HOP_DROP_AQM, src_s2))
-            ev_src.append(flat(src_s2))
-            ev_seq.append(flat(seq_s2))
-            ev_dst.append(flat(a_rows))
-            ev_t.append(flat(arr_s))
-            ev_mask.append(flat(valid_s2
-                                & (rstatus == codel.STATUS_DROPPED)
-                                & samp_a))
-        flightrec = flightrec_mod.record_events(
-            flightrec,
-            jnp.concatenate(ev_kind), jnp.concatenate(ev_src),
-            jnp.concatenate(ev_seq), jnp.concatenate(ev_dst),
-            jnp.concatenate(ev_t), jnp.concatenate(ev_mask))
-        flightrec = flightrec_mod.advance_window(flightrec)
+        with jax.named_scope("flightrec"):
+            rows_e = jnp.broadcast_to(
+                jnp.arange(N, dtype=jnp.int32)[:, None], eg_dst.shape)
+            samp_eg = flightrec_mod.sample_mask(flightrec, rows_e, eg_seq)
+            flat = lambda a: a.reshape(-1)
+            kind_of = lambda k, ref: jnp.full((ref.size,), k, jnp.int32)
+            ev_kind = [kind_of(flightrec_mod.HOP_ROUTED, eg_dst),
+                       kind_of(flightrec_mod.HOP_DROP_LOSS, eg_dst)]
+            ev_src = [flat(rows_e), flat(rows_e)]
+            ev_seq = [flat(eg_seq), flat(eg_seq)]
+            ev_dst = [flat(eg_dst), flat(eg_dst)]
+            ev_t = [flat(eg_tsend), flat(eg_tsend)]
+            ev_mask = [flat(sent & samp_eg), flat(lost & samp_eg)]
+            if faults is not None:
+                # every fault-drop class the step distinguishes: source
+                # purge (crashed/link-down sender), burst corruption, AND
+                # the destination-blocked route withdrawal — a sampled
+                # packet eaten by its destination's crash must record a
+                # drop_fault hop, not silently vanish from the hop stream
+                # while metrics.drop_fault counts it
+                ev_kind.append(kind_of(flightrec_mod.HOP_DROP_FAULT, eg_dst))
+                ev_src.append(flat(rows_e))
+                ev_seq.append(flat(eg_seq))
+                ev_dst.append(flat(eg_dst))
+                ev_t.append(flat(eg_tsend))
+                ev_mask.append(flat(
+                    (fault_purged | corrupt | blocked_dst) & samp_eg))
+            d_rows = jnp.broadcast_to(
+                jnp.arange(N, dtype=jnp.int32)[:, None],
+                delivered["mask"].shape)
+            samp_d = flightrec_mod.sample_mask(
+                flightrec, delivered["src"], delivered["seq"])
+            ev_kind.append(kind_of(flightrec_mod.HOP_DELIVERED,
+                                   delivered["mask"]))
+            ev_src.append(flat(delivered["src"]))
+            ev_seq.append(flat(delivered["seq"]))
+            ev_dst.append(flat(d_rows))
+            ev_t.append(flat(delivered["deliver_rel"]))
+            ev_mask.append(flat(delivered["mask"] & samp_d))
+            if router_aqm:
+                a_rows = jnp.broadcast_to(
+                    jnp.arange(N, dtype=jnp.int32)[:, None], src_s2.shape)
+                samp_a = flightrec_mod.sample_mask(flightrec, src_s2, seq_s2)
+                ev_kind.append(kind_of(flightrec_mod.HOP_DROP_AQM, src_s2))
+                ev_src.append(flat(src_s2))
+                ev_seq.append(flat(seq_s2))
+                ev_dst.append(flat(a_rows))
+                ev_t.append(flat(arr_s))
+                ev_mask.append(flat(valid_s2
+                                    & (rstatus == codel.STATUS_DROPPED)
+                                    & samp_a))
+            flightrec = flightrec_mod.record_events(
+                flightrec,
+                jnp.concatenate(ev_kind), jnp.concatenate(ev_src),
+                jnp.concatenate(ev_seq), jnp.concatenate(ev_dst),
+                jnp.concatenate(ev_t), jnp.concatenate(ev_mask))
+            flightrec = flightrec_mod.advance_window(flightrec)
     fs_out = None
     if flows is not None:
         # --- 12. device flow plane (static; compiled out when off):
@@ -2132,20 +2156,21 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
         # next_event was reduced before the append — chained callers
         # re-arm it exactly like the workload emission (the min with
         # window_ns in `chain_windows`).
-        from . import flows as flows_mod  # lazy: flows.py imports plane
+        with jax.named_scope("flows"):
+            from . import flows as flows_mod  # lazy: flows.py imports plane
 
-        ft, fs = flows
-        fout = flows_mod.flow_step(
-            ft, fs, new_state, delivered, window_ns,
-            metrics=metrics, guards=guards, flightrec=flightrec)
-        new_state, fs_out = fout[0], fout[1]
-        rest = list(fout[3:])
-        if metrics is not None:
-            metrics = rest.pop(0)
-        if guards is not None:
-            guards = rest.pop(0)
-        if flightrec is not None:
-            flightrec = rest.pop(0)
+            ft, fs = flows
+            fout = flows_mod.flow_step(
+                ft, fs, new_state, delivered, window_ns,
+                metrics=metrics, guards=guards, flightrec=flightrec)
+            new_state, fs_out = fout[0], fout[1]
+            rest = list(fout[3:])
+            if metrics is not None:
+                metrics = rest.pop(0)
+            if guards is not None:
+                guards = rest.pop(0)
+            if flightrec is not None:
+                flightrec = rest.pop(0)
     cs_out = None
     if compute is not None:
         # --- 13. device compute plane (static; compiled out when
@@ -2154,11 +2179,12 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
         # plane". Pure reads of the delivered dict; writes only the
         # ComputeState appended last — the SL501 full-invisibility
         # obligation `window_step[compute]` (analysis/proofs.py).
-        from . import compute as compute_mod  # lazy: compute imports plane
+        with jax.named_scope("compute"):
+            from . import compute as compute_mod  # lazy: compute imports plane
 
-        ctab, cstate = compute
-        cs_out = compute_mod.compute_step(ctab, cstate, delivered,
-                                          shift_ns, window_ns)
+            ctab, cstate = compute
+            cs_out = compute_mod.compute_step(ctab, cstate, delivered,
+                                              shift_ns, window_ns)
     out = (new_state, delivered, next_event)
     for plane_out in (metrics, guards, hist, flightrec):
         if plane_out is not None:

@@ -73,8 +73,8 @@ Sections (superset of the window step's numbered stages):
   `lax.scan` chain (the shared driver's device-resident unit,
   `tpu/elastic.drive_chained_windows`): divide by 8×``window_step``
   for the chain amortization ratio — what a host sync per window was
-  costing. bench.py surfaces the companion ``windows_per_sync`` ratio
-  in its JSON `sections`.
+  costing. bench.py records the companion ``windows_per_sync`` ratio
+  in its JSON `driver` field.
 - ``window_step_workload`` — the full step plus the workload plane's
   `workload_step` (`shadow_tpu/workloads/device.py`, an onoff traffic
   program at the bench shape): phase-pointer advance + table-driven
@@ -100,6 +100,12 @@ Sections (superset of the window step's numbered stages):
   docs/workloads.md "Serving load & the compute plane").
 
 Drive it from the CLI: ``python tools/profile_plane.py --hosts 1024,32768``.
+
+These are isolated micro-kernels timed by the host clock, not self times
+of the real chain: per-section device time of the compiled chain comes
+from the `jax.named_scope` each `window_step` section carries, read from
+a profiler trace (`benchmark/scopes.py`). The sections here stay for the
+CI presence-cost gates, which compare whole-step variants.
 """
 
 from __future__ import annotations
@@ -126,17 +132,6 @@ DEFAULT_SECTIONS = (
     "window_step_faults", "window_step_guards", "window_step_elastic",
     "window_step_trace", "window_step_workload", "window_step_flows",
     "window_step_compute",
-)
-
-#: the cheap per-section subset bench.py records in its JSON `sections`
-#: field (one profiled rep; the window_step_* presence-switch variants
-#: are gated separately in CI and cost full extra compiles, and
-#: rr_tensors/codel_drain never run in the bench's FIFO direct mode)
-BENCH_SECTIONS = (
-    "rebase_refill", "qdisc_sort", "token_gate", "loss_latency",
-    "ingress_compact", "routing_scatter", "routing_rank", "routing_place",
-    "release_due", "egress_compact", "ingest_rows", "window_step",
-    "window_chain8",
 )
 
 
@@ -350,9 +345,8 @@ def profile_sections(n_hosts: int, *, reps: int = 20,
         # the workload plane's per-window cost: the step + a
         # table-driven workload_step (an onoff program over the full
         # fleet at the bench shape — phase advance, emission gathers,
-        # ingest_rows append). Built only when the section is wanted:
-        # bench.py's BENCH_SECTIONS subset skips it, so bench runs
-        # never pay the program compile.
+        # ingest_rows append). Built only when the section is wanted,
+        # so profiles that skip it never pay the program compile.
         from ..workloads import compile_program, parse_scenario
         from ..workloads import device as _wdevice
 

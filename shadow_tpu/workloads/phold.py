@@ -19,21 +19,25 @@ def respawn_batch(delivered, spawn_seq, round_idx, n_hosts: int,
     `bench.py`'s scan body and the profiler's `ingest_rows` section,
     so the profiled batch is exactly the batch the bench feeds it —
     any workload change here changes both with it. Returns
-    (valid_mask, dst, nbytes, seq, ctrl), all [N, CI]."""
+    (valid_mask, dst, nbytes, seq, ctrl), all [N, CI]. Its ops carry
+    the named scope `respawn` into the compiled program."""
+    import jax
     import jax.numpy as jnp
 
-    mask = delivered["mask"]
-    dst = (delivered["src"] * 40503
-           + delivered["seq"] * 1566083941 + round_idx * 97) % n_hosts
-    # seq rank = position among the row's DUE lanes, not the raw column
-    # index: due lanes sit at the row TAIL of the delivered arrays, so a
-    # column-index rank would bake the ring capacity into every respawned
-    # seq — making the PHOLD stream capacity-dependent and breaking the
-    # elastic-growth parity contract (docs/determinism.md "Growth is
-    # bitwise-invisible"). The cumsum rank is identical at any CI.
-    rank = jnp.where(
-        mask, jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1, 0)
-    seq = spawn_seq[:, None] + rank
-    nbytes = jnp.full((n_hosts, ingress_cap), 1400, jnp.int32)
-    ctrl = jnp.zeros((n_hosts, ingress_cap), bool)
+    with jax.named_scope("respawn"):
+        mask = delivered["mask"]
+        dst = (delivered["src"] * 40503
+               + delivered["seq"] * 1566083941 + round_idx * 97) % n_hosts
+        # seq rank = position among the row's DUE lanes, not the raw
+        # column index: due lanes sit at the row TAIL of the delivered
+        # arrays, so a column-index rank would bake the ring capacity
+        # into every respawned seq — making the PHOLD stream
+        # capacity-dependent and breaking the elastic-growth parity
+        # contract (docs/determinism.md "Growth is bitwise-invisible").
+        # The cumsum rank is identical at any CI.
+        rank = jnp.where(
+            mask, jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1, 0)
+        seq = spawn_seq[:, None] + rank
+        nbytes = jnp.full((n_hosts, ingress_cap), 1400, jnp.int32)
+        ctrl = jnp.zeros((n_hosts, ingress_cap), bool)
     return mask, dst, nbytes, seq, ctrl

@@ -296,6 +296,122 @@ def test_three_drivers_route_through_the_shared_loop():
             f"route it through drive_chained_windows")
 
 
+class _Annotations:
+    """Logs the profiler spans the driver enters: (name, r0, r1)."""
+
+    def __init__(self):
+        self.entered = []
+
+    def __call__(self, name, **args):
+        entered = self.entered
+
+        class _Span:
+            def __enter__(self):
+                entered.append((name, args.get("r0"), args.get("r1")))
+
+            def __exit__(self, *exc):
+                return False
+
+        return _Span()
+
+
+class _Memo:
+    """A chain memo keyed by span length alone: the first chain of a
+    length misses and records, the next one of that length hits."""
+
+    def __init__(self):
+        self.store = {}
+
+    def snapshot(self, state, extras):
+        return np.asarray(state), extras
+
+    def key(self, carry, r0, r1, span_salt=b""):
+        return r1 - r0, None
+
+    def lookup(self, key):
+        return self.store.get(key)
+
+    def record(self, key, pre_walk, post, span_len):
+        self.store[key] = post
+
+    def replay(self, entry, pre):
+        return entry
+
+    def to_device(self, carry):
+        return jnp.asarray(carry[0]), carry[1]
+
+
+@pytest.mark.parametrize("memo,hook", [(False, False), (False, True),
+                                       (True, False), (True, True)])
+def test_driver_phases_are_profiler_spans(monkeypatch, memo, hook):
+    """Each chain opens `chain.span` and its phases as profiler spans
+    carrying the chain's r0/r1: `chain.launch` once per executed chain,
+    `chain.memo` only with a memo, `chain.hook` only with `on_chain`,
+    `chain.upload` when a replayed carry goes back to the device."""
+    from shadow_tpu.telemetry import tracer
+
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    ledger = tracer.RunTracer("t", backend={"platform": "cpu"})
+
+    def chain_fn(state, extras, rids, _pr):
+        return state + rids.shape[0], extras, None, None
+
+    state, _ = elastic.drive_chained_windows(
+        jnp.int32(0), (), chain_fn, n_rounds=8, chain_len=4,
+        memo=_Memo() if memo else None,
+        on_chain=(lambda r1, st, ex: None) if hook else None,
+        tracer=ledger)
+    # the stub memo's replay hands back the recorded carry as it was
+    assert int(state) == (4 if memo else 8)
+    first = [("chain.span", 0, 4)]
+    first += [("chain.memo", 0, 4)] if memo else []
+    first += [("chain.launch", 0, 4)]
+    first += [("chain.memo", 0, 4)] if memo else []
+    first += [("chain.hook", 0, 4)] if hook else []
+    if memo:
+        # the second chain replays the first one's record
+        second = [("chain.span", 4, 8), ("chain.memo", 4, 8)]
+        second += ([("chain.upload", 4, 8), ("chain.hook", 4, 8)] if hook
+                   else [("chain.upload", 4, 8)])
+        modes = ["execute", "replay" if hook else "ffwd"]
+    else:
+        second = [(n, 4, 8) for n, _r0, _r1 in first]
+        modes = ["execute", "execute"]
+    assert ann.entered == first + second
+    spans = [r for r in ledger.records if r.get("kind") == "span"]
+    assert [r["mode"] for r in spans] == modes
+
+
+def test_driver_spans_reach_a_profiler_trace(tmp_path):
+    """On a real `jax.profiler` trace (the CPU here), each chain's
+    `chain.launch` span is recorded once, with r0/r1 as its arguments."""
+    from jax.profiler import ProfileData
+
+    step = jax.jit(lambda st, rids: st + rids.sum())
+
+    def chain_fn(state, extras, rids, _pr):
+        return step(state, rids), extras, None, None
+
+    jax.block_until_ready(step(jnp.int32(0), jnp.arange(4)))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        state, _ = elastic.drive_chained_windows(
+            jnp.int32(0), (), chain_fn, n_rounds=12, chain_len=4)
+        jax.block_until_ready(state)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = [os.path.join(d, f) for d, _s, fs in os.walk(tmp_path)
+               for f in fs if f.endswith(".xplane.pb")]
+    launches = sorted(
+        (dict(e.stats)["r0"], dict(e.stats)["r1"])
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for e in line.events
+        if e.name == "chain.launch")
+    assert launches == [(0, 4), (4, 8), (8, 12)]
+    assert int(state) == sum(range(12))
+
+
 def test_unpack_planes_shapes_and_mismatch():
     """The shared presence-output unpacker every driver uses: lead
     splits, declaration-order plane outputs, the bare-NamedTuple-state

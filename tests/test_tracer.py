@@ -126,6 +126,49 @@ def _synthetic_ledger():
     ]
 
 
+class _Annotations:
+    """A stand-in for `jax.profiler.TraceAnnotation` that logs every
+    span entered and exited."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **args):
+        log = self.log
+
+        class _Span:
+            def __enter__(self):
+                log.append(("enter", name, args))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name, args))
+                return False
+
+        return _Span()
+
+
+@pytest.mark.parametrize("with_tracer", [False, True])
+def test_phase_opens_a_profiler_span_and_times_it(monkeypatch, with_tracer):
+    """`tracer.phase` enters and exits one annotation with its
+    arguments, and gives the ledger its wall ms only with a tracer."""
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    t = tracer.RunTracer("t", backend={"platform": "cpu"}) \
+        if with_tracer else None
+    with tracer.phase("chain.launch", t, r0=4, r1=8) as p:
+        assert ann.log == [("enter", "chain.launch", {"r0": 4, "r1": 8})]
+    assert ann.log[-1] == ("exit", "chain.launch", {"r0": 4, "r1": 8})
+    if with_tracer:
+        assert p.ms >= 0.0 and p.t0 > 0.0
+    else:
+        assert (p.ms, p.t0) == (0.0, 0.0)
+    # an exception closes the span and propagates
+    with pytest.raises(KeyError):
+        with tracer.phase("chain.hook", t):
+            raise KeyError("x")
+    assert ann.log[-1] == ("exit", "chain.hook", {})
+
+
 def test_chrome_trace_valid_and_nested(tmp_path):
     out = tmp_path / "trace.json"
     info = tracer.write_chrome_trace(_synthetic_ledger(), str(out))

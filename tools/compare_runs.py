@@ -160,8 +160,9 @@ def bench_delta(before_path: str, after_path: str) -> int:
     if w0 is not None or w1 is not None:
         print(f"windows/sync: {w0} -> {w1}")
     if not (s0 or s1):
-        print("(no `sections` field in either record — re-run bench.py "
-              "without BENCH_SECTIONS=0 to record the breakdown)")
+        print("(no `sections` field in either record: per-section "
+              "device time comes from a profiler trace's named scopes, "
+              "benchmark/scopes.py)")
         return 0
     _delta_table("section", s0, s1)
     return 0
