@@ -1200,15 +1200,7 @@ def range_specs() -> list[RangeSpec]:
                 "prio": (0, _B, "priorities"),
             },
             modular={**_STATE_MODULAR, **_PLANES_MODULAR,
-                     "seq": _WHY_FLOWSEQ},
-            allow={
-                "/take_along_axis (shadow_tpu/tpu/plane.py": (
-                    "packed-rank permutation indices occupy the key's "
-                    "low bits (< W by _assert_bit_budget's trace-time "
-                    "guard); the masked AND is invisible to intervals "
-                    "and take_along_axis's negative-index arm never "
-                    "executes for non-negative ranks"),
-            }),
+                     "seq": _WHY_FLOWSEQ}),
         RangeSpec(
             key="shadow_tpu.tpu.flows:flow_step",
             arg_names=["ft", "fs", "state", "delivered"],

@@ -252,18 +252,35 @@ def _row_sort(*arrays, keys: int):
 # The window step's row sorts used to push every payload column through the
 # lax.sort comparator network (12 arrays for the egress qdisc sort). The
 # packed-key forms below fuse the (validity, key) pair into ONE uint32 key,
-# sort (key, column-index), and apply the resulting permutation to the
-# payload columns with take_along_axis — the sorting network then carries 2-3
-# arrays instead of 7-12. Flat cross-host sorts (routing, flat ingest) get
-# the BUCKETED diet instead (`_routing_rank`, `ingest`): the group key is a
-# bounded bucket id, so the comparator network carries only (bucket, order
-# key, slot index) and the payload columns land via one fused scatter each —
-# never a standalone flat-permutation gather, which is DMA-bound on TPU
-# (~0.5 ms per column at 65k slots on a v5e); a row-sort permutation, by
-# contrast, only moves values within a C-wide row.
+# sort (key, column-index), and apply the resulting per-row permutation to
+# the payload columns with `_row_take` — the sorting network carries 2-3
+# arrays instead of 7-12, and the permutation only moves values within a
+# C-wide row: at ring widths a dense compare-and-select chain, not a TPU
+# gather (5-10 ns per element on a v5e). Flat cross-host sorts (routing,
+# flat ingest) get the BUCKETED diet instead (`_routing_rank`, `ingest`):
+# the group key is a bounded bucket id, so the comparator network carries
+# only (bucket, order key, slot index), and the payload columns land
+# through one stacked flat gather addressed by index arithmetic
+# (`_routing_place`, `ingest`).
 
 _SIGN32 = np.uint32(0x80000000)
 _U32_MAX = np.uint32(0xFFFFFFFF)
+
+def _row_take(a, perm):
+    """`jnp.take_along_axis(a, perm, axis=1)` for an [N, C] `a` and an
+    [N, K] int32 `perm` with entries in [0, C), as C compare-and-select
+    passes over the [N, K] output: dense elementwise work that fuses
+    into one loop, where XLA's gather costs 5-10 ns per element on a
+    TPU v5e (`tools/bench_row_take.py`: the chain is ahead at every
+    width measured, 16 to 256 columns). Only slices, broadcasts,
+    compares on `perm` and selects touch `a`, so SL505's selection
+    witness sees a pure selection of `a`."""
+    col = lambda c: jax.lax.broadcast_in_dim(
+        jax.lax.slice_in_dim(a, c, c + 1, axis=1), perm.shape, (0, 1))
+    out = col(0)
+    for c in range(1, a.shape[1]):
+        out = jax.lax.select(perm == c, col(c), out)
+    return out
 
 
 def _assert_bit_budget(*fields):
@@ -312,7 +329,7 @@ def _pack_rank_key(valid, rank, width: int):
 
 def _row_perm_sort(packed, *extra_keys):
     """Stable row sort of (packed uint32 key [, extra keys]); returns the
-    permutation [N, C] to apply to payload columns via take_along_axis.
+    permutation [N, C] to apply to payload columns via `_row_take`.
     Stability makes the carried column index break ties in original
     order, exactly like the variadic stable sort it replaces."""
     N, C = packed.shape
@@ -856,7 +873,7 @@ def ingest_rows(state: NetPlaneState, dst: jax.Array, nbytes: jax.Array,
                 key = jax.lax.sort(_pack_rank_key(valid_all, rank, W),
                                    dimension=1, is_stable=True)
                 perm = (key & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)[:, :CE]
-                take = lambda a, b: jnp.take_along_axis(cat(a, b), perm, axis=1)
+                take = lambda a, b: _row_take(cat(a, b), perm)
                 dst_m = take(state.eg_dst, dst)
                 bytes_m = take(state.eg_bytes, nbytes)
                 prio_m = take(state.eg_prio, prio)
@@ -865,9 +882,11 @@ def ingest_rows(state: NetPlaneState, dst: jax.Array, nbytes: jax.Array,
                 tsend_m = take(state.eg_tsend, send_rel)
                 clamp_m = take(state.eg_clamp, clamp_rel)
                 sock_m = take(state.eg_sock, sock)
-                valid_m = take(state.eg_valid, valid)
-                overflow = jnp.maximum(
-                    valid_all.sum(axis=1, dtype=jnp.int32) - CE, 0)
+                # every valid key sorts below every invalid one, so the
+                # merged row is valid exactly on its first n_valid lanes
+                n_valid = valid_all.sum(axis=1, dtype=jnp.int32)
+                valid_m = rank[:, :CE] < n_valid[:, None]
+                overflow = jnp.maximum(n_valid - CE, 0)
             else:
                 inv = (~valid_all).astype(jnp.int32)
                 # stable sort by validity alone: existing entries (columns
@@ -1021,7 +1040,7 @@ def _qdisc_keys(state: NetPlaneState, params: NetPlaneParams, *,
     earlier = state.eg_seq[:, None, :] < state.eg_seq[:, :, None]
     rr_rank = jnp.sum(same_sock & both_valid & earlier, axis=2,
                       dtype=jnp.int32)
-    rr_key = jnp.take_along_axis(rr_base, sock_slot, axis=1) + rr_rank
+    rr_key = _row_take(rr_base, sock_slot) + rr_rank
     rr_mode = params.qdisc_rr[:, None]
     qkey1 = jnp.where(rr_mode, rr_key, state.eg_prio)
     qkey2 = jnp.where(rr_mode, state.eg_sock, 0)
@@ -1060,7 +1079,7 @@ def _egress_order(state: NetPlaneState, qkey1, qkey2, eg_tsend_rb,
 
             def do_sort(packed):
                 perm = _row_perm_sort(packed)
-                take = lambda a: jnp.take_along_axis(a, perm, axis=1)
+                take = lambda a: _row_take(a, perm)
                 return (take(state.eg_prio), take(state.eg_sock),
                         take(state.eg_dst), take(state.eg_bytes),
                         take(state.eg_seq), take(state.eg_ctrl),
@@ -1070,7 +1089,7 @@ def _egress_order(state: NetPlaneState, qkey1, qkey2, eg_tsend_rb,
             return jax.lax.cond(ordered, ident, do_sort, packed)
         extra = (qkey2,) if rr_enabled else ()
         perm = _row_perm_sort(packed, *extra)
-        take = lambda a: jnp.take_along_axis(a, perm, axis=1)
+        take = lambda a: _row_take(a, perm)
         return (take(state.eg_prio), take(state.eg_sock),
                 take(state.eg_dst), take(state.eg_bytes),
                 take(state.eg_seq), take(state.eg_ctrl),
@@ -1203,7 +1222,7 @@ def _compact_ingress(state: NetPlaneState, in_deliver, *, packed_sort: bool):
 
         def do_sort(packed):
             perm = _row_perm_sort(packed)
-            take = lambda a: jnp.take_along_axis(a, perm, axis=1)
+            take = lambda a: _row_take(a, perm)
             return (take(key_deliver), take(state.in_src),
                     take(state.in_seq), take(state.in_sock),
                     take(state.in_bytes), take(state.in_valid))
@@ -1267,11 +1286,11 @@ def _routing_order(sent, eg_dst, eg_seq, deliver_rel, row_perm=None):
         # so the scatter inverts it: row_perm[n, rank[n, c]] = c
         row_perm = jnp.zeros((N, CE), jnp.int32).at[rows, rank].set(
             jnp.broadcast_to(col, (N, CE)))
-    take_row = lambda a: jnp.take_along_axis(a, row_perm, axis=1)
-    sent_p, dst_p = take_row(sent), take_row(eg_dst)
-    flat_dst = jnp.where(sent_p & (dst_p >= 0) & (dst_p < N),
-                         dst_p, N).reshape(-1)
-    deliver_key = take_row(deliver_rel).reshape(-1) \
+    # the bucket is elementwise in (sent, dst), so it is built before the
+    # row permutation: two takes, not three
+    bucket = jnp.where(sent & (eg_dst >= 0) & (eg_dst < N), eg_dst, N)
+    flat_dst = _row_take(bucket, row_perm).reshape(-1)
+    deliver_key = _row_take(deliver_rel, row_perm).reshape(-1) \
         .astype(jnp.uint32) ^ _SIGN32
     pos = jnp.arange(B, dtype=jnp.int32)
     # (dst, deliver, pos) is a TOTAL order (pos is distinct), so the
@@ -1495,9 +1514,12 @@ def _release_due(in_deliver_m, in_src_m, in_seq_m, in_sock_m, in_bytes_m,
             dimension=1, is_stable=False, num_keys=4,
         )
         d_t = ((wkey_s + w_bias) ^ _SIGN32).astype(jnp.int32)
-        take = lambda a: jnp.take_along_axis(a, perm, axis=1)
+        take = lambda a: _row_take(a, perm)
         d_sock, d_bytes = take(in_sock_m), take(in_bytes_m)
-        d_due, d_valid = take(due), take(in_valid_m)
+        d_valid = take(in_valid_m)
+        # `due` is elementwise in (valid, deliver key), and d_t is the
+        # permuted deliver key exactly, so no take is needed for it
+        d_due = d_valid & (d_t < window_ns)
     else:
         (_, d_t, d_src, d_seq, d_sock, d_bytes, d_due,
          # shadowlint: disable=SL403 -- pre-diet variadic reference path
@@ -1523,7 +1545,7 @@ def _compact_egress(eg_prio, eg_dst, eg_bytes, eg_seq, eg_ctrl, eg_tsend,
     eg_prio_left = jnp.where(eg_valid_left, eg_prio, I32_MAX)
     if packed_sort:
         perm = _row_perm_sort(_pack_time_key(eg_valid_left, eg_prio_left))
-        take = lambda a: jnp.take_along_axis(a, perm, axis=1)
+        take = lambda a: _row_take(a, perm)
         return (take(eg_prio_left), take(eg_dst), take(eg_bytes),
                 take(eg_seq), take(eg_ctrl), take(eg_tsend),
                 take(eg_clamp), take(eg_sock), take(eg_valid_left))
@@ -1808,7 +1830,7 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
              sendable, spent) = pallas_egress.egress_order_gate(
                 state.eg_valid, state.eg_prio, state.eg_bytes, state.eg_tsend,
                 state.eg_clamp, balance, shift_ns)
-            take = lambda a: jnp.take_along_axis(a, perm, axis=1)
+            take = lambda a: _row_take(a, perm)
             eg_prio, eg_sock, eg_dst = (take(state.eg_prio),
                                         take(state.eg_sock),
                                         take(state.eg_dst))
@@ -1915,7 +1937,7 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
             # moves into the router scalars until the relay resumes
             new_cached = c_idx >= 0
             ci = jnp.clip(c_idx, 0, CI - 1)[:, None]
-            take = lambda a: jnp.take_along_axis(a, ci, axis=1)[:, 0]
+            take = lambda a: _row_take(a, ci)[:, 0]
             rt2 = rt2._replace(
                 cached_src=jnp.where(new_cached, take(src_s2), rt.cached_src),
                 cached_seq=jnp.where(new_cached, take(seq_s2), rt.cached_seq),
@@ -1949,7 +1971,7 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
                 # permutation (kept arrivals are real times < I32_MAX, so
                 # the pack is exactly the (~keep, key) order)
                 perm_keep = _row_perm_sort(_pack_time_key(keep, arr_s))
-                take_keep = lambda a: jnp.take_along_axis(a, perm_keep, axis=1)
+                take_keep = lambda a: _row_take(a, perm_keep)
                 in_deliver_new = take_keep(jnp.where(keep, arr_s, I32_MAX))
                 in_src_new, in_seq_new = take_keep(src_s2), take_keep(seq_s2)
                 in_sock_new, in_bytes_new = (take_keep(sock_s2),

@@ -226,8 +226,8 @@ def profile_sections(n_hosts: int, *, reps: int = 20,
                         _qdisc_keys, _refill_tokens, _release_due,
                         _route_scatter, _routing_place,
                         _routing_place_legacy, _routing_rank,
-                        _routing_rank_legacy, _row_sort, _token_gate,
-                        ingest_rows, window_step)
+                        _routing_rank_legacy, _row_sort, _row_take,
+                        _token_gate, ingest_rows, window_step)
 
     from ..faults.plane import neutral_faults as _neutral_faults
     from ..guards.plane import make_guards as _clean_guards
@@ -406,7 +406,7 @@ def profile_sections(n_hosts: int, *, reps: int = 20,
              f_spent) = pallas_egress.egress_order_gate(
                 st.eg_valid, st.eg_prio, st.eg_bytes, st.eg_tsend,
                 st.eg_clamp, balance2, sh)
-            takeE = lambda a: jnp.take_along_axis(a, f_permE, axis=1)
+            takeE = lambda a: _row_take(a, f_permE)
             f_dst, f_seq = takeE(st.eg_dst), takeE(st.eg_seq)
             f_sock, f_ctrl = takeE(st.eg_sock), takeE(st.eg_ctrl)
         else:
