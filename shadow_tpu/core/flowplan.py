@@ -163,6 +163,12 @@ def compile_flow_plan(config, routing, node_index_of_host=None) -> FlowPlan:
 # maximizes XLA compile-cache hits across configs.
 _WINDOW_LADDER = (1_000, 2_000, 5_000, 20_000)
 
+# a bucket's first run: segment ring slots per connection, and fused
+# steps per window before the run counts as saturated (each re-run
+# doubles the one that ran out)
+QUEUE_SLOTS = 256
+STEP_CAP = 512
+
 
 def _bucket_window(lat_us: int) -> int:
     w = min(lat_us, _WINDOW_LADDER[-1])
@@ -193,7 +199,17 @@ def run_flow_simulation(config, routing, stats, *, checkpoint_dir=None,
     """Execute the config's tgen workload on the device flow engine and
     fill `stats` (a `SimStats`) the way the round loop would: segments
     as events/packets, wire drops as packet drops, incomplete transfers
-    as process failures against the clients' expected exit 0.
+    as process failures against the clients' expected exit 0. Per flow
+    (plan order) it also fills `flow_complete_us`, `flow_bytes_read`,
+    `flow_segments`, `flow_retransmits_by_flow` and `flow_wire_drops`;
+    `flow_counters` counts this run's `dispatches`, host-device `syncs`
+    (probes, saturation reads, result reads), `windows` dispatched and
+    `reruns` (saturation and ring re-runs).
+
+    The host phases run under profiler spans: `flow.plan` (the plan and
+    its buckets), `flow.world` (each world built), `flow.dispatch` and
+    `flow.probe` (floweng.run_to_completion), `flow.readback` (the
+    saturation and result reads) and `flow.reconcile` (stats).
 
     Checkpoint/resume (docs/robustness.md): latency buckets are
     independent worlds, so bucket completion is an EXACT resume unit.
@@ -203,21 +219,26 @@ def run_flow_simulation(config, routing, stats, *, checkpoint_dir=None,
     remaining buckets — the merged results are bitwise-identical to an
     uninterrupted run because per-bucket results are deterministic and
     disjoint."""
+    from ..telemetry.tracer import phase
     from ..tpu import floweng, require_device
 
     require_device("flow engine")
     # shadowlint: disable=SL101 -- wall-clock perf stat only; never feeds sim state
     wall0 = _walltime.monotonic()
-    plan = compile_flow_plan(config, routing)
-    F = len(plan.size)
-    buckets: dict[int, list[int]] = {}
-    for f in range(F):
-        lookahead = min(int(plan.latency_us[f]),
-                        int(plan.latency_back_us[f]))
-        buckets.setdefault(_bucket_window(lookahead), []).append(f)
+    with phase("flow.plan"):
+        plan = compile_flow_plan(config, routing)
+        F = len(plan.size)
+        buckets: dict[int, list[int]] = {}
+        for f in range(F):
+            lookahead = min(int(plan.latency_us[f]),
+                            int(plan.latency_back_us[f]))
+            buckets.setdefault(_bucket_window(lookahead), []).append(f)
 
     complete_us = np.full(F, np.iinfo(np.int32).max, np.int64)
     bytes_read = np.zeros(F, np.int64)
+    by_flow = {k: np.zeros(F, np.int64) for k in (
+        "segments_by_flow", "retransmits_by_flow", "wire_drops_by_flow")}
+    counters = {"dispatches": 0, "syncs": 0, "windows": 0, "reruns": 0}
     segments = wire_drops = queue_drops = retransmits = 0
     rounds = 0
     total_retries = 0
@@ -250,6 +271,9 @@ def run_flow_simulation(config, routing, stats, *, checkpoint_dir=None,
                 f"merge incompatible bucket results")
         complete_us = arrays["complete_us"].astype(np.int64)
         bytes_read = arrays["bytes_read"].astype(np.int64)
+        for k in by_flow:
+            if k in arrays:
+                by_flow[k] = arrays[k].astype(np.int64)
         c = meta["counters"]
         segments, wire_drops = c["segments"], c["wire_drops"]
         queue_drops, retransmits = c["queue_drops"], c["retransmits"]
@@ -284,7 +308,8 @@ def run_flow_simulation(config, routing, stats, *, checkpoint_dir=None,
                     "ring_dirty": bool(ring_dirty),
                 },
             },
-            arrays={"complete_us": complete_us, "bytes_read": bytes_read},
+            arrays={"complete_us": complete_us, "bytes_read": bytes_read,
+                    **by_flow},
         )
 
     for window_us, idx in sorted(buckets.items(), reverse=True):
@@ -313,17 +338,21 @@ def run_flow_simulation(config, routing, stats, *, checkpoint_dir=None,
         # machines recover via retransmit, so results stay valid but
         # completion times are distorted. Same discipline as step-cap
         # saturation: re-run the bucket from scratch with doubled rings.
-        queue_slots = 256
+        queue_slots = QUEUE_SLOTS
         for ring_attempt in range(max_doublings + 1):
-            world = floweng.make_flow_world(
-                lat, size, start_us=start, loss=loss, seed=plan.seed,
-                server_writes=True, queue_slots=queue_slots,
-                latency_back_us=lat_b, loss_back=loss_b)
+            with phase("flow.world"):
+                world = floweng.make_flow_world(
+                    lat, size, start_us=start, loss=loss, seed=plan.seed,
+                    server_writes=True, queue_slots=queue_slots,
+                    latency_back_us=lat_b, loss_back=loss_b)
             world, sim_s, retries = floweng.run_to_completion(
                 world, window_us, max_sim_s=plan.stop_us / 1e6,
-                chunk_windows=chunk, probe_every=3)
-            world = floweng.finalize_to(world, plan.stop_us)
-            res = floweng.flow_results(world)
+                chunk_windows=chunk, probe_every=3,
+                max_events_per_window=STEP_CAP, counters=counters)
+            with phase("flow.readback"):
+                world = floweng.finalize_to(world, plan.stop_us)
+                res = floweng.flow_results(world)
+            counters["syncs"] += 1
             if res["queue_drops"] == 0:
                 break
             if cap_mode == "strict":
@@ -360,6 +389,7 @@ def run_flow_simulation(config, routing, stats, *, checkpoint_dir=None,
                 overflow=int(res["queue_drops"]), plane="floweng")
             ev["bucket_window_us"] = window_us
             queue_slots *= 2
+            counters["reruns"] += 1
             log.warning(
                 "flow engine: %d ring-capacity drop(s) in the %d us "
                 "bucket (engine ring overflow, distinct from modeled "
@@ -368,6 +398,8 @@ def run_flow_simulation(config, routing, stats, *, checkpoint_dir=None,
             total_retries += 1
         complete_us[sel] = res["complete_us"][:Fb]
         bytes_read[sel] = res["bytes_read"][:Fb]
+        for k, arr in by_flow.items():
+            arr[sel] = res[k][:Fb]
         segments += res["segments"]
         wire_drops += res["wire_drops"]
         queue_drops += res["queue_drops"]
@@ -377,35 +409,41 @@ def run_flow_simulation(config, routing, stats, *, checkpoint_dir=None,
         done_buckets.add(window_us)
         _bucket_checkpoint()
 
-    ok = bytes_read >= plan.size
-    for f in np.nonzero(~ok)[0]:
-        stats.process_failures.append((
-            f"{plan.client[f]}/tgen-client",
-            f"expected exited(0), got running (transfer "
-            f"{int(bytes_read[f])}/{int(plan.size[f])}"
-            f" bytes from {plan.server[f]})",
-        ))
-    if total_retries:
-        log.warning("flow engine re-ran %d time(s) after window "
-                    "saturation%s", total_retries,
-                    " (ring drops persisted in a final run)" if ring_dirty
-                    else " (final runs clean)")
-    if ring_dirty and getattr(config, "strict", False):
-        # top-level strict: a final run that still lost packets to
-        # engine ring capacity is a refused silent divergence, not a
-        # warning (same promotion as the transport's ingress drops)
-        raise CapacityError(
-            "flow engine: ring-capacity drops persisted after the "
-            "growth budget (capacity.max_doublings="
-            f"{max_doublings}) under strict: true; raise the rings or "
-            "the budget", ring="flow-queue")
-    stats.capacity_events = list(trajectory.events)
-    stats.rounds = rounds
-    stats.events_executed = segments
-    stats.packets_sent = segments
-    stats.packets_dropped = wire_drops + queue_drops
-    stats.sim_time_ns = config.general.stop_time
-    stats.wall_seconds = _walltime.monotonic() - wall0  # shadowlint: disable=SL101 -- perf stat
-    stats.flow_complete_us = complete_us
-    stats.flow_retransmits = retransmits
+    with phase("flow.reconcile"):
+        ok = bytes_read >= plan.size
+        for f in np.nonzero(~ok)[0]:
+            stats.process_failures.append((
+                f"{plan.client[f]}/tgen-client",
+                f"expected exited(0), got running (transfer "
+                f"{int(bytes_read[f])}/{int(plan.size[f])}"
+                f" bytes from {plan.server[f]})",
+            ))
+        if total_retries:
+            log.warning("flow engine re-ran %d time(s) after window "
+                        "saturation%s", total_retries,
+                        " (ring drops persisted in a final run)"
+                        if ring_dirty else " (final runs clean)")
+        if ring_dirty and getattr(config, "strict", False):
+            # top-level strict: a final run that still lost packets to
+            # engine ring capacity is a refused silent divergence, not a
+            # warning (same promotion as the transport's ingress drops)
+            raise CapacityError(
+                "flow engine: ring-capacity drops persisted after the "
+                "growth budget (capacity.max_doublings="
+                f"{max_doublings}) under strict: true; raise the rings or "
+                "the budget", ring="flow-queue")
+        stats.capacity_events = list(trajectory.events)
+        stats.rounds = rounds
+        stats.events_executed = segments
+        stats.packets_sent = segments
+        stats.packets_dropped = wire_drops + queue_drops
+        stats.sim_time_ns = config.general.stop_time
+        stats.wall_seconds = _walltime.monotonic() - wall0  # shadowlint: disable=SL101 -- perf stat
+        stats.flow_complete_us = complete_us
+        stats.flow_retransmits = retransmits
+        stats.flow_bytes_read = bytes_read
+        stats.flow_segments = by_flow["segments_by_flow"]
+        stats.flow_retransmits_by_flow = by_flow["retransmits_by_flow"]
+        stats.flow_wire_drops = by_flow["wire_drops_by_flow"]
+        stats.flow_counters = dict(counters)
     return stats

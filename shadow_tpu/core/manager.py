@@ -59,6 +59,15 @@ class SimStats:
     # flow-engine runs: each flow's completion time (us, plan order;
     # int32 max = never completed)
     flow_complete_us: Optional[list] = None
+    # ... and, per flow in plan order, the bytes its client read and its
+    # two lanes' segments (MSS units), retransmissions and wire drops
+    flow_bytes_read: Optional[list] = None
+    flow_segments: Optional[list] = None
+    flow_retransmits_by_flow: Optional[list] = None
+    flow_wire_drops: Optional[list] = None
+    # the run's dispatches, host-device syncs, windows and re-runs
+    # (core/flowplan.run_flow_simulation)
+    flow_counters: Optional[dict] = None
 
     def as_dict(self) -> dict:
         out = {
