@@ -438,9 +438,10 @@ def _plane_entry(rr_enabled: bool, router_aqm: bool, no_loss: bool,
 
 def _routing_entry(stage: str):
     """The routing-stage split (plane section 5): `routing_rank` audits
-    the bucketed-order computation (row seq-rank + diet flat sort +
-    histogram placement), `routing_place` the fused per-column
-    gather-scatters — the same split the per-section profiler times."""
+    the bucketed-order computation (row seq-rank + the diet flat sort
+    that carries the payload + bucket bounds), `routing_place` the one
+    stacked placement gather — the same split the per-section profiler
+    times."""
     def build():
         import jax.numpy as jnp
 
@@ -457,17 +458,19 @@ def _routing_entry(stage: str):
             rng.integers(0, 10**6, (n, ce)), jnp.int32)
         n_valid = jnp.zeros((n,), jnp.int32)
         if stage == "rank":
-            def fn(sent, eg_dst, eg_seq, deliver, n_valid):
+            def fn(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver,
+                   n_valid):
                 return plane._routing_rank(
-                    sent, eg_dst, eg_seq, deliver, n_valid, ci)
+                    sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver,
+                    n_valid, ci)
 
-            return fn, (sent, eg_dst, eg_seq, deliver, n_valid)
-        row_perm, o_pos, offsets, take_n, _ovf = plane._routing_rank(
-            sent, eg_dst, eg_seq, deliver, n_valid, ci)
+            return fn, (sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver,
+                        n_valid)
+        streams, offsets, take_n, _ovf = plane._routing_rank(
+            sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver, n_valid, ci)
         z = lambda: jnp.zeros((n, ci), jnp.int32)
         return plane._routing_place, (
-            row_perm, o_pos, offsets, take_n, n_valid, eg_seq, eg_bytes,
-            eg_sock, deliver, z(), z(), z(), z(), z(),
+            streams, offsets, take_n, n_valid, z(), z(), z(), z(), z(),
             jnp.zeros((n, ci), bool))
 
     return build

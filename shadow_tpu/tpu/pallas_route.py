@@ -12,11 +12,12 @@ compare mask, with no per-column placement dispatches.
 
 The arrival order itself still comes from the XLA diet sort (sorting
 across the host axis is what XLA's comparator networks are for; under
-a sharded mesh that sort IS the all-to-all). The sorted payload streams
-are materialized once, stacked as one [8, ~B] array that stays in HBM
-(`pl.ANY`): per destination row the kernel DMAs the 128-lane-aligned
-span covering that row's segment into a small ring of VMEM windows
-(`_DMA_LAG` rows in flight) and rotates the segment to lane 0. The
+a sharded mesh that sort IS the all-to-all), and so do the sorted
+payload streams, which that sort carries. They are stacked as one
+[8, ~B] array that stays in HBM (`pl.ANY`): per destination row the
+kernel DMAs the 128-lane-aligned span covering that row's segment into
+a small ring of VMEM windows (`_DMA_LAG` rows in flight) and rotates
+the segment to lane 0. The
 stream is never resident in VMEM whole — at N = 32,768 hosts and the
 config's default caps it is 32 MiB per column.
 
@@ -142,29 +143,24 @@ def route_place(sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
     or None to compute it in XLA (`plane_kernel: pallas`). Returns the
     merged ingress columns + per-host overflow, like
     `plane._route_scatter`."""
-    N, CE = eg_dst.shape
     CI = in_src_c.shape[1]
     # the irreducible cross-host exchange: ONE diet flat sort over the
-    # (bucket, deliver, slot) keys + binary-searched bucket bounds
-    row_perm, o_pos, offsets, take_n, overflow = _routing_rank(
-        sent, eg_dst, eg_seq, deliver_rel, n_valid_in, CI,
-        row_perm=row_perm)
+    # (bucket, deliver, slot) keys, carrying the payload, + binary-
+    # searched bucket bounds
+    streams, offsets, take_n, overflow = _routing_rank(
+        sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel, n_valid_in,
+        CI, row_perm=row_perm)
     lo = offsets - n_valid_in
 
-    # arrival-sorted payload streams, addressed through the composed
-    # permutation (sorted position -> original slot), stacked to fill
-    # the 8-sublane HBM tile and padded CI on the left and one window on
-    # the right (to a whole lane tile), so every aligned window is in
-    # bounds; padding is never selected: masked lanes only cover the
-    # bucket's own segment
-    flat = lambda a: a.reshape(-1)
-    g = (o_pos // CE) * CE + flat(row_perm)[o_pos]
-    cols = [(o_pos // CE).astype(jnp.int32),  # src == source row
-            flat(eg_seq)[g], flat(eg_sock)[g], flat(eg_bytes)[g],
-            flat(deliver_rel)[g]]
+    # the arrival-sorted payload streams, stacked to fill the 8-sublane
+    # HBM tile and padded CI on the left and one window on the right (to
+    # a whole lane tile), so every aligned window is in bounds; padding
+    # is never selected: masked lanes only cover the bucket's own segment
+    B = streams[0].shape[0]
     right = _window_width(CI)
-    right += -(CI + N * CE + right) % _LANES
-    streams = jnp.pad(jnp.stack(cols), ((0, 8 - len(cols)), (CI, right)))
+    right += -(CI + B + right) % _LANES
+    streams = jnp.pad(jnp.stack(streams),
+                      ((0, 8 - len(streams)), (CI, right)))
 
     (in_src_m, in_seq_m, in_sock_m, in_bytes_m, in_deliver_m,
      in_valid_m) = _place_call(

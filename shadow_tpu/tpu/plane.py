@@ -258,10 +258,11 @@ def _row_sort(*arrays, keys: int):
 # C-wide row: at ring widths a dense compare-and-select chain, not a TPU
 # gather (5-10 ns per element on a v5e). Flat cross-host sorts (routing,
 # flat ingest) get the BUCKETED diet instead (`_routing_rank`, `ingest`):
-# the group key is a bounded bucket id, so the comparator network carries
-# only (bucket, order key, slot index), and the payload columns land
-# through one stacked flat gather addressed by index arithmetic
-# (`_routing_place`, `ingest`).
+# the group key is a bounded bucket id, so the comparator network's keys
+# are only (bucket, order key, slot index). The routing sort carries its
+# three payload columns too (a flat gather is the dearer way to order
+# them), and the rows are then filled through one stacked gather
+# addressed by index arithmetic (`_routing_place`, `ingest`).
 
 _SIGN32 = np.uint32(0x80000000)
 _U32_MAX = np.uint32(0xFFFFFFFF)
@@ -1242,117 +1243,114 @@ def _compact_ingress(state: NetPlaneState, in_deliver, *, packed_sort: bool):
             in_valid_c, n_valid_in)
 
 
-def _routing_order(sent, eg_dst, eg_seq, deliver_rel, row_perm=None):
+def _seq_row_perm(eg_seq):
+    """[N, CE] seq-order position -> original column, per row: the stable
+    rank of each slot within its row by (seq, column), from an
+    [N, CE, CE] pairwise compare, inverted. The qdisc sort left rows in
+    priority order, not seq order, and equal (dst, deliver) arrivals
+    from one source must land by seq."""
+    N, CE = eg_seq.shape
+    col = jnp.arange(CE, dtype=jnp.int32)
+    earlier = ((eg_seq[:, None, :] < eg_seq[:, :, None])
+               | ((eg_seq[:, None, :] == eg_seq[:, :, None])
+                  & (col[None, None, :] < col[None, :, None])))
+    rank = jnp.sum(earlier, axis=2, dtype=jnp.int32)  # [N, CE]
+    rows = jnp.arange(N, dtype=jnp.int32)[:, None]
+    # rank is a permutation per row ((seq, col) pairs are distinct),
+    # so the scatter inverts it: row_perm[n, rank[n, c]] = c
+    return jnp.zeros((N, CE), jnp.int32).at[rows, rank].set(
+        jnp.broadcast_to(col, (N, CE)))
+
+
+def _routing_order(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel,
+                   row_perm=None):
     """Bucketed routing, phase A: establish the deterministic global
-    arrival order WITHOUT pushing payload through the flat comparator
-    network. The order the CPU plane's event queue imposes per
-    destination is (deliver, src, seq); the legacy path realizes it as
-    one flat 4-key variadic sort over [N*CE] slots. Here:
+    arrival order and sort the payload into it. The order the CPU
+    plane's event queue imposes per destination is (deliver, src, seq);
+    the legacy path realizes it as one flat 4-key variadic sort over
+    [N*CE] slots. Here:
 
     - a row-local stable seq RANK (an [N, CE, CE] pairwise compare —
       CE is small, so this beats a row sort the same way the RR qdisc's
       rank tensors do) permutes each source row into seq order, so the
       flat slot index itself encodes the (src, seq) tiebreak;
-    - ONE flat sort then carries just the routing key pair — destination
-      bucket + sign-biased deliver time, the 64-bit (dst | deliver) key
-      expressed as two uint32/int32 words under the plane's 32-bit dtype
-      discipline — plus the flat slot index as the only payload;
+    - ONE flat sort then orders by the routing key triple — destination
+      bucket, sign-biased deliver time, flat slot index — and carries
+      the three payload columns (seq, sock, bytes), row-permuted like
+      the keys, as its payload: the deliver column is recovered from its
+      key and the source row from the slot index, so the arrival-ordered
+      streams come out of the sort itself. On a TPU a flat gather costs
+      5-10 ns per index and the sort far less
+      (`tools/bench_route_place.py`);
     - each bucket's [start, count) segment of the sorted sequence comes
       from a binary search of the bucket ids over the sorted keys
       (O(N log B) — vs an O(B) histogram scatter-add).
 
     Non-sent slots (and any out-of-domain dst) route to the sentinel
-    bucket N, which sorts last and is never placed. Returns
-    (row_perm [N, CE] — seq-rank position -> original column,
-    o_pos [B] — sorted order -> seq-permuted flat slot,
-    offsets/counts [N] — each bucket's segment of the sorted order).
+    bucket N, which sorts last and is never placed. Returns (streams —
+    the arrival-ordered (src, seq, sock, bytes, deliver) columns, each
+    [B], offsets/counts [N] — each bucket's segment of the sorted
+    order).
 
     `row_perm` may be passed in precomputed (the fused Pallas pipeline
     derives it inside the egress kernel while the sorted rows are still
-    VMEM-resident); None computes it here via the pairwise rank."""
+    VMEM-resident); None computes it here (`_seq_row_perm`)."""
     N, CE = eg_dst.shape
     B = N * CE
-    col = jnp.arange(CE, dtype=jnp.int32)
     if row_perm is None:
-        # stable rank of each slot within its row by (seq, column): the
-        # qdisc sort left rows in priority order, not seq order, and equal
-        # (dst, deliver) arrivals from one source must land by seq
-        earlier = ((eg_seq[:, None, :] < eg_seq[:, :, None])
-                   | ((eg_seq[:, None, :] == eg_seq[:, :, None])
-                      & (col[None, None, :] < col[None, :, None])))
-        rank = jnp.sum(earlier, axis=2, dtype=jnp.int32)  # [N, CE]
-        rows = jnp.arange(N, dtype=jnp.int32)[:, None]
-        # rank is a permutation per row ((seq, col) pairs are distinct),
-        # so the scatter inverts it: row_perm[n, rank[n, c]] = c
-        row_perm = jnp.zeros((N, CE), jnp.int32).at[rows, rank].set(
-            jnp.broadcast_to(col, (N, CE)))
+        row_perm = _seq_row_perm(eg_seq)
+    take = lambda a: _row_take(a, row_perm).reshape(-1)
     # the bucket is elementwise in (sent, dst), so it is built before the
-    # row permutation: two takes, not three
+    # row permutation: one take, not two
     bucket = jnp.where(sent & (eg_dst >= 0) & (eg_dst < N), eg_dst, N)
-    flat_dst = _row_take(bucket, row_perm).reshape(-1)
-    deliver_key = _row_take(deliver_rel, row_perm).reshape(-1) \
-        .astype(jnp.uint32) ^ _SIGN32
+    deliver_key = take(deliver_rel).astype(jnp.uint32) ^ _SIGN32
     pos = jnp.arange(B, dtype=jnp.int32)
     # (dst, deliver, pos) is a TOTAL order (pos is distinct), so the
     # unstable sort with pos promoted to a key returns exactly the
     # stable 2-key permutation — and skips the stable-sort machinery,
     # measurably cheaper through XLA:CPU's comparator path
-    o_dst, _, o_pos = jax.lax.sort((flat_dst, deliver_key, pos),
-                                   dimension=0, is_stable=False,
-                                   num_keys=3)
+    o_dst, o_key, o_pos, o_seq, o_sock, o_bytes = jax.lax.sort(
+        (take(bucket), deliver_key, pos, take(eg_seq), take(eg_sock),
+         take(eg_bytes)),
+        dimension=0, is_stable=False, num_keys=3)
+    o_deliver = (o_key ^ _SIGN32).astype(jnp.int32)
     bounds = jnp.searchsorted(
         o_dst, jnp.arange(N + 1, dtype=jnp.int32)).astype(jnp.int32)
     offsets, counts = bounds[:-1], bounds[1:] - bounds[:-1]
-    return row_perm, o_pos, offsets, counts
+    streams = (o_pos // CE, o_seq, o_sock, o_bytes, o_deliver)
+    return streams, offsets, counts
 
 
-def _routing_rank(sent, eg_dst, eg_seq, deliver_rel, n_valid_in,
-                  ingress_cap: int, row_perm=None):
+def _routing_rank(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel,
+                  n_valid_in, ingress_cap: int, row_perm=None):
     """Section 5a (packed): counting placement over the bucketed order.
     Each destination row accepts the first `take` items of its bucket's
     sorted segment — exactly the items whose in-bucket rank fits the
     row's free slots — so placement reduces to per-bucket [N] arithmetic
     over the segment bounds; no per-item destination indices are ever
-    materialized. Returns (row_perm, o_pos, offsets, take [N], overflow
-    [N])."""
-    row_perm, o_pos, offsets, counts = _routing_order(
-        sent, eg_dst, eg_seq, deliver_rel, row_perm)
+    materialized. Returns (streams, offsets, take [N], overflow [N]);
+    `streams` are `_routing_order`'s arrival-ordered payload columns."""
+    streams, offsets, counts = _routing_order(
+        sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel, row_perm)
     # per-bucket arithmetic is exact: occupancy never exceeds capacity,
     # so free = CI - n_valid >= 0; arrivals past the free slots drop
     take_n = jnp.minimum(counts, jnp.int32(ingress_cap) - n_valid_in)
     overflow = jnp.maximum(counts + n_valid_in - ingress_cap, 0)
-    return row_perm, o_pos, offsets, take_n, overflow
+    return streams, offsets, take_n, overflow
 
 
-def _routing_place(row_perm, o_pos, offsets, take_n, n_valid_in, eg_seq,
-                   eg_bytes, eg_sock, deliver_rel, in_deliver_c, in_src_c,
-                   in_seq_c, in_sock_c, in_bytes_c, in_valid_c):
-    """Section 5b (packed): land the payload columns with ONE fused
-    gather per column (stacked into a single [6, ...] gather) — no flat
-    scatters at all. Each merged ingress row is a select between its
-    existing entries and its bucket's contiguous segment of the
-    arrival-sorted stream; the stream itself is addressed through the
-    composed permutation (sorted position -> seq-permuted slot ->
-    original slot), so the payload columns are read straight from their
-    original layout and never materialize any intermediate."""
-    N, CI = in_src_c.shape
-    CE = row_perm.shape[1]
-    B = N * CE
-    flat = lambda a: a.reshape(-1)
-    # sorted position -> original flat slot (row-major)
-    g = (o_pos // CE) * CE + flat(row_perm)[o_pos]
-    streams = jnp.stack([
-        (o_pos // CE).astype(jnp.int32),  # src == source row
-        flat(eg_seq)[g], flat(eg_sock)[g], flat(eg_bytes)[g],
-        flat(deliver_rel)[g],
-        jnp.ones((B,), jnp.int32),  # arrivals are valid
-    ])
-    bases = jnp.stack([
-        flat(in_src_c), flat(in_seq_c), flat(in_sock_c), flat(in_bytes_c),
-        flat(jnp.where(in_valid_c, in_deliver_c, I32_MAX)),
-        flat(in_valid_c.astype(jnp.int32)),
-    ])
-    combined = jnp.concatenate([bases, streams], axis=1)  # [6, N*CI + B]
+def _routing_place(streams, offsets, take_n, n_valid_in, in_deliver_c,
+                   in_src_c, in_seq_c, in_sock_c, in_bytes_c, in_valid_c):
+    """Section 5b (packed): land the arrival-ordered streams with ONE
+    gather of stacked [B, 5] stream rows — no flat scatters at all.
+    Each merged ingress row is a select between its existing entries
+    and its bucket's contiguous segment of the stream. The gather reads
+    the streams alone: one over the streams concatenated with the
+    ingress rows compiles to about five times the code
+    (`tools/bench_route_place.py`), and generated code counts in the
+    device's memory."""
+    CI = in_src_c.shape[1]
+    B = streams[0].shape[0]
     ci_col = jnp.arange(CI, dtype=jnp.int32)[None, :]
     nv = n_valid_in[:, None]
     append = (ci_col >= nv) & (ci_col < nv + take_n[:, None])
@@ -1360,11 +1358,12 @@ def _routing_place(row_perm, o_pos, offsets, take_n, n_valid_in, eg_seq,
     # non-append lanes keep the base value (compaction garbage included,
     # exactly like the reference scatters, which never touch them)
     stream_idx = jnp.clip(offsets[:, None] + ci_col - nv, 0, B - 1)
-    rows = jnp.arange(N, dtype=jnp.int32)[:, None]
-    idx = jnp.where(append, N * CI + stream_idx, rows * CI + ci_col)
-    merged = combined[:, idx]  # one [6, N, CI] gather
-    return (merged[0], merged[1], merged[2], merged[3], merged[4],
-            merged[5] != 0)
+    arrivals = jnp.stack(streams, axis=1)[stream_idx]  # [N, CI, 5]
+    bases = (in_src_c, in_seq_c, in_sock_c, in_bytes_c,
+             jnp.where(in_valid_c, in_deliver_c, I32_MAX))
+    return (*(jnp.where(append, arrivals[..., k], b)
+              for k, b in enumerate(bases)),
+            append | in_valid_c)
 
 
 def _routing_rank_legacy(sent, eg_dst, eg_seq, eg_bytes, eg_sock,
@@ -1461,13 +1460,13 @@ def _route_scatter(sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
     CI = in_src_c.shape[1]
     if packed_sort:
         with jax.named_scope("routing_rank"):
-            row_perm, o_pos, offsets, take_n, overflowed = _routing_rank(
-                sent, eg_dst, eg_seq, deliver_rel, n_valid_in, CI)
+            streams, offsets, take_n, overflowed = _routing_rank(
+                sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel,
+                n_valid_in, CI)
         with jax.named_scope("routing_place"):
             merged = _routing_place(
-                row_perm, o_pos, offsets, take_n, n_valid_in, eg_seq,
-                eg_bytes, eg_sock, deliver_rel, in_deliver_c, in_src_c,
-                in_seq_c, in_sock_c, in_bytes_c, in_valid_c)
+                streams, offsets, take_n, n_valid_in, in_deliver_c,
+                in_src_c, in_seq_c, in_sock_c, in_bytes_c, in_valid_c)
         return (*merged, overflowed)
     with jax.named_scope("routing_rank"):
         (flat_idx, ok, o_deliver, o_src, o_seq, o_bytes, o_sock,

@@ -21,11 +21,12 @@ Sections (superset of the window step's numbered stages):
 - ``ingress_compact`` — surviving-ingress compaction sort (4)
 - ``routing_scatter`` — the full routing stage (5): rank + placement
 - ``routing_rank``    — routing sub-section 5a: the bucketed order
-  (row seq-rank + diet flat sort + histogram/prefix offsets; on the
-  legacy path, the variadic flat sort + grouped ranks)
+  (row seq-rank + the diet flat sort, which carries the payload
+  columns, + bucket bounds; on the legacy path, the variadic flat sort
+  + grouped ranks)
 - ``routing_place``   — routing sub-section 5b: landing the payload
-  columns into the destination ingress rows (the fused gather-scatters;
-  on the legacy path, the per-column scatters)
+  columns into the destination ingress rows (one stacked gather; on
+  the legacy path, the per-column scatters)
 - ``release_due``     — due split/presentation sort (5b, direct mode)
 - ``codel_drain``     — the router CoDel/relay micro-step (5b, AQM mode)
 - ``egress_compact``  — leftover-egress compaction sort (6)
@@ -286,15 +287,14 @@ def profile_sections(n_hosts: int, *, reps: int = 20,
     # the routing sub-sections (5a rank / 5b place) per sort mode; the
     # place inputs are the rank outputs, precomputed untimed
     if packed_sort:
-        route_rank = jax.jit(lambda s, d, q, dl, nv: _routing_rank(
-            s, d, q, dl, nv, CI))
-        rank_out = jax.block_until_ready(route_rank(
-            sent, eg_dst, eg_seq, deliver_rel, n_valid_in))
+        route_rank = jax.jit(lambda s, d, q, k, b, dl, nv: _routing_rank(
+            s, d, q, k, b, dl, nv, CI))
+        rank_args = (sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel,
+                     n_valid_in)
+        rank_out = jax.block_until_ready(route_rank(*rank_args))
         route_place = jax.jit(_routing_place)
-        place_args = (*rank_out[:4], n_valid_in, eg_seq, eg_bytes,
-                      eg_sock, deliver_rel, in_deliver_c, in_src_c,
+        place_args = (*rank_out[:3], n_valid_in, in_deliver_c, in_src_c,
                       in_seq_c, in_sock_c, in_bytes_c, in_valid_c)
-        rank_args = (sent, eg_dst, eg_seq, deliver_rel, n_valid_in)
     else:
         route_rank = jax.jit(lambda s, d, q, b, k, dl, nv:
                              _routing_rank_legacy(s, d, q, b, k, dl, nv,

@@ -459,15 +459,15 @@ def test_real_entry_passes_checked_in_budget():
 def test_worklist_names_the_rank_place_materialization():
     """The acceptance handoff: window_step's ranked worklist leads
     with the routing-stage materializations ROADMAP-4 targets — the
-    stacked [6, N, CE] place-payload gather and the routing flat
-    sort."""
+    place gather of the stacked [B, 5] stream rows and the routing
+    flat sort."""
     entry = [e for e in default_cost_entries()
              if e.key.endswith("window_step[lean]")][0]
     bounds = costmodel.entry_costs(entry)["boundaries"]
     head = bounds[:3]
-    # the stacked place gather: [6, N, CI] on older XLA, laid out as
-    # [N*CI, 6, 1] by JAX 0.9's
-    assert any(s in ("s32[6,4,8]", "s32[32,6,1]")
+    # the place gather: one 5-wide stream row per ingress lane, laid
+    # out as [N*CI, 1, 5]
+    assert any(s == "s32[32,1,5]"
                for b in head for s in b["shapes"]), \
         [b["shapes"] for b in head]
     assert any("sort" in b["producer"] for b in head), \

@@ -15,7 +15,11 @@ tests/test_plane_sortdiet.py's base matrix does not reach:
 - the fused Pallas routing kernel (`tpu/pallas_route.py`, interpret
   mode on CPU) directly against the XLA scatters, with overflow forced;
 - the profiler's routing_rank/routing_place split and the bench
-  `sections` plumbing (tools/compare_runs.py --bench).
+  `sections` plumbing (tools/compare_runs.py --bench);
+- the routing stage alone, XLA and Pallas, against the variadic
+  reference over the ring widths the plane compiles, with the deliver
+  column recovered from the sort key at the int32 extremes, and under
+  vmap.
 """
 
 import json
@@ -385,3 +389,85 @@ def test_routing_rank_seq_tiebreak_vs_row_position():
         assert np.array_equal(np.asarray(la), np.asarray(lb))
     for k in got[1]:
         assert np.array_equal(np.asarray(got[1][k]), np.asarray(ref[1][k]))
+
+
+# -- the routing stage alone against the variadic reference ---------------
+
+I32 = np.iinfo(np.int32)
+
+
+def routing_inputs(rng, n, ce, ci):
+    """One routing stage's inputs: sent and unsent slots (the unsent
+    with the dead-lane dst -1 or any value), sent packets to hosts past
+    the last (never placed), hot destinations whose rows fill past
+    capacity, some rows already full, duplicate seqs in a row, and
+    deliver times over the whole int32 range, extremes included."""
+    sent = rng.integers(0, 4, (n, ce)) > 0
+    eg_dst = rng.integers(0, 3, (n, ce))  # hot: buckets 0-2 overflow
+    wide = rng.integers(0, 2, (n, ce)) == 0
+    eg_dst = np.where(wide, rng.integers(0, n + 3, (n, ce)), eg_dst)
+    eg_dst = np.where(sent | (rng.integers(0, 2, (n, ce)) == 0), eg_dst,
+                      -1)
+    eg_seq = rng.integers(0, max(ce // 2, 1), (n, ce))  # duplicate seqs
+    deliver = rng.integers(-5 * MS, 15 * MS, (n, ce))
+    ties = rng.integers(0, 4, (n, ce)) == 0  # equal deliver times
+    deliver = np.where(ties, 2 * MS, deliver)
+    extreme = rng.integers(0, 10, (n, ce))
+    deliver = np.where(extreme == 0, I32.min, deliver)
+    deliver = np.where(extreme == 1, I32.max, deliver)
+    deliver = np.where(extreme == 2, -1, deliver)
+    n_valid = rng.integers(0, ci + 1, n)
+    n_valid[: n // 4] = ci  # full rows: every arrival overflows
+    in_valid = np.arange(ci)[None, :] < n_valid[:, None]
+    i32 = lambda a: jnp.asarray(a, jnp.int32)
+    garbage = lambda: i32(rng.integers(-100, 1 << 20, (n, ci)))
+    return (jnp.asarray(sent), i32(eg_dst), i32(eg_seq),
+            i32(rng.integers(100, 1500, (n, ce))),
+            i32(rng.integers(0, 40, (n, ce))), i32(deliver),
+            garbage(), garbage(), garbage(), garbage(), garbage(),
+            jnp.asarray(in_valid), i32(n_valid))
+
+
+def route(kernel, packed_sort=True):
+    from shadow_tpu.tpu import pallas_route
+    from shadow_tpu.tpu.plane import _route_scatter
+
+    if kernel == "pallas":
+        return pallas_route.route_place
+    return lambda *a: _route_scatter(*a, packed_sort=packed_sort)
+
+
+@pytest.mark.parametrize("ci", [8, 32])
+@pytest.mark.parametrize("ce", [1, 8, 16, 32])
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_packed_route_matches_variadic_reference(kernel, ce, ci):
+    """The bucketed route, whose sort carries the payload and whose
+    deliver column comes back from the sort key, is bitwise the
+    `packed_sort=False` reference — merged columns, valid mask and
+    per-host overflow — over every ring width the plane compiles, with
+    overflowing rows, unplaceable destinations and int32-extreme
+    deliver times."""
+    rng = np.random.default_rng(1000 * ce + ci)
+    args = routing_inputs(rng, 16, ce, ci)
+    got = jax.jit(route(kernel))(*args)
+    ref = jax.jit(route("xla", packed_sort=False))(*args)
+    for k, (la, lb) in enumerate(zip(got, ref)):
+        assert np.array_equal(np.asarray(la), np.asarray(lb)), k
+    assert int(got[-1].sum()) > 0  # overflow exercised
+    placed = np.asarray(got[5]) & ~np.asarray(args[11])
+    deliver = np.asarray(got[4])[placed]
+    if ce >= 8:  # extremes placed, so the key round trip is exercised
+        assert {I32.min, -1}.issubset(set(deliver.tolist())), deliver
+
+
+def test_packed_route_under_vmap_matches_reference():
+    """Over a batch of worlds (`drive_ensemble`'s vmap), the bucketed
+    route equals the reference world by world."""
+    rng = np.random.default_rng(5)
+    worlds = [routing_inputs(rng, 16, 16, 8) for _ in range(3)]
+    batched = [jnp.stack(col) for col in zip(*worlds)]
+    got = jax.jit(jax.vmap(route("xla")))(*batched)
+    ref = jax.jit(route("xla", packed_sort=False))
+    for w, args in enumerate(worlds):
+        for k, (la, lb) in enumerate(zip(got, ref(*args))):
+            assert np.array_equal(np.asarray(la)[w], np.asarray(lb)), (w, k)
