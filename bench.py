@@ -31,10 +31,6 @@ MS = 1_000_000
 N_HOSTS = int(os.environ.get("BENCH_HOSTS", "32768"))
 ROUNDS = int(os.environ.get("BENCH_ROUNDS", "192"))
 N_NODES = int(os.environ.get("BENCH_NODES", "64"))  # graph nodes (GML-like)
-# "xla" (default), "pallas" (two-dispatch egress+route fusion), or
-# "pallas_fused" (the single rank→place→egress pipeline) — the
-# experimental.plane_kernel flag's bench-side twin (docs/performance.md)
-PLANE_KERNEL = os.environ.get("BENCH_PLANE_KERNEL", "xla")
 # BENCH_TELEMETRY=1 threads the PlaneMetrics counters through every
 # window and harvests heartbeat JSONL + a Perfetto trace into
 # BENCH_TELEMETRY_DIR every BENCH_HARVEST_EVERY windows
@@ -74,8 +70,7 @@ GROW_EVERY = int(os.environ.get("BENCH_GROW_EVERY", "16"))
 # docs/determinism.md "Worlds are theorems"), one host sync per chain
 # for the whole ensemble. The JSON gains a `worlds` record with the
 # summed events/s and the amortization ratio vs W sequential solo
-# runs (docs/performance.md "Ensemble amortization"). xla kernel,
-# fixed capacity, no telemetry — per-world ring growth would diverge
+# runs (docs/performance.md "Ensemble amortization"). Fixed capacity, no telemetry — per-world ring growth would diverge
 # array shapes across the batch, so the modes are exclusive by
 # construction.
 N_WORLDS = int(os.environ.get("BENCH_WORLDS", "0"))
@@ -105,8 +100,7 @@ TRACE_PATH = os.environ.get("BENCH_TRACE", "")
 SPAWN_PER_DELIVERY = 1
 
 
-def phold_chain(world: dict, kernel: str, *, faults=None,
-                donate: bool = True):
+def phold_chain(world: dict, *, faults=None, donate: bool = True):
     """The bench's PHOLD window chain over `world`
     (`profiling.build_world`): one compiled `lax.scan` of
     `window_step` + on-device respawn over the round ids, returning
@@ -136,9 +130,8 @@ def phold_chain(world: dict, kernel: str, *, faults=None,
         state0 = state
         shift = jnp.where(round_idx == 0, jnp.int32(0), window)
         out = window_step(state, params, key, shift, window,
-                          rr_enabled=False, kernel=kernel,
-                          faults=faults, metrics=metrics,
-                          hist=hist)
+                          rr_enabled=False, faults=faults,
+                          metrics=metrics, hist=hist)
         ((state, delivered, _next_ev), metrics, _g, hist,
          _fr) = unpack_planes(out, metrics=metrics, hist=hist)
         # ingress-ring overflow (the routing stage's drops) — the
@@ -194,8 +187,7 @@ def phold_chain(world: dict, kernel: str, *, faults=None,
     return chain
 
 
-def bench_tpu() -> tuple[float, int, dict | None, dict, dict | None,
-                         dict]:
+def bench_tpu() -> tuple[float, int, dict | None, dict | None, dict]:
     import jax
     import jax.numpy as jnp
 
@@ -207,16 +199,6 @@ def bench_tpu() -> tuple[float, int, dict | None, dict, dict | None,
         raise SystemExit(
             f"BENCH_CAPACITY={CAPACITY_MODE!r}: expected "
             f"fixed|strict|elastic")
-    if PLANE_KERNEL != "xla" and EGRESS_CAP & (EGRESS_CAP - 1):
-        # bench-side twin of the config-time ConfigError: the fused
-        # Pallas kernels' bitonic row sorts need power-of-two rings
-        # (shadow_tpu/tpu/pallas_egress.py / pallas_pipeline.py) — fail
-        # before tracing
-        raise SystemExit(
-            f"BENCH_PLANE_KERNEL={PLANE_KERNEL} needs a power-of-two "
-            f"BENCH_EGRESS_CAP, got {EGRESS_CAP}; pick a power of two "
-            f"or use the xla kernel")
-
     N, M = N_HOSTS, N_NODES
     # ONE definition of the PHOLD world, shared with the per-section
     # profiler (tpu/profiling.build_world): node-level path tables, 4 seed
@@ -238,16 +220,8 @@ def bench_tpu() -> tuple[float, int, dict | None, dict, dict | None,
 
         _faults = neutral_faults(N, M)
 
-    def make_chain(kernel: str):
-        return phold_chain(world, kernel, faults=_faults,
-                           donate=CAPACITY_MODE == "fixed")
-
-    # no demotion on the bench: a Pallas kernel that fails to lower or
-    # compile is an error, never a silent XLA measurement under the
-    # pallas name
-    from shadow_tpu.faults import KernelFallback
-
-    chain_call = KernelFallback(PLANE_KERNEL, make_chain, enabled=False)
+    chain_call = phold_chain(world, faults=_faults,
+                             donate=CAPACITY_MODE == "fixed")
     capacity_info: dict | None = None
     if CAPACITY_MODE != "fixed" and TELEMETRY:
         raise SystemExit(
@@ -331,7 +305,7 @@ def bench_tpu() -> tuple[float, int, dict | None, dict, dict | None,
         tracer = RunTracer(
             "bench", backend=backend_fingerprint(),
             meta={"hosts": N, "rounds": ROUNDS, "chain_len": CHAIN_LEN,
-                  "kernel": PLANE_KERNEL, "capacity": CAPACITY_MODE,
+                  "capacity": CAPACITY_MODE,
                   "telemetry": TELEMETRY})
     telemetry_info = None
     if TELEMETRY:
@@ -385,12 +359,6 @@ def bench_tpu() -> tuple[float, int, dict | None, dict, dict | None,
 
     sent = int(np.asarray(state_out.n_sent).sum())
     events = ndel + sent  # send + deliver events, like Shadow's event count
-    kernel_info = {
-        "requested": PLANE_KERNEL,
-        "used": chain_call.kernel,
-        "fell_back": chain_call.fell_back,
-        "faults_threaded": FAULTS,
-    }
     # the chained-driver amortization record (docs/performance.md "The
     # driver loop"): how many windows execute device-resident per host
     # sync — the satellite metric next to the headline events/s
@@ -403,8 +371,8 @@ def bench_tpu() -> tuple[float, int, dict | None, dict, dict | None,
         "chains": n_chains,
         "windows_per_sync": round(ROUNDS / max(n_chains, 1), 2),
     }
-    return events / wall, events, telemetry_info, kernel_info, \
-        capacity_info, driver_info
+    return events / wall, events, telemetry_info, capacity_info, \
+        driver_info
 
 
 def bench_tpu_worlds(solo_rate: float) -> dict:
@@ -788,7 +756,7 @@ def _regression_guard(value: float, fingerprint: dict):
 
 
 def main():
-    (tpu_rate, events, telemetry_info, kernel_info, capacity_info,
+    (tpu_rate, events, telemetry_info, capacity_info,
      driver_info) = bench_tpu()
     worlds_info = bench_tpu_worlds(tpu_rate) if N_WORLDS > 0 else None
     memo_info = bench_memo() if MEMO else None
@@ -805,7 +773,7 @@ def main():
                 "backend": fingerprint,
                 "driver": driver_info,
                 "telemetry": telemetry_info,
-                "kernel": kernel_info,
+                "faults_threaded": FAULTS,
                 "capacity": capacity_info,
                 "worlds": worlds_info,
                 "memo": memo_info,

@@ -13,10 +13,8 @@ order, each printing its check and its wall/compile seconds:
   on the CPU).
 - phold-32k: bench.py's PHOLD chain at its recorded shape (32,768 hosts,
   64-node graph, egress/ingress caps 16/32, 192 windows) through
-  `elastic.drive_chained_windows`, with kernels xla, pallas and
-  pallas_fused and no fallback: the canonical state digests must be
-  equal, and the first 16 windows must equal the same windows run on
-  the CPU device.
+  `elastic.drive_chained_windows`; the first 16 windows must equal the
+  same windows run on the CPU device.
 - tgen-atlas-1k-floweng: bench_ladder rung 3f through `cli.main`; every
   transfer completes, and the per-flow completion digest equals the one
   the same config gives under JAX_PLATFORMS=cpu (`FLOWENG_CPU_DIGEST`,
@@ -122,7 +120,7 @@ def corpus() -> dict:
     return {"scenarios": n}
 
 
-def _phold(kernel: str, rounds: int, device=None):
+def _phold(rounds: int, device=None):
     """One PHOLD run from a fresh world: (digest, delivered, compile s,
     run s). On `device` when given (the CPU twin), else the default."""
     import contextlib
@@ -131,7 +129,6 @@ def _phold(kernel: str, rounds: int, device=None):
     import jax.numpy as jnp
 
     from bench import phold_chain
-    from shadow_tpu.faults import KernelFallback
     from shadow_tpu.tpu import elastic, profiling
     from shadow_tpu.workloads.runner import digest_pytrees
 
@@ -146,11 +143,9 @@ def _phold(kernel: str, rounds: int, device=None):
         spawn = jnp.full((PHOLD["n_hosts"],), 10_000, jnp.int32)
         rids = jnp.arange(rounds, dtype=jnp.int32)
         t0 = time.monotonic()
-        compiled = phold_chain(world, kernel).lower(
+        chain = phold_chain(world).lower(
             state, spawn, None, None, rids).compile()
         compile_s = time.monotonic() - t0
-        # no demotion: a kernel failure is an error
-        chain = KernelFallback(kernel, lambda _k: compiled, enabled=False)
 
         def chain_fn(state, extras, rids, _per_round):
             spawn, total = extras
@@ -174,31 +169,22 @@ def phold_32k() -> dict:
 
     dev = jax.devices()[0]
     label = f"{dev.platform}/{dev.device_kind}"
-    out = {}
     n, r = PHOLD["n_hosts"], PHOLD["rounds"]
-    digests = {}
-    for kernel in ("xla", "pallas", "pallas_fused"):
-        digest, delivered, comp, run = _phold(kernel, r)
-        digests[kernel] = digest
-        out[kernel] = dict(delivered=delivered, compile_s=comp, run_s=run,
-                           delivered_per_s=delivered / run)
-        print(f"[phold-32k] {kernel} on {label}: {n} hosts x {r} windows, "
-              f"{delivered} delivered, {delivered / run:.0f} delivered/s "
-              f"(run {run:.2f} s, compile {comp:.1f} s; information, not "
-              f"a claim)", flush=True)
-    check(len(set(digests.values())) == 1,
-          f"phold-32k: kernel digests differ: {digests}")
-    print(f"[phold-32k] xla == pallas == pallas_fused canonical state "
-          f"digest {digests['xla'][:16]}", flush=True)
+    digest, delivered, comp, run = _phold(r)
+    out = dict(delivered=delivered, compile_s=comp, run_s=run,
+               delivered_per_s=delivered / run, digest=digest)
+    print(f"[phold-32k] on {label}: {n} hosts x {r} windows, "
+          f"{delivered} delivered, {delivered / run:.0f} delivered/s "
+          f"(run {run:.2f} s, compile {comp:.1f} s; information, not "
+          f"a claim)", flush=True)
 
     c = PHOLD["cpu_rounds"]
-    chip, _d, _c, _r = _phold("xla", c)
-    cpu, _d, _c, cpu_s = _phold("xla", c, device=jax.devices("cpu")[0])
+    chip, _d, _c, _r = _phold(c)
+    cpu, _d, _c, cpu_s = _phold(c, device=jax.devices("cpu")[0])
     check(chip == cpu, f"phold-32k: first {c} windows differ between "
                        f"{label} ({chip[:16]}) and the CPU ({cpu[:16]})")
     print(f"[phold-32k] first {c} windows: {label} == cpu digest "
           f"{chip[:16]} (cpu run {cpu_s:.1f} s)", flush=True)
-    out["digest"] = digests["xla"]
     return out
 
 

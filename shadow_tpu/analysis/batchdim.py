@@ -36,11 +36,11 @@ three rule families gate on the result:
 - **SL703 vmap-traceability census** — every registered entry either
   vmaps cleanly at TWO world counts with a stable primitive census
   (same graph, wider arrays — the shape-polymorphism witness), or
-  carries a written refusal rationale in ``VMAP_REFUSALS``. The
-  pallas kernels refuse (their ``pallas_call`` bodies are opaque to
-  the provenance walk), exactly like they refuse faults/guards
-  threading — registered, not silent; a refusal naming a
-  no-longer-registered entry is itself a finding.
+  carries a written refusal rationale in ``VMAP_REFUSALS`` (none
+  does today). An entry built on an opaque kernel (a ``pallas_call``
+  body is invisible to the provenance walk) would have to refuse —
+  registered, not silent; a refusal naming a no-longer-registered
+  entry is itself a finding.
 
 Soundness caveat (mirrors ``ranges.py``): SL701 proves DATAFLOW
 isolation over the jaxpr. Two constructs sit outside pure dataflow and
@@ -87,21 +87,7 @@ BATCH_WORLD_COUNTS = (2, 3)
 #: entries that REFUSE the vmap surface, with the written rationale
 #: SL703 requires (a refusal is a registered engineering decision, not
 #: a silent skip; one naming a de-registered entry is a finding)
-VMAP_REFUSALS: dict[str, str] = {
-    "shadow_tpu.tpu.plane:window_step[pallas]":
-        "pallas_call bodies are opaque to the axis-provenance walk "
-        "(the kernel is not a jaxpr at this level, and the batching "
-        "rule folds the world axis into the pallas grid), so no "
-        "world-isolation theorem exists for this entry; the xla twin "
-        "window_step[lean] proves the identical plane math, and "
-        "ensemble runs dispatch the xla kernel "
-        "(faults/guards refuse pallas the same way)",
-    "shadow_tpu.tpu.plane:window_step[pallas_fused]":
-        "same as window_step[pallas]: the fused rank->place->egress "
-        "pipeline is one opaque pallas_call; its bitwise parity with "
-        "the proven xla path is pinned by tests/test_pallas_*.py, "
-        "and drive_ensemble is documented xla-only",
-}
+VMAP_REFUSALS: dict[str, str] = {}
 
 #: (entry key, rule) -> justification for a deliberately-accepted
 #: finding — the pass-5 analogue of the jaxpr-audit allow-lists
@@ -398,7 +384,7 @@ class _WorldWalk:
                 eqn, "cross-world hazard: opaque `pallas_call` with a "
                 "world-batched operand — the kernel body is invisible "
                 "to the provenance walk (register a VMAP_REFUSALS "
-                "rationale for pallas entries instead)")
+                "rationale for such an entry instead)")
             return [None] * n_out
 
         self._find(

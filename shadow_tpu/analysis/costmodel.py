@@ -119,18 +119,16 @@ class CostEntry:
 
 def default_cost_entries() -> list[CostEntry]:
     """The budgeted surface: the window-step compile modes the drivers
-    actually dispatch (hot path, lean, flows, the fused pallas
-    pipeline), the ingest kernel, the device-resident chain, and the
-    standalone flow kernel — every builder REUSED from the jaxpr-audit
-    registry so the cost ledger and the op ledger can never diverge on
-    what an entry is. Two deliberate exclusions, both priced by the
-    seven-family proof-gate time budget (one CI step, one shared
-    cache): the two-dispatch ``window_step[pallas]`` variant
-    (``pallas_fused`` subsumes its kernels on the gating path) and
-    ``window_step[flows]`` (its compiled cost is structurally
-    ``window_step[lean]`` + the standalone ``flow_step`` kernel, both
-    budgeted here; the flow sections' fusion structure is censused on
-    ``flow_step`` where it is not diluted by the window body).
+    actually dispatch (hot path, lean, flows), the ingest kernel, the
+    device-resident chain, and the standalone flow kernel — every
+    builder REUSED from the jaxpr-audit registry so the cost ledger and
+    the op ledger can never diverge on what an entry is. One deliberate
+    exclusion, priced by the seven-family proof-gate time budget (one
+    CI step, one shared cache): ``window_step[flows]`` (its compiled
+    cost is structurally ``window_step[lean]`` + the standalone
+    ``flow_step`` kernel, both budgeted here; the flow sections' fusion
+    structure is censused on ``flow_step`` where it is not diluted by
+    the window body).
     window_step and chain_windows carry the two-shape watermark pairs
     the ROADMAP-2 shard_map fence extrapolates from."""
     from .jaxpr_audit import (_chain_entry, _compute_entry,
@@ -145,9 +143,6 @@ def default_cost_entries() -> list[CostEntry]:
                   _plane_entry(False, False, True),
                   scale_n=8,
                   scale_build=_plane_entry(False, False, True, n=8)),
-        CostEntry(f"{mod}:window_step[pallas_fused]", 4, 8,
-                  _plane_entry(False, False, True,
-                               kernel="pallas_fused")),
         CostEntry(f"{mod}:ingest_rows[planes]", 4, 8,
                   _ingest_rows_entry()),
         CostEntry(f"{mod}:chain_windows", 4, 8,

@@ -353,8 +353,7 @@ class _StubRouting:
 
 
 def _plane_entry(rr_enabled: bool, router_aqm: bool, no_loss: bool,
-                 packed_sort: bool = True, kernel: str = "xla",
-                 telemetry: bool = False, faults: bool = False,
+                 packed_sort: bool = True, telemetry: bool = False, faults: bool = False,
                  guards: bool = False, trace: bool = False,
                  n: int = 4):
     def build():
@@ -386,7 +385,7 @@ def _plane_entry(rr_enabled: bool, router_aqm: bool, no_loss: bool,
                     state, params, root, shift, window,
                     rr_enabled=rr_enabled, router_aqm=router_aqm,
                     no_loss=no_loss, packed_sort=packed_sort,
-                    kernel=kernel, faults=fault_arrays)
+                    faults=fault_arrays)
 
             return fn, (state, neutral_faults(n, m), jnp.int32(0),
                         jnp.int32(10_000_000))
@@ -397,7 +396,7 @@ def _plane_entry(rr_enabled: bool, router_aqm: bool, no_loss: bool,
                     state, params, root, shift, window,
                     rr_enabled=rr_enabled, router_aqm=router_aqm,
                     no_loss=no_loss, packed_sort=packed_sort,
-                    kernel=kernel, metrics=metrics)
+                    metrics=metrics)
 
             return fn, (state, make_metrics(n), jnp.int32(0),
                         jnp.int32(10_000_000))
@@ -408,7 +407,7 @@ def _plane_entry(rr_enabled: bool, router_aqm: bool, no_loss: bool,
                     state, params, root, shift, window,
                     rr_enabled=rr_enabled, router_aqm=router_aqm,
                     no_loss=no_loss, packed_sort=packed_sort,
-                    kernel=kernel, hist=hist, flightrec=flightrec)
+                    hist=hist, flightrec=flightrec)
 
             return fn, (state, make_histograms(n),
                         make_flightrec(0, sample_every=4, ring=64),
@@ -420,7 +419,7 @@ def _plane_entry(rr_enabled: bool, router_aqm: bool, no_loss: bool,
                     state, params, root, shift, window,
                     rr_enabled=rr_enabled, router_aqm=router_aqm,
                     no_loss=no_loss, packed_sort=packed_sort,
-                    kernel=kernel, guards=guard_state)
+                    guards=guard_state)
 
             return fn, (state, make_guards(n), jnp.int32(0),
                         jnp.int32(10_000_000))
@@ -429,7 +428,7 @@ def _plane_entry(rr_enabled: bool, router_aqm: bool, no_loss: bool,
             return plane.window_step(
                 state, params, root, shift, window,
                 rr_enabled=rr_enabled, router_aqm=router_aqm,
-                no_loss=no_loss, packed_sort=packed_sort, kernel=kernel)
+                no_loss=no_loss, packed_sort=packed_sort)
 
         return fn, (state, jnp.int32(0), jnp.int32(10_000_000))
 
@@ -838,11 +837,6 @@ def default_entries() -> list[AuditEntry]:
                    _plane_entry(False, False, True)),
         AuditEntry("window_step[legacy-sort]", "shadow_tpu.tpu.plane",
                    _plane_entry(True, True, False, packed_sort=False)),
-        AuditEntry("window_step[pallas]", "shadow_tpu.tpu.plane",
-                   _plane_entry(False, False, True, kernel="pallas")),
-        AuditEntry("window_step[pallas_fused]", "shadow_tpu.tpu.plane",
-                   _plane_entry(False, False, True,
-                                kernel="pallas_fused")),
         AuditEntry("window_step[telemetry]", "shadow_tpu.tpu.plane",
                    _plane_entry(True, True, False, telemetry=True)),
         AuditEntry("window_step[faults]", "shadow_tpu.tpu.plane",

@@ -419,11 +419,11 @@ RULES: dict[str, RuleInfo] = {
             "analysis/batchdim.py traces each entry under vmap at "
             "W=2 and W=3 and requires an identical primitive census "
             "(same graph, wider arrays — the world-count "
-            "shape-polymorphism witness). Pallas kernels refuse via "
-            "batchdim.VMAP_REFUSALS with a written rationale, "
-            "exactly like the faults/guards refusals — registered, "
-            "not silent; a refusal naming a de-registered entry is "
-            "itself a finding",
+            "shape-polymorphism witness). An entry that cannot "
+            "(an opaque kernel) refuses via batchdim.VMAP_REFUSALS "
+            "with a written rationale — registered, not silent; a "
+            "refusal naming a de-registered entry is itself a "
+            "finding",
             scope="batch registry (analysis/batchdim.batch_entries "
                   "+ batchdim.VMAP_REFUSALS)",
             fixture="fixture_vmap_refusal.py",

@@ -125,8 +125,7 @@ class RingPolicy:
     """Growth bookkeeping for the window-step drivers' two ring
     dimensions (egress CE / ingress CI). Doubling counts are per
     dimension and bounded by ``max_doublings``; growth targets are
-    always powers of two so the Pallas kernels stay eligible
-    (`tpu/pallas_egress.py`) and recompiles stay log2-bounded."""
+    always powers of two so recompiles stay log2-bounded."""
 
     mode: str = "fixed"
     max_doublings: int = 3

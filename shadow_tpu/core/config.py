@@ -182,17 +182,6 @@ class ExperimentalOptions:
     tpu_egress_cap: int = 256  # per-host device egress slots
     tpu_ingress_cap: int = 256  # per-host device in-flight slots
     tpu_compact_cap: int = 4096  # per-window compacted-delivery slots
-    # device-plane fused-kernel selector: "xla" = the packed-key sort
-    # diet + bucketed routing path (default); "pallas" = the fused
-    # Pallas kernels for egress (tpu/pallas_egress.py) and routing
-    # (tpu/pallas_route.py; FIFO qdisc only, bitwise-identical,
-    # interpret mode off-TPU). Governs the general plane's window_step
-    # drivers (bench.py via BENCH_PLANE_KERNEL, tools/profile_plane.py
-    # --kernel); the use_tpu_transport path has its own kernels and
-    # does not consult this — Manager-driven runs therefore warn
-    # loudly (ConfigError under `strict: true`) when it is set. See
-    # docs/performance.md.
-    plane_kernel: str = "xla"
 
 
 @dataclass
@@ -406,8 +395,6 @@ class FaultsOptions:
     structured WatchdogError instead of a wedged simulator; wall time
     here can only change failure detection, never results). `seed`
     overrides `general.seed` for the fault-schedule RNG stream.
-    `kernel_fallback` lets a failing Pallas plane kernel degrade to the
-    XLA path (logged loudly) instead of killing the run;
     `device_retries`/`retry_backoff`/`retry_cap`/`retry_jitter` govern
     the transient-device-error retry loop around transport dispatches:
     exponential backoff from `retry_backoff`, capped at `retry_cap`,
@@ -421,7 +408,6 @@ class FaultsOptions:
     random: Optional[dict] = None
     respawn_on_reboot: bool = True
     watchdog: Optional[int] = None  # WALL ns
-    kernel_fallback: bool = True
     device_retries: int = 3
     retry_backoff: int = 50 * simtime.MILLISECOND  # WALL ns
     retry_cap: int = 2 * simtime.SECOND  # WALL ns, backoff ceiling
@@ -750,30 +736,10 @@ def parse_config_dict(raw: dict) -> ConfigOptions:
             "within a quarter of the int32 range")
     if not cfg.hosts:
         raise ConfigError("at least one host is required")
-    if cfg.experimental.plane_kernel not in ("xla", "pallas",
-                                             "pallas_fused"):
-        raise ConfigError(
-            f"experimental.plane_kernel: expected 'xla', 'pallas', or "
-            f"'pallas_fused', got {cfg.experimental.plane_kernel!r}")
     for cap_name in ("tpu_egress_cap", "tpu_ingress_cap",
                      "tpu_compact_cap"):
         if getattr(cfg.experimental, cap_name) < 1:
             raise ConfigError(f"experimental.{cap_name} must be >= 1")
-    if cfg.experimental.plane_kernel != "xla":
-        kname = cfg.experimental.plane_kernel
-        ce = cfg.experimental.tpu_egress_cap
-        if ce & (ce - 1):
-            # the fused Pallas egress kernels' bitonic row sorts need a
-            # power-of-two egress ring (tpu/pallas_egress.py /
-            # tpu/pallas_pipeline.py); failing HERE beats the opaque
-            # trace-time death it used to be. Elastic growth always
-            # targets powers of two, so an elastic run never grows its
-            # way out of pallas eligibility.
-            raise ConfigError(
-                f"experimental.plane_kernel: {kname!r} requires a "
-                f"power-of-two egress capacity (the fused kernel's "
-                f"bitonic row sort), got tpu_egress_cap={ce}; pick a "
-                f"power of two or use plane_kernel: xla")
     from .capacity import CAPACITY_MODES
 
     if cfg.capacity.mode not in CAPACITY_MODES:

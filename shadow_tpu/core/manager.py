@@ -187,23 +187,6 @@ class Manager:
                     "periodic checkpoints are disabled for this run")
             else:
                 self._next_ckpt_ns = config.faults.checkpoint.interval
-        if config.experimental.plane_kernel != "xla":
-            # the config validates `plane_kernel: pallas` but no
-            # Manager-driven run consults it: the use_tpu_transport
-            # device path has its own fused kernels, and the CPU object
-            # plane / flow engine run no window_step at all — only the
-            # general plane's drivers (bench.py via BENCH_PLANE_KERNEL,
-            # tools/profile_plane.py --kernel, direct window_step
-            # callers) honor the flag. A silent no-op here looked like a
-            # broken feature (docs/performance.md caveat), so warn
-            # loudly / refuse under `strict: true`.
-            self._unsupported_combo(
-                f"experimental.plane_kernel: "
-                f"{config.experimental.plane_kernel!r} is not consulted "
-                "by Manager-driven runs (use_tpu_transport has its own "
-                "fused kernels; the CPU plane runs no window_step) — "
-                "this run proceeds on its default kernels; the flag "
-                "governs bench.py and tools/profile_plane.py only")
         if config.telemetry.flight_recorder.enabled:
             # the sampled hop recorder rides the device-plane WINDOW
             # drivers (bench.py, tools/chaos_smoke.py,
@@ -543,8 +526,8 @@ class Manager:
             self._status_hook = None
 
     def _unsupported_combo(self, message: str) -> None:
-        """Unsupported feature-combination handling (flow-engine combos,
-        the plane_kernel no-op): warn by default, ConfigError under
+        """Unsupported feature-combination handling (flow-engine
+        combos): warn by default, ConfigError under
         top-level `strict: true` (exit 2) — the feature the config asked
         for will NOT run, and strict callers want that to be fatal, not
         a log line."""

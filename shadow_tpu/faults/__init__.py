@@ -14,14 +14,14 @@ and self-healing execution (docs/robustness.md).
 - `watchdog`   — the round watchdog: hung managed processes become a
   structured `WatchdogError` with per-host blame.
 - `healing`    — transient-device-error retry (deterministic seeded
-  backoff) and the Pallas->XLA kernel fallback.
+  backoff).
 """
 
 from .checkpoint import (CheckpointError, load_checkpoint,  # noqa: F401
                          load_npz_checkpoint, load_plane_checkpoint,
                          prune_checkpoints, save_plane_checkpoint,
                          write_checkpoint, write_npz_checkpoint)
-from .healing import (KernelFallback, backoff_schedule,  # noqa: F401
+from .healing import (backoff_schedule,  # noqa: F401
                       is_transient_device_error, retry_transient)
 from .plane import FaultArrays, neutral_faults  # noqa: F401
 from .runstate import (RUNSTATE_SCHEMA, RunCheckpointer,  # noqa: F401
@@ -33,7 +33,7 @@ from .watchdog import HostBlame, RoundWatchdog, WatchdogError  # noqa: F401
 
 __all__ = [
     "CheckpointError", "FaultArrays", "FaultEvent", "FaultSchedule",
-    "HostBlame", "KernelFallback", "RUNSTATE_SCHEMA", "RoundWatchdog",
+    "HostBlame", "RUNSTATE_SCHEMA", "RoundWatchdog",
     "RunCheckpointer", "WatchdogError", "backoff_schedule",
     "compile_schedule", "flatten_carry", "is_transient_device_error",
     "latest_checkpoint", "load_checkpoint", "load_npz_checkpoint",

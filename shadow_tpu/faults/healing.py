@@ -1,25 +1,16 @@
-"""Self-healing execution: transient-error retry and kernel fallback.
+"""Self-healing execution: transient-error retry.
 
-Two recovery mechanisms shared by the Manager's device dispatches and
-the bench/profiler drivers (docs/robustness.md):
-
-- `retry_transient` — retry-with-backoff around a device dispatch.
-  Only errors that LOOK transient (resource exhaustion, a runtime
-  reporting UNAVAILABLE or ABORTED) are retried; anything else —
-  and exhaustion of the retry budget — re-raises so a real bug still
-  fails the run. The backoff sleeps WALL time, which can only change
-  performance, never results — but the *schedule* of sleeps is itself
-  deterministic: `backoff_schedule` derives the exact delay sequence
-  (exponential from the base, capped, seeded jitter) as a pure
-  function of (attempts, base, cap, jitter, seed, what), so two runs
-  of the same config retry on the same wall cadence and a postmortem
-  can reproduce the timing it is reading about.
-- `KernelFallback` — the Pallas->XLA degradation path. A Pallas plane
-  kernel that fails to lower/compile/execute on this backend demotes
-  the run to the bitwise-identical XLA path, ONCE, loudly; the run
-  completes instead of dying, and the fallback is recorded so CI and
-  operators see it. For the chaos tooling only: the bench and the chip
-  smoke build it with `enabled=False`, so a kernel failure is an error.
+`retry_transient` retries a device dispatch of the Manager with
+backoff (docs/robustness.md). Only errors that LOOK transient
+(resource exhaustion, a runtime reporting UNAVAILABLE or ABORTED) are
+retried; anything else — and exhaustion of the retry budget —
+re-raises so a real bug still fails the run. The backoff sleeps WALL
+time, which can only change performance, never results — but the
+*schedule* of sleeps is itself deterministic: `backoff_schedule`
+derives the exact delay sequence (exponential from the base, capped,
+seeded jitter) as a pure function of (attempts, base, cap, jitter,
+seed, what), so two runs of the same config retry on the same wall
+cadence and a postmortem can reproduce the timing it is reading about.
 """
 
 from __future__ import annotations
@@ -27,7 +18,7 @@ from __future__ import annotations
 import hashlib
 import logging
 import time as _walltime
-from typing import Callable, Optional, Tuple
+from typing import Callable, Tuple
 
 log = logging.getLogger("shadow_tpu.faults")
 
@@ -96,58 +87,3 @@ def retry_transient(fn: Callable, *args, attempts: int = 3,
                 "%.2fs): %s", what, attempt + 1, attempts, delay, e)
             _walltime.sleep(delay)
 
-
-class KernelFallback:
-    """Sticky Pallas->XLA demotion for the plane-kernel drivers.
-
-    `build(kernel)` must return a ready-to-call driver for that kernel
-    name; the builder is invoked lazily so the XLA twin is only
-    compiled if the fallback actually fires. After a fallback,
-    `self.kernel` is "xla" and `self.fell_back` records the demotion
-    (surfaced in bench JSON / chaos-smoke output)."""
-
-    def __init__(self, kernel: str, build: Callable[[str], Callable],
-                 enabled: bool = True):
-        if kernel not in ("xla", "pallas", "pallas_fused"):
-            raise ValueError(f"unknown plane kernel {kernel!r}")
-        self.kernel = kernel
-        self.fell_back = False
-        self.failure: Optional[str] = None
-        self._build = build
-        self._enabled = enabled
-        self._driver: Optional[Callable] = None
-
-    def __call__(self, *args, **kwargs):
-        if self._driver is None:
-            self._driver = self._build(self.kernel)
-        try:
-            return self._driver(*args, **kwargs)
-        except Exception as e:
-            if self.kernel == "xla" or not self._enabled:
-                raise
-            # LOUD: a silent demotion would let a broken Pallas kernel
-            # masquerade as a healthy run at XLA speed
-            log.error(
-                "pallas plane kernel failed (%s: %s) — falling back to "
-                "the bitwise-identical XLA path; the run continues but "
-                "the fused kernel is NOT being exercised",
-                type(e).__name__, e)
-            self.failure = f"{type(e).__name__}: {e}"
-            self.kernel = "xla"
-            self.fell_back = True
-            self._driver = self._build("xla")
-            try:
-                return self._driver(*args, **kwargs)
-            except Exception as e2:
-                # trace/compile-time failures (the common pallas case)
-                # leave the arguments intact and the retry succeeds; an
-                # EXECUTION-time failure after a donating dispatch may
-                # have consumed the donated input buffers, in which case
-                # the re-run dies on deleted buffers — surface the
-                # ORIGINAL kernel failure with that context instead of
-                # the confusing secondary error
-                raise RuntimeError(
-                    f"pallas plane kernel failed ({self.failure}) and "
-                    f"the XLA fallback could not re-run with the same "
-                    f"arguments (donated inputs are consumed at "
-                    f"dispatch): {type(e2).__name__}: {e2}") from e

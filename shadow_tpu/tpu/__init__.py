@@ -74,15 +74,6 @@ def require_device(plane: str) -> None:
             f"it on the CPU on purpose.")
 
 
-def pallas_interpret() -> bool:
-    """Whether the Pallas kernels run in interpret mode: only off-TPU
-    (the JAX_PLATFORMS=cpu tests). On a TPU backend every kernel is
-    compiled by Mosaic, never emulated."""
-    import jax
-
-    return jax.default_backend() != "tpu"
-
-
 def donating_jit(fun=None, donate_argnums=(0,), **jit_kwargs):
     """`jax.jit` that donates the state-pytree argument(s) so XLA aliases
     the ~20 [N, C] state buffers in place across window dispatches instead
@@ -109,7 +100,6 @@ __all__ = [
     "chain_windows",
     "donating_jit",
     "enable_compilation_cache",
-    "pallas_interpret",
     "require_device",
     "make_flow_state",
     "make_flow_tables",

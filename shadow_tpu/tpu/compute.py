@@ -13,8 +13,7 @@ program's per-(host, phase) ``compute_service_ns`` table.
 
 Like every presence plane (docs/observability.md, docs/robustness.md),
 the compute plane is a static compile-out switch on `window_step`
-(``compute=None`` removes the section entirely; pallas kernels refuse
-it like faults/guards/flows) and is **bitwise-invisible to the
+(``compute=None`` removes the section entirely) and is **bitwise-invisible to the
 simulation state**: `compute_step` reads the delivered dict the step
 already materialized and writes ONLY its own `ComputeState` — the
 SL501 full-invisibility obligation ``window_step[compute]``

@@ -550,7 +550,7 @@ def chain_windows(state: NetPlaneState, params: NetPlaneParams,
                   rng_root: jax.Array, shift0, window0_ns, runahead_ns,
                   horizon_rel, stop_rel, max_windows: int = 64, *,
                   rr_enabled: bool = True, router_aqm: bool = False,
-                  no_loss: bool = False, kernel: str = "xla",
+                  no_loss: bool = False,
                   faults: FaultArrays | None = None,
                   metrics: PlaneMetrics | None = None,
                   guards: GuardState | None = None,
@@ -599,8 +599,6 @@ def chain_windows(state: NetPlaneState, params: NetPlaneParams,
     re-arms the next-event reduction — an idle chain may sleep
     through a backlog draining, which is fine because nothing
     observes the backlog until the next delivery wakes the chain.
-    `kernel` selects the plane kernel like `window_step`
-    ("xla" | "pallas" | "pallas_fused").
 
     Returns (state, delivered, off, next_rel, n_windows[, metrics']
     [, guards'][, hist'][, flightrec'][, ws'][, fs'][, cs']) —
@@ -635,7 +633,7 @@ def chain_windows(state: NetPlaneState, params: NetPlaneParams,
         m, g, h, fr, ws, fstate, cstate = planes
         out = window_step(st, params, rng_root, shift, window_ns,
                           rr_enabled=rr_enabled, router_aqm=router_aqm,
-                          no_loss=no_loss, kernel=kernel, faults=faults,
+                          no_loss=no_loss, faults=faults,
                           metrics=m, guards=g, hist=h, flightrec=fr,
                           flows=(ft, fstate) if fstate is not None
                           else None,
@@ -974,10 +972,10 @@ def ingest_rows(state: NetPlaneState, dst: jax.Array, nbytes: jax.Array,
 # ---------------------------------------------------------------------------
 # window_step sections. Each stage of the per-window pipeline is a named
 # helper so (a) the profiler (`tpu/profiling.py`) can time every section in
-# isolation with realistic inputs and (b) alternative kernels (the packed
-# sort diet, the Pallas egress fusion) swap in per-section without touching
-# the rest of the pipeline. window_step composes them; the section
-# boundaries are exactly the numbered comments the monolithic body used.
+# isolation with realistic inputs and (b) alternative forms (the packed
+# sort diet) swap in per-section without touching the rest of the
+# pipeline. window_step composes them; the section boundaries are exactly
+# the numbered comments the monolithic body used.
 # ---------------------------------------------------------------------------
 
 
@@ -1262,8 +1260,7 @@ def _seq_row_perm(eg_seq):
         jnp.broadcast_to(col, (N, CE)))
 
 
-def _routing_order(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel,
-                   row_perm=None):
+def _routing_order(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel):
     """Bucketed routing, phase A: establish the deterministic global
     arrival order and sort the payload into it. The order the CPU
     plane's event queue imposes per destination is (deliver, src, seq);
@@ -1290,15 +1287,10 @@ def _routing_order(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel,
     bucket N, which sorts last and is never placed. Returns (streams —
     the arrival-ordered (src, seq, sock, bytes, deliver) columns, each
     [B], offsets/counts [N] — each bucket's segment of the sorted
-    order).
-
-    `row_perm` may be passed in precomputed (the fused Pallas pipeline
-    derives it inside the egress kernel while the sorted rows are still
-    VMEM-resident); None computes it here (`_seq_row_perm`)."""
+    order)."""
     N, CE = eg_dst.shape
     B = N * CE
-    if row_perm is None:
-        row_perm = _seq_row_perm(eg_seq)
+    row_perm = _seq_row_perm(eg_seq)
     take = lambda a: _row_take(a, row_perm).reshape(-1)
     # the bucket is elementwise in (sent, dst), so it is built before the
     # row permutation: one take, not two
@@ -1322,7 +1314,7 @@ def _routing_order(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel,
 
 
 def _routing_rank(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel,
-                  n_valid_in, ingress_cap: int, row_perm=None):
+                  n_valid_in, ingress_cap: int):
     """Section 5a (packed): counting placement over the bucketed order.
     Each destination row accepts the first `take` items of its bucket's
     sorted segment — exactly the items whose in-bucket rank fits the
@@ -1331,7 +1323,7 @@ def _routing_rank(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel,
     materialized. Returns (streams, offsets, take [N], overflow [N]);
     `streams` are `_routing_order`'s arrival-ordered payload columns."""
     streams, offsets, counts = _routing_order(
-        sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel, row_perm)
+        sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel)
     # per-bucket arithmetic is exact: occupancy never exceeds capacity,
     # so free = CI - n_valid >= 0; arrivals past the free slots drop
     take_n = jnp.minimum(counts, jnp.int32(ingress_cap) - n_valid_in)
@@ -1418,13 +1410,12 @@ def _routing_place_legacy(flat_idx, ok, o_deliver, o_src, o_seq, o_bytes,
 
 def _route_scatter(sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
                    in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
-                   in_valid_c, n_valid_in, *, packed_sort: bool = True,
-                   kernel: str = "xla"):
+                   in_valid_c, n_valid_in, *, packed_sort: bool = True):
     """Section 5: route sent packets into destination ingress queues,
     in the deterministic per-destination (deliver, src, seq) insertion
     order the CPU plane's event queue imposes.
 
-    Three implementations, all bitwise-identical for in-domain inputs
+    Two implementations, bitwise-identical for in-domain inputs
     (dst in [0, N), the only thing callers produce):
 
     - `packed_sort=True` (default): BUCKETED counting placement — dst is
@@ -1438,25 +1429,8 @@ def _route_scatter(sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
       that impossible input).
     - `packed_sort=False`: the pre-diet flat 4-key variadic sort, the
       parity-test reference.
-    - `kernel="pallas"`: the rank computation feeds the fused
-      per-destination-tile append kernel (`tpu.pallas_route`) instead of
-      the XLA scatters; interpret mode off-TPU, refused when faults or
-      guards are threaded (window_step enforces this at trace time).
 
     Returns the merged ingress columns + per-host overflow."""
-    if kernel == "pallas":
-        if not packed_sort:
-            raise ValueError(
-                "kernel='pallas' implements the packed/bucketed ordering "
-                "only; use kernel='xla' for the packed_sort=False parity "
-                "reference")
-        from . import pallas_route
-
-        with jax.named_scope("routing_place"):
-            return pallas_route.route_place(
-                sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
-                in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
-                in_valid_c, n_valid_in)
     CI = in_src_c.shape[1]
     if packed_sort:
         with jax.named_scope("routing_rank"):
@@ -1641,12 +1615,9 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
     placement for the flat routing stage (section 5, `_routing_rank` /
     `_routing_place`) — bitwise-identical ordering, far fewer arrays
     through the comparator networks; False compiles the original
-    variadic sorts (the parity-test reference). `kernel` (static) picks
-    the fused-kernel implementation: "xla" (default) or "pallas" — the
-    fused VMEM-resident Pallas kernels for the egress stage
-    (`tpu.pallas_egress`) and the routing scatter-append
-    (`tpu.pallas_route`), FIFO-only (requires rr_enabled=False),
-    bitwise-identical to the XLA path.
+    variadic sorts (the parity-test reference). `kernel` accepts "xla"
+    alone: the step has one implementation, and the argument stays only
+    for callers that still name it.
 
     `metrics` (static presence switch) threads the telemetry counters
     (`telemetry/metrics.PlaneMetrics`) through the step: per-host
@@ -1667,7 +1638,6 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
     counter. With faults=None (default) every fault branch is compiled
     out — bitwise-identical to the pre-fault plane — and neutral masks
     (`neutral_faults`) are bitwise-identity too (tests/test_faults.py).
-    XLA kernel only (the pallas egress fusion predates the fault gate).
 
     `guards` (static presence switch, docs/robustness.md) threads the
     runtime invariant plane (`guards/plane.GuardState`): conservation
@@ -1676,8 +1646,7 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
     pure jnp compares over values the step already materialized —
     nothing raises inside jit, nothing feeds back into simulation
     state, and guards=None compiles the section out entirely (bitwise-
-    identical; pinned by tests/test_guards.py). XLA kernel only, like
-    faults.
+    identical; pinned by tests/test_guards.py).
 
     `hist` (static presence switch, docs/observability.md
     "Distributions and the flight recorder") threads the log2-bucketed
@@ -1687,7 +1656,7 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
     one-hot sums / int32 scatter-adds over values the step already
     materialized — bitwise-invisible to simulation state, metrics, and
     guards (tests/test_flightrec.py). hist=None compiles the section
-    out. XLA kernel only, like faults and guards.
+    out.
 
     `flightrec` (static presence switch, same doc) threads the sampled
     flight recorder (`telemetry/flightrec.py`): packets whose
@@ -1696,7 +1665,7 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
     verdict) into the device-side trace ring, drained asynchronously
     at harvest boundaries. The sampling draw is an independent
     counter-based stream (like fault corruption), so recording never
-    perturbs the simulation. XLA kernel only.
+    perturbs the simulation.
 
     `flows` (static presence switch, docs/robustness.md "Flow plane")
     threads the device flow plane as a ``(FlowTables, FlowState)``
@@ -1710,8 +1679,8 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
     overflow counter (the SL501 append-only obligation
     `window_step[flows]`, same theorem as the workload generator);
     threading tables whose flows are all inactive is bitwise-inert
-    (tests/test_flows.py). flows=None compiles the section out. XLA
-    kernel only, like faults. The returned state's next_event was
+    (tests/test_flows.py). flows=None compiles the section out. The
+    returned state's next_event was
     reduced BEFORE the flow emission; chained callers re-arm it like
     the workload emission (`chain_windows`).
 
@@ -1726,7 +1695,7 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
     proves no compute taint reaches the lead outputs. The
     delivery-AND-service phase coupling lives in the scenario runner
     (`compute.gate_credits`), never here. compute=None compiles the
-    section out. XLA kernel only, like faults.
+    section out.
 
     `shift_ns` = this window's start minus the previous window's start;
     stored relative times are rebased by it. Returns
@@ -1740,51 +1709,9 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
     per host) and `next_event_rel` is the min pending delivery time
     relative to the new window start (INT32_MAX when idle).
     """
-    if kernel not in ("xla", "pallas", "pallas_fused"):
-        raise ValueError(f"unknown plane kernel {kernel!r}: "
-                         "expected 'xla', 'pallas', or 'pallas_fused'")
-    pallas_kernel = kernel != "xla"
-    if pallas_kernel and rr_enabled:
-        raise ValueError(
-            f"plane_kernel={kernel!r} fuses the FIFO qdisc only; compile "
-            "with rr_enabled=False (all-FIFO configs) or use the XLA path")
-    if pallas_kernel and not packed_sort:
-        raise ValueError(
-            f"plane_kernel={kernel!r} implements the packed/bucketed "
-            "ordering only; the packed_sort=False parity reference is an "
-            "XLA-path concept — compile with kernel='xla' to measure or "
-            "compare against the legacy variadic sorts")
-    if pallas_kernel and faults is not None:
-        raise ValueError(
-            f"plane_kernel={kernel!r} does not fuse the fault plane; "
-            "compile with kernel='xla' when a FaultArrays pytree is "
-            "threaded (the self-healing kernel fallback in "
-            "faults/healing.py does this automatically)")
-    if pallas_kernel and guards is not None:
-        raise ValueError(
-            f"plane_kernel={kernel!r} does not fuse the guard plane; "
-            "compile with kernel='xla' when a GuardState pytree is "
-            "threaded (the self-healing kernel fallback in "
-            "faults/healing.py does this automatically)")
-    if pallas_kernel and (hist is not None or flightrec is not None):
-        raise ValueError(
-            f"plane_kernel={kernel!r} does not fuse the histogram/flight-"
-            "recorder observability plane; compile with kernel='xla' "
-            "when a PlaneHistograms or FlightRecArrays pytree is "
-            "threaded (the self-healing kernel fallback in "
-            "faults/healing.py does this automatically)")
-    if pallas_kernel and flows is not None:
-        raise ValueError(
-            f"plane_kernel={kernel!r} does not fuse the flow plane; "
-            "compile with kernel='xla' when a (FlowTables, FlowState) "
-            "pair is threaded (the self-healing kernel fallback in "
-            "faults/healing.py does this automatically)")
-    if pallas_kernel and compute is not None:
-        raise ValueError(
-            f"plane_kernel={kernel!r} does not fuse the compute plane; "
-            "compile with kernel='xla' when a (ComputeTables, "
-            "ComputeState) pair is threaded (the self-healing kernel "
-            "fallback in faults/healing.py does this automatically)")
+    if kernel != "xla":
+        raise ValueError(f"unknown plane kernel {kernel!r}: the window "
+                         "step has one kernel, 'xla'")
     N, CE = state.eg_dst.shape
 
     # Each numbered section runs under a `jax.named_scope`, named after
@@ -1809,56 +1736,29 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
     # socket by per-source seq, which is monotone in emission order).
     # Send times / clamps of leftover packets were taken relative to the
     # window they were ingested in; rebase them too.
-    row_perm_fused = None
     with jax.named_scope("egress_order"):
-        if kernel == "pallas_fused":
-            from . import pallas_pipeline
-
-            (eg_prio, eg_sock, eg_dst, eg_bytes, eg_seq, eg_ctrl, eg_tsend,
-             eg_clamp, eg_valid, sendable, spent,
-             row_perm_fused) = pallas_pipeline.egress_rank_stage(
-                state.eg_valid, state.eg_prio, state.eg_bytes,
-                state.eg_tsend, state.eg_clamp, state.eg_dst, state.eg_seq,
-                state.eg_sock, state.eg_ctrl, balance, shift_ns)
-            balance = balance - spent
-            rr_sent = state.rr_sent
-        elif kernel == "pallas":
-            from . import pallas_egress
-
-            (perm, eg_bytes, eg_tsend, eg_clamp, eg_valid,
-             sendable, spent) = pallas_egress.egress_order_gate(
-                state.eg_valid, state.eg_prio, state.eg_bytes, state.eg_tsend,
-                state.eg_clamp, balance, shift_ns)
-            take = lambda a: _row_take(a, perm)
-            eg_prio, eg_sock, eg_dst = (take(state.eg_prio),
-                                        take(state.eg_sock),
-                                        take(state.eg_dst))
-            eg_seq, eg_ctrl = take(state.eg_seq), take(state.eg_ctrl)
-            balance = balance - spent
-            rr_sent = state.rr_sent
-        else:
-            eg_tsend_rb = jnp.where(state.eg_valid, state.eg_tsend - shift_ns, 0)
-            eg_clamp_rb = jnp.where(
-                state.eg_valid & (state.eg_clamp != NO_CLAMP),
-                state.eg_clamp - shift_ns, state.eg_clamp,
-            )
-            qkey1, qkey2, rr_aux = _qdisc_keys(state, params,
-                                               rr_enabled=rr_enabled)
-            (eg_prio, eg_sock, eg_dst, eg_bytes, eg_seq, eg_ctrl, eg_tsend,
-             eg_clamp, eg_valid) = _egress_order(
-                state, qkey1, qkey2, eg_tsend_rb, eg_clamp_rb,
-                rr_enabled=rr_enabled, packed_sort=packed_sort)
-            if faults is not None:
-                # 2f. a crashed / link-down host transmits nothing: its queued
-                # egress drops HERE, before the token gate (dead hosts spend
-                # no bandwidth), counted once per slot — the slots leave the
-                # queue, so a multi-window outage never double-counts
-                up_src = (faults.host_alive & faults.link_up)[:, None]
-                fault_purged = eg_valid & ~up_src
-                eg_valid = eg_valid & up_src
-            sendable, balance = _token_gate(eg_valid, eg_bytes, balance)
-            rr_sent = (_rr_advance(eg_sock, eg_valid, sendable, rr_aux)
-                       if rr_enabled else state.rr_sent)
+        eg_tsend_rb = jnp.where(state.eg_valid, state.eg_tsend - shift_ns, 0)
+        eg_clamp_rb = jnp.where(
+            state.eg_valid & (state.eg_clamp != NO_CLAMP),
+            state.eg_clamp - shift_ns, state.eg_clamp,
+        )
+        qkey1, qkey2, rr_aux = _qdisc_keys(state, params,
+                                           rr_enabled=rr_enabled)
+        (eg_prio, eg_sock, eg_dst, eg_bytes, eg_seq, eg_ctrl, eg_tsend,
+         eg_clamp, eg_valid) = _egress_order(
+            state, qkey1, qkey2, eg_tsend_rb, eg_clamp_rb,
+            rr_enabled=rr_enabled, packed_sort=packed_sort)
+        if faults is not None:
+            # 2f. a crashed / link-down host transmits nothing: its queued
+            # egress drops HERE, before the token gate (dead hosts spend
+            # no bandwidth), counted once per slot — the slots leave the
+            # queue, so a multi-window outage never double-counts
+            up_src = (faults.host_alive & faults.link_up)[:, None]
+            fault_purged = eg_valid & ~up_src
+            eg_valid = eg_valid & up_src
+        sendable, balance = _token_gate(eg_valid, eg_bytes, balance)
+        rr_sent = (_rr_advance(eg_sock, eg_valid, sendable, rr_aux)
+                   if rr_enabled else state.rr_sent)
 
     # --- 3. loss sampling + latency lookup ------------------------------
     with jax.named_scope("loss_latency"):
@@ -1899,22 +1799,11 @@ def window_step(state: NetPlaneState, params: NetPlaneParams, rng_root: jax.Arra
         (in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
          in_valid_c, n_valid_in) = _compact_ingress(
             state, in_deliver, packed_sort=packed_sort)
-    if kernel == "pallas_fused":
-        from . import pallas_route
-
-        with jax.named_scope("routing_place"):
-            (in_src_m, in_seq_m, in_sock_m, in_bytes_m, in_deliver_m,
-             in_valid_m, overflowed) = pallas_route.route_place(
-                sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
-                in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
-                in_valid_c, n_valid_in, row_perm_fused)
-    else:
-        (in_src_m, in_seq_m, in_sock_m, in_bytes_m, in_deliver_m,
-         in_valid_m, overflowed) = _route_scatter(
-            sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
-            in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
-            in_valid_c, n_valid_in,
-            packed_sort=packed_sort, kernel=kernel)
+    (in_src_m, in_seq_m, in_sock_m, in_bytes_m, in_deliver_m,
+     in_valid_m, overflowed) = _route_scatter(
+        sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
+        in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c,
+        in_valid_c, n_valid_in, packed_sort=packed_sort)
     CI = in_src_m.shape[1]
 
     # --- 5b. destination side: release what this window hands the hosts --

@@ -63,13 +63,6 @@ Sections (superset of the window step's numbered stages):
   telemetry section: histogram one-hot sums, the sampling threefry,
   and the trace-ring compaction may never cost the hot path a sync or
   material compute.
-- ``fused_stage`` — the span of the window step the fused Pallas
-  pipeline covers (egress order + token gate + loss/latency + ingress
-  compaction + routing), timed under whatever ``kernel`` the profile
-  runs: the number the CI perf-smoke gate compares between
-  ``--kernel pallas`` (two dispatches + XLA glue) and ``--kernel
-  pallas_fused`` (kernel A → flat exchange → kernel B,
-  tpu/pallas_pipeline.py).
 - ``window_chain8`` — EIGHT window steps as one compiled
   `lax.scan` chain (the shared driver's device-resident unit,
   `tpu/elastic.drive_chained_windows`): divide by 8×``window_step``
@@ -128,7 +121,7 @@ DEFAULT_SECTIONS = (
     "rebase_refill", "rr_tensors", "qdisc_sort", "token_gate",
     "loss_latency", "ingress_compact", "routing_scatter", "routing_rank",
     "routing_place", "release_due", "codel_drain", "egress_compact",
-    "ingest_rows", "fused_stage", "window_step", "window_chain8",
+    "ingest_rows", "window_step", "window_chain8",
     "window_step_telemetry",
     "window_step_faults", "window_step_guards", "window_step_elastic",
     "window_step_trace", "window_step_workload", "window_step_flows",
@@ -212,8 +205,7 @@ def build_world(n_hosts: int, *, n_nodes: int = 64, egress_cap: int = 16,
 
 def profile_sections(n_hosts: int, *, reps: int = 20,
                      sections=None, rr_enabled: bool = False,
-                     packed_sort: bool = True, kernel: str = "xla",
-                     n_nodes: int = 64, egress_cap: int = 16,
+                     packed_sort: bool = True, n_nodes: int = 64, egress_cap: int = 16,
                      ingress_cap: int = 32, seed: int = 0) -> dict:
     """Time each window-step section at the given bench shape. Returns a
     JSON-ready dict. `packed_sort=False` times the pre-diet variadic
@@ -227,8 +219,8 @@ def profile_sections(n_hosts: int, *, reps: int = 20,
                         _qdisc_keys, _refill_tokens, _release_due,
                         _route_scatter, _routing_place,
                         _routing_place_legacy, _routing_rank,
-                        _routing_rank_legacy, _row_sort, _row_take,
-                        _token_gate, ingest_rows, window_step)
+                        _routing_rank_legacy, _row_sort, _token_gate,
+                        ingest_rows, window_step)
 
     from ..faults.plane import neutral_faults as _neutral_faults
     from ..guards.plane import make_guards as _clean_guards
@@ -277,8 +269,7 @@ def profile_sections(n_hosts: int, *, reps: int = 20,
         st, ind, packed_sort=packed_sort))
     (in_deliver_c, in_src_c, in_seq_c, in_sock_c, in_bytes_c, in_valid_c,
      n_valid_in) = jax.block_until_ready(compact(state, in_deliver))
-    route = jax.jit(lambda *a: _route_scatter(*a, packed_sort=packed_sort,
-                                              kernel=kernel))
+    route = jax.jit(lambda *a: _route_scatter(*a, packed_sort=packed_sort))
     (in_src_m, in_seq_m, in_sock_m, in_bytes_m, in_deliver_m, in_valid_m,
      _ovf) = jax.block_until_ready(route(
         sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel, in_deliver_c,
@@ -336,8 +327,7 @@ def profile_sections(n_hosts: int, *, reps: int = 20,
 
     def _elastic_probe(st, sh):
         out = window_step(st, params, rng_root, sh, window,
-                          rr_enabled=rr_enabled, packed_sort=packed_sort,
-                          kernel=kernel)
+                          rr_enabled=rr_enabled, packed_sort=packed_sort)
         ovf = out[0].n_overflow_dropped - st.n_overflow_dropped
         return (*out, ovf, ovf.sum())
 
@@ -361,73 +351,12 @@ def profile_sections(n_hosts: int, *, reps: int = 20,
         def probe(st, ws, sh):
             st, delivered, nxt = window_step(
                 st, params, rng_root, sh, window,
-                rr_enabled=rr_enabled, packed_sort=packed_sort,
-                kernel=kernel)
+                rr_enabled=rr_enabled, packed_sort=packed_sort)
             st, ws = _wdevice.workload_step(wl, ws, st, delivered,
                                             jnp.int32(1), window)
             return st, ws, nxt
 
         return jax.jit(probe), _wdevice.make_workload_state(prog)
-
-    def _fused_stage(st, sh):
-        """The span the fused pipeline covers (sections 2 + 3 + 4 + 5),
-        composed for the profiled `kernel` — the apples-to-apples
-        number behind the CI fused-vs-two-dispatch gate."""
-        in_dl = jnp.where(st.in_valid, st.in_deliver_rel - sh, I32_MAX)
-        balance2, _rem2 = _refill_tokens(st, params, sh)
-        if kernel == "pallas_fused":
-            from . import pallas_pipeline, pallas_route
-
-            (_p, f_sock, f_dst, f_bytes, f_seq, f_ctrl, f_tsend,
-             f_clamp, _v, f_send, _spent,
-             f_perm) = pallas_pipeline.egress_rank_stage(
-                st.eg_valid, st.eg_prio, st.eg_bytes, st.eg_tsend,
-                st.eg_clamp, st.eg_dst, st.eg_seq, st.eg_sock,
-                st.eg_ctrl, balance2, sh)
-            f_sent, _l, _rc, f_dr = _loss_latency(
-                st, params, rng_root, f_dst, f_ctrl, f_tsend, f_clamp,
-                f_send, window, no_loss=False)
-            comp = _compact_ingress(st, in_dl, packed_sort=True)
-            (m_src, m_seq, m_sock, m_bytes, m_del, m_valid,
-             f_ovf) = pallas_route.route_place(
-                f_sent, f_dst, f_seq, f_bytes, f_sock, f_dr, *comp,
-                f_perm)
-            return f_ovf, _release_due(m_del, m_src, m_seq, m_sock,
-                                       m_bytes, m_valid, window,
-                                       packed_sort=True)
-        tsr = jnp.where(st.eg_valid, st.eg_tsend - sh, 0)
-        clr = jnp.where(st.eg_valid & (st.eg_clamp != NO_CLAMP),
-                        st.eg_clamp - sh, st.eg_clamp)
-        qk1f, qk2f, _af = _qdisc_keys(st, params, rr_enabled=rr_enabled)
-        if kernel == "pallas":
-            from . import pallas_egress
-
-            (f_permE, f_bytes, f_tsend, f_clamp, _v, f_send,
-             f_spent) = pallas_egress.egress_order_gate(
-                st.eg_valid, st.eg_prio, st.eg_bytes, st.eg_tsend,
-                st.eg_clamp, balance2, sh)
-            takeE = lambda a: _row_take(a, f_permE)
-            f_dst, f_seq = takeE(st.eg_dst), takeE(st.eg_seq)
-            f_sock, f_ctrl = takeE(st.eg_sock), takeE(st.eg_ctrl)
-        else:
-            (_p, f_sock, f_dst, f_bytes, f_seq, f_ctrl, f_tsend,
-             f_clamp, f_valid) = _egress_order(
-                st, qk1f, qk2f, tsr, clr, rr_enabled=rr_enabled,
-                packed_sort=packed_sort)
-            f_send, _bal2f = _token_gate(f_valid, f_bytes, balance2)
-        f_sent, _l, _rc, f_dr = _loss_latency(
-            st, params, rng_root, f_dst, f_ctrl, f_tsend, f_clamp,
-            f_send, window, no_loss=False)
-        comp = _compact_ingress(st, in_dl, packed_sort=packed_sort)
-        (m_src, m_seq, m_sock, m_bytes, m_del, m_valid,
-         f_ovf) = _route_scatter(
-            f_sent, f_dst, f_seq, f_bytes, f_sock, f_dr, *comp,
-            packed_sort=packed_sort, kernel=kernel)
-        # the fused pipeline's span ends at the due split, so the
-        # non-fused variants time it too (apples-to-apples)
-        return f_ovf, _release_due(m_del, m_src, m_seq, m_sock,
-                                   m_bytes, m_valid, window,
-                                   packed_sort=packed_sort)
 
     def _chain8(st, sh):
         """Eight windows as one compiled scan — the shared driver's
@@ -436,14 +365,13 @@ def profile_sections(n_hosts: int, *, reps: int = 20,
             st, sh = carry
             st, delivered, _nxt = window_step(
                 st, params, rng_root, sh, window, rr_enabled=rr_enabled,
-                packed_sort=packed_sort, kernel=kernel)
+                packed_sort=packed_sort)
             return (st, window), delivered["mask"].sum(dtype=jnp.int32)
         (st, _sh), outs = jax.lax.scan(
             body, (st, sh), jnp.arange(8, dtype=jnp.int32))
         return st, outs.sum()
 
     section_calls = {
-        "fused_stage": (jax.jit(_fused_stage), (state, shift)),
         "window_chain8": (jax.jit(_chain8), (state, shift)),
         "rebase_refill": (jax.jit(rebase_refill), (state, shift)),
         "rr_tensors": (
@@ -482,35 +410,29 @@ def profile_sections(n_hosts: int, *, reps: int = 20,
         "window_step": (
             jax.jit(lambda st, sh: window_step(
                 st, params, rng_root, sh, window, rr_enabled=rr_enabled,
-                packed_sort=packed_sort, kernel=kernel)),
+                packed_sort=packed_sort)),
             (state, shift)),
         "window_step_telemetry": (
             jax.jit(lambda st, m, sh: window_step(
                 st, params, rng_root, sh, window, rr_enabled=rr_enabled,
-                packed_sort=packed_sort, kernel=kernel, metrics=m)),
+                packed_sort=packed_sort, metrics=m)),
             (state, _zero_metrics(n_hosts), shift)),
         "window_step_faults": (
-            # faults require the XLA step (the pallas fusion predates
-            # the fault gate), so this section pins kernel="xla"
             jax.jit(lambda st, f, sh: window_step(
                 st, params, rng_root, sh, window, rr_enabled=rr_enabled,
-                packed_sort=packed_sort, kernel="xla", faults=f)),
+                packed_sort=packed_sort, faults=f)),
             (state, _neutral_faults(n_hosts, n_nodes), shift)),
         "window_step_guards": (
-            # guards, like faults, refuse the pallas fusion: pin xla
             jax.jit(lambda st, g, sh: window_step(
                 st, params, rng_root, sh, window, rr_enabled=rr_enabled,
-                packed_sort=packed_sort, kernel="xla", guards=g)),
+                packed_sort=packed_sort, guards=g)),
             (state, _clean_guards(n_hosts), shift)),
         "window_step_trace": (
             # the flight recorder + histograms (docs/observability.md
-            # "Distributions and the flight recorder"); like faults/
-            # guards, the observability plane refuses the pallas
-            # fusion — pin xla
+            # "Distributions and the flight recorder")
             jax.jit(lambda st, h, f, sh: window_step(
                 st, params, rng_root, sh, window, rr_enabled=rr_enabled,
-                packed_sort=packed_sort, kernel="xla", hist=h,
-                flightrec=f)),
+                packed_sort=packed_sort, hist=h, flightrec=f)),
             (state, _zero_hist(n_hosts),
              _fresh_flightrec(0, sample_every=64, ring=4096), shift)),
         "window_step_elastic": (
@@ -542,14 +464,13 @@ def profile_sections(n_hosts: int, *, reps: int = 20,
             jax.jit(lambda st, fst, sh: window_step(
                 st, params, rng_root, sh, window,
                 rr_enabled=rr_enabled, packed_sort=packed_sort,
-                kernel="xla", flows=(_ftab, fst))),
+                flows=(_ftab, fst))),
             (state, _fstate, shift))
     if "window_step_compute" in wanted:
         # the compute plane's presence cost: a one-phase uniform
         # service table, zero backlog — the closed-form FIFO and the
         # histogram folds run at full delivered width, like the idle
-        # flow / neutral fault sections above (compute, like every
-        # presence plane, refuses the pallas fusion — pin xla)
+        # flow / neutral fault sections above
         from . import compute as _compute
 
         _ctab = _compute.make_compute_tables(
@@ -559,7 +480,7 @@ def profile_sections(n_hosts: int, *, reps: int = 20,
             jax.jit(lambda st, cst, sh: window_step(
                 st, params, rng_root, sh, window,
                 rr_enabled=rr_enabled, packed_sort=packed_sort,
-                kernel="xla", compute=(_ctab, cst))),
+                compute=(_ctab, cst))),
             (state, _cstate, shift))
 
     out_sections = {}
@@ -575,6 +496,5 @@ def profile_sections(n_hosts: int, *, reps: int = 20,
         "backend": jax.default_backend(),
         "rr_enabled": rr_enabled,
         "packed_sort": packed_sort,
-        "kernel": kernel,
         "sections": out_sections,
     }

@@ -441,20 +441,20 @@ def test_unpack_planes_shapes_and_mismatch():
 
 
 def test_fused_kernel_chain_parity():
-    """K windows through the chained driver with kernel='pallas_fused'
-    == the XLA reference, bitwise (the fused pipeline's interpret-mode
-    contract under the default loop)."""
+    """K FIFO windows through the chained driver on the packed path ==
+    the same chain on the `packed_sort=False` reference, bitwise: the
+    sort diet's parity contract under the default loop."""
     world = _world(egress_cap=8, ingress_cap=16)
     params, rng_root, window = (world["params"], world["rng_root"],
                                 world["window"])
 
-    def make_chain_fn(kernel):
+    def make_chain_fn(packed_sort):
         def round_fn(carry, rid):
             state = carry
             shift = jnp.where(rid == 0, jnp.int32(0), window)
             state, delivered, _next = window_step(
                 state, params, rng_root, shift, window,
-                rr_enabled=False, kernel=kernel)
+                rr_enabled=False, packed_sort=packed_sort)
             return state, delivered["mask"].sum(dtype=jnp.int32)
 
         @jax.jit
@@ -467,9 +467,9 @@ def test_fused_kernel_chain_parity():
         return chain_fn
 
     out = {}
-    for kernel in ("xla", "pallas_fused"):
+    for packed_sort in (True, False):
         state, (counts,) = elastic.drive_chained_windows(
-            world["state"], (None,), make_chain_fn(kernel),
+            world["state"], (None,), make_chain_fn(packed_sort),
             n_rounds=K, chain_len=3)
-        out[kernel] = (state, counts)
-    assert _leaves_equal(out["xla"], out["pallas_fused"])
+        out[packed_sort] = (state, counts)
+    assert _leaves_equal(out[True], out[False])

@@ -1,12 +1,11 @@
 """Compiles for a described TPU v5e — no chip attached, no chip time.
 
-The TPU compiler installed here refuses what the chip would refuse:
-Pallas primitives Mosaic cannot lower, unaligned slices, a program that
-does not fit the device. Interpret mode on the CPU shows none of that,
-so every kernel of the main path is compiled here with
-`interpret=False` at the bench widths (N=32,768 hosts, egress cap 16,
-ingress cap 32), and the XLA window step at 32,768 hosts is held to the
-chip's 16 GiB of HBM.
+The TPU compiler installed here refuses what the chip would refuse: an
+op the TPU backend cannot lower, a program that does not fit the
+device. The CPU backend shows none of that, so the window step is
+compiled here at the bench widths (N=32,768 hosts, egress cap 16,
+ingress cap 32) in each of its qdisc/destination variants and held to
+the chip's 16 GiB of HBM.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and every test
@@ -51,34 +50,10 @@ def _spec(sharding, shape, dtype="int32"):
     return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
 
 
-def _kernel_call(name, sh):
-    from shadow_tpu.tpu import pallas_egress, pallas_pipeline, pallas_route
-
-    row, col, one = (_spec(sh, (N, CE)), _spec(sh, (N,)), _spec(sh, ()))
-    mask = _spec(sh, (N, CE), "bool")
-    if name == "egress":
-        return pallas_egress._egress_call, (mask, row, row, row, row, col,
-                                            one)
-    if name == "egress_rank":
-        return pallas_pipeline._egress_rank_call, (
-            (mask,) + (row,) * 7 + (mask, col, one))
-    # the placement kernel's stacked stream: 8 sublanes x (CI + N*CE +
-    # one aligned window), padded to a whole lane tile
-    width = CI + N * CE + pallas_route._window_width(CI)
-    width += -width % 128
-    return pallas_route._place_call, (
-        (col, col, col, _spec(sh, (8, width)))
-        + (_spec(sh, (N, CI)),) * 6)
-
-
-@pytest.mark.parametrize("name", ["egress", "egress_rank", "place"])
-def test_pallas_kernel_compiles_for_v5e(one_chip, name):
-    fn, args = _kernel_call(name, one_chip)
-    compiled = fn.lower(*args, interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_window_step_xla_fits_v5e_hbm(one_chip):
+@pytest.mark.parametrize("rr_enabled,router_aqm",
+                         [(False, False), (True, False), (False, True)],
+                         ids=["lean", "rr", "router_aqm"])
+def test_window_step_xla_fits_v5e_hbm(one_chip, rr_enabled, router_aqm):
     import jax
     import numpy as np
 
@@ -93,7 +68,7 @@ def test_window_step_xla_fits_v5e_hbm(one_chip):
                                           sharding=one_chip)
     key = jax.random.key(0)
     step = jax.jit(lambda st, pr, k, sh, w: window_step(
-        st, pr, k, sh, w, rr_enabled=False, kernel="xla"))
+        st, pr, k, sh, w, rr_enabled=rr_enabled, router_aqm=router_aqm))
     compiled = step.lower(
         jax.tree.map(spec, state), jax.tree.map(spec, params), spec(key),
         _spec(one_chip, ()), _spec(one_chip, ())).compile()

@@ -173,25 +173,19 @@ def test_hostname_validation():
     assert "lossy.tcpserver.echo" in cfg.hosts
 
 
-def test_plane_kernel_flag_validates():
-    """experimental.plane_kernel accepts xla/pallas and rejects loudly."""
-    assert config.ConfigOptions().experimental.plane_kernel == "xla"
-    cfg = load_config_str(
-        BASIC.replace("general:",
-                      "experimental:\n  plane_kernel: pallas\ngeneral:"))
-    assert cfg.experimental.plane_kernel == "pallas"
-    with pytest.raises(ConfigError, match="plane_kernel"):
+@pytest.mark.parametrize("block,option", [
+    ("experimental", "plane_kernel: pallas"),
+    ("faults", "kernel_fallback: true"),
+])
+def test_plane_kernel_flag_validates(block, option):
+    """The removed kernel selector and its fallback switch are unknown
+    options now: a config that still sets either is refused loudly at
+    parse time, never silently ignored."""
+    assert not hasattr(config.ConfigOptions().experimental, "plane_kernel")
+    assert not hasattr(config.ConfigOptions().faults, "kernel_fallback")
+    with pytest.raises(ConfigError, match="unknown option"):
         load_config_str(
-            BASIC.replace("general:",
-                          "experimental:\n  plane_kernel: cuda\ngeneral:"))
-    # pallas_fused: accepted, with any ingress ring (only the egress
-    # ring is a bitonic width)
-    cfg = load_config_str(
-        BASIC.replace("general:",
-                      "experimental:\n  plane_kernel: pallas_fused\n"
-                      "  tpu_ingress_cap: 6\ngeneral:"))
-    assert cfg.experimental.plane_kernel == "pallas_fused"
-    assert cfg.experimental.tpu_ingress_cap == 6
+            BASIC.replace("general:", f"{block}:\n  {option}\ngeneral:"))
 
 
 def test_workload_block_yaml11_spellings():

@@ -16,8 +16,8 @@ docs/determinism.md "Growth is bitwise-invisible"):
   CapacityError under strict;
 - the flow engine's queue-slot re-runs land in the unified capacity
   trajectory; strict refuses them;
-- the `capacity:` config block and the pallas power-of-two egress
-  validation parse/fail at config time.
+- the `capacity:` config block and the device ring caps parse/fail at
+  config time.
 """
 
 import hashlib
@@ -397,21 +397,21 @@ def test_capacity_config_validation():
         load_config_str(BASE_CFG + "capacity: {bounce: 1}")
 
 
-def test_pallas_non_pow2_egress_cap_is_config_error():
-    """plane_kernel: pallas + a non-power-of-two egress cap used to die
-    at trace time deep in pallas_egress; it must be a clear ConfigError
-    at parse time (elastic growth keeps power-of-two, so an elastic run
-    never loses pallas eligibility)."""
-    with pytest.raises(ConfigError, match="power-of-two"):
-        load_config_str(
-            BASE_CFG + "experimental: {plane_kernel: pallas, "
-                       "tpu_egress_cap: 20}")
+def test_non_power_of_two_egress_cap_parses():
+    """The window step's one kernel takes any ring width: a non-power-
+    of-two egress cap is a valid config (elastic growth then doubles to
+    the next power of two)."""
     cfg = load_config_str(
-        BASE_CFG + "experimental: {plane_kernel: pallas, "
-                   "tpu_egress_cap: 32}")
-    assert cfg.experimental.tpu_egress_cap == 32
-    with pytest.raises(ConfigError, match="tpu_ingress_cap"):
-        load_config_str(BASE_CFG + "experimental: {tpu_ingress_cap: 0}")
+        BASE_CFG + "experimental: {tpu_egress_cap: 20, tpu_ingress_cap: 6}")
+    assert cfg.experimental.tpu_egress_cap == 20
+    assert cfg.experimental.tpu_ingress_cap == 6
+
+
+@pytest.mark.parametrize("cap_name", ["tpu_egress_cap", "tpu_ingress_cap",
+                                      "tpu_compact_cap"])
+def test_device_ring_cap_must_be_positive(cap_name):
+    with pytest.raises(ConfigError, match=cap_name):
+        load_config_str(BASE_CFG + f"experimental: {{{cap_name}: 0}}")
 
 
 # -- trajectory record ----------------------------------------------------

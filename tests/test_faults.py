@@ -30,7 +30,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from shadow_tpu.core.config import (ConfigError, FaultsOptions,  # noqa: E402
                                     load_config_str)
-from shadow_tpu.faults import (CheckpointError, KernelFallback,  # noqa: E402
+from shadow_tpu.faults import (CheckpointError,  # noqa: E402
                                WatchdogError, compile_schedule,
                                load_checkpoint, load_plane_checkpoint,
                                neutral_faults, prune_checkpoints,
@@ -179,12 +179,22 @@ def test_bandwidth_division_throttles_egress():
         int(np.asarray(base[-1][0].n_sent).sum())
 
 
-def test_pallas_kernel_refuses_faults():
+def test_crashed_host_on_fifo_step_neither_sends_nor_receives():
+    """The lean FIFO step (rr_enabled=False, the bench's program) gates
+    a crashed, link-down host like the RR step: it sends nothing, and
+    every packet of its own or routed toward it is a fault drop."""
     state, params = busy_world(rr_mix=False)
-    with pytest.raises(ValueError, match="pallas"):
-        window_step(state, params, jax.random.key(0), jnp.int32(0),
-                    jnp.int32(10 * MS), rr_enabled=False, kernel="pallas",
-                    faults=neutral_faults(N, N))
+    down = jnp.asarray(np.arange(N) != 0)
+    f = neutral_faults(N, N)._replace(host_alive=down, link_up=down)
+    kw = dict(windows=3, rr_enabled=False, router_aqm=False,
+              no_loss=True)
+    final = run_windows(state, params, faults=f, **kw)[-1][0]
+    neutral = run_windows(state, params, faults=neutral_faults(N, N),
+                          **kw)[-1][0]
+    assert int(np.asarray(final.n_sent)[0]) == 0
+    assert int(np.asarray(neutral.n_sent)[0]) > 0
+    assert int(np.asarray(final.n_fault_dropped).sum()) > 0
+    assert int(np.asarray(final.n_loss_dropped).sum()) == 0
 
 
 # -- schedule compile: seeded, deterministic, validated ------------------
@@ -392,7 +402,7 @@ def test_plane_checkpoint_resume_bitwise(tmp_path):
         assert np.array_equal(np.asarray(la), np.asarray(lb))
 
 
-# -- self-healing: retry + kernel fallback -------------------------------
+# -- self-healing: transient-error retry ---------------------------------
 
 def test_retry_transient_retries_then_succeeds():
     calls = []
@@ -513,36 +523,6 @@ network: {graph: {type: 1_gbit_switch}}
 faults: {retry_backoff: 2s, retry_cap: 1s}
 hosts: {a: {network_node_id: 0, processes: []}}
 """)
-
-
-def test_kernel_fallback_demotes_pallas_to_xla(caplog):
-    import logging
-
-    def build(kernel):
-        if kernel == "pallas":
-            def boom(*a):
-                raise RuntimeError("no TPU: mosaic lowering failed")
-            return boom
-        return lambda x: x + 1
-
-    fb = KernelFallback("pallas", build)
-    with caplog.at_level(logging.ERROR, logger="shadow_tpu.faults"):
-        assert fb(41) == 42
-    assert fb.fell_back and fb.kernel == "xla"
-    assert any("falling back" in r.message for r in caplog.records)
-    # sticky: later calls go straight to xla
-    assert fb(1) == 2
-
-
-def test_kernel_fallback_disabled_reraises():
-    def build(kernel):
-        def boom(*a):
-            raise RuntimeError("kaput")
-        return boom
-
-    fb = KernelFallback("pallas", build, enabled=False)
-    with pytest.raises(RuntimeError, match="kaput"):
-        fb()
 
 
 # -- the round watchdog ---------------------------------------------------

@@ -10,8 +10,7 @@
   re-ack, cumulative-ack cwnd advance, RTO expiry -> go-back-N with
   exponential backoff and counted retransmissions.
 - presence: all-inactive flow tables threaded through window_step are
-  bitwise-invisible (state + metrics); pallas kernels refuse flows;
-  unpack_planes grows the flows slot; chain_windows threads the plane
+  bitwise-invisible (state + metrics); unpack_planes grows the flows slot; chain_windows threads the plane
   and refuses the workload+flows combo.
 - scenario integration (slow): a lossy `transport: flows` incast
   completes all phases deterministically with >0 retransmits; at
@@ -387,13 +386,24 @@ def test_window_step_inactive_flows_bitwise_invisible():
     assert int(gb.checks) > int(ga.checks)
 
 
-def test_window_step_flows_refuses_pallas():
+def test_window_step_active_flow_completes_on_fifo_step():
+    """An active flow threaded through the lean FIFO step (the bench's
+    program) runs its exchange end to end: both segments go on the
+    wire, the receiver credits them in order, one cumulative ack comes
+    back, and the sender's RTO disarms — within five 10 ms windows over
+    1 ms paths."""
     state, params, root = _mini_world()
-    ft, fs = _one_flow()
-    with pytest.raises(ValueError, match="flow plane"):
-        plane.window_step(state, params, root, jnp.int32(0), WINDOW,
-                          rr_enabled=False, kernel="pallas",
-                          flows=(ft, fs))
+    ft, fs = _one_flow(stream=2)
+    step = jax.jit(lambda st, fstate, sh: plane.window_step(
+        st, params, root, sh, WINDOW, rr_enabled=False,
+        flows=(ft, fstate)))
+    sh = jnp.int32(0)
+    for _ in range(5):
+        state, _d, _n, fs = step(state, fs, sh)
+        sh = WINDOW
+    assert int(fs.snd_nxt[0]) == 2 and int(fs.rcv_nxt[0]) == 2
+    assert int(fs.snd_una[0]) == 2 and not bool(fs.rto_armed[0])
+    assert int(np.asarray(state.n_sent).sum()) == 3  # 2 data + 1 ack
 
 
 @pytest.mark.slow  # two eager window_step traces; CI's lossy-corpus

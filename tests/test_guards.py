@@ -579,30 +579,19 @@ def test_strict_promotes_flow_engine_combos(extra, needle, caplog):
         Manager(load_config_str("strict: true\n" + _flow_cfg(extra)))
 
 
-def test_plane_kernel_no_op_warns_and_strict_refuses(caplog):
-    """`experimental.plane_kernel: pallas` is validated by the config
-    but never consulted by Manager-driven runs (the use_tpu_transport
-    caveat in docs/performance.md): the Manager must say so loudly
-    instead of silently no-op-ing, and `strict: true` must refuse."""
-    import logging
-
-    from shadow_tpu.core.manager import Manager
-
+def test_plane_kernel_no_op_warns_and_strict_refuses():
+    """`experimental.plane_kernel` used to parse and then do nothing in
+    a Manager-driven run; the option is gone, so a config that sets it
+    never reaches the Manager: it is refused as an unknown option, with
+    and without `strict: true`."""
     cfg = ("general: {stop_time: 1s, seed: 1}\n"
            "experimental: {plane_kernel: pallas}\n"
            "network:\n  graph:\n    type: 1_gbit_switch\n"
            "hosts:\n  peer0:\n    network_node_id: 0\n")
-    with caplog.at_level(logging.WARNING, logger="shadow_tpu.manager"):
-        Manager(load_config_str(cfg))
-    assert any("plane_kernel" in r.message and "not consulted" in r.message
-               for r in caplog.records)
-    with pytest.raises(ConfigError, match="strict mode.*plane_kernel"):
-        Manager(load_config_str("strict: true\n" + cfg))
-    # the default kernel stays silent — no spurious warning
-    caplog.clear()
-    with caplog.at_level(logging.WARNING, logger="shadow_tpu.manager"):
-        Manager(load_config_str(cfg.replace("pallas", "xla")))
-    assert not any("plane_kernel" in r.message for r in caplog.records)
+    for text in (cfg, "strict: true\n" + cfg):
+        with pytest.raises(ConfigError,
+                           match="unknown option 'plane_kernel'"):
+            load_config_str(text)
 
 
 # -- the device retransmits producer (telemetry satellite) ----------------

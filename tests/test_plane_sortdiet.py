@@ -1,14 +1,15 @@
-"""Sort-diet + Pallas-kernel parity for the device plane (PR 2).
+"""Sort-diet parity for the device plane.
 
-The packed-key row sorts, the routing sort's recovered `sent` column,
-the `ingest_rows` single-key merge + idle gate, and the fused Pallas
-egress kernel must all be BITWISE-identical to the pre-change variadic
-paths (kept compiled-in as `packed_sort=False` / `kernel="xla"`): same
-`NetPlaneState` (every leaf, including compacted-slot contents), same
-delivered sets, same next-event scalar — across the RR/FIFO x
-router_aqm x no_loss matrix, over multiple chained windows.
+The packed-key row sorts, the routing sort's recovered `sent` column
+and the `ingest_rows` single-key merge + idle gate must all be
+BITWISE-identical to the pre-change variadic paths (kept compiled-in
+as `packed_sort=False`): same `NetPlaneState` (every leaf, including
+compacted-slot contents), same delivered sets, same next-event scalar
+— across the RR/FIFO x router_aqm x no_loss matrix, on power-of-two
+and non-power-of-two rings, over multiple chained windows.
 
-Also pins the trace-time bit-budget assertion for packed sort keys.
+Also pins the window step's one kernel (any other name is refused) and
+the trace-time bit-budget assertion for packed sort keys.
 """
 
 import numpy as np
@@ -25,7 +26,7 @@ MS = 1_000_000
 N = 8
 
 
-def busy_world(rr_mix=True, ingress_cap=8):
+def busy_world(rr_mix=True, ingress_cap=8, egress_cap=8):
     """A small world with starved token buckets (leftover egress every
     window), real loss, mixed qdiscs, duplicate priorities and colliding
     socket slots — every tiebreak path of the sorts gets exercised."""
@@ -35,7 +36,7 @@ def busy_world(rr_mix=True, ingress_cap=8):
     qrr = (np.arange(N) % 2 == 0) if rr_mix else np.zeros(N, bool)
     params = make_params(lat, loss, np.full((N,), 80_000, np.int64),
                          qdisc_rr=qrr, down_bw_bps=np.full((N,), 400_000))
-    state = make_state(N, egress_cap=8, ingress_cap=ingress_cap,
+    state = make_state(N, egress_cap=egress_cap, ingress_cap=ingress_cap,
                        params=params,
                        initial_tokens=np.asarray(params.tb_cap))
     b = 48
@@ -77,11 +78,20 @@ def assert_runs_equal(a, b, ctx):
         assert int(na) == int(nb), (ctx, w)
 
 
+@pytest.mark.parametrize("caps", [(8, 8), (6, 6)],
+                         ids=["caps8x8", "caps6x6"])
 @pytest.mark.parametrize("rr_enabled", [False, True])
 @pytest.mark.parametrize("router_aqm", [False, True])
 @pytest.mark.parametrize("no_loss", [False, True])
-def test_packed_sort_matches_variadic(rr_enabled, router_aqm, no_loss):
-    state, params = busy_world(rr_mix=rr_enabled)
+def test_packed_sort_matches_variadic(rr_enabled, router_aqm, no_loss,
+                                      caps):
+    """The packed path equals the variadic reference on the default
+    power-of-two rings and on non-power-of-two (egress, ingress) rings
+    of 6 slots, whose packed keys and select chains have no power-of-two
+    width to lean on."""
+    egress_cap, ingress_cap = caps
+    state, params = busy_world(rr_mix=rr_enabled, egress_cap=egress_cap,
+                               ingress_cap=ingress_cap)
     kw = dict(rr_enabled=rr_enabled, router_aqm=router_aqm,
               no_loss=no_loss)
     packed = run_windows(state, params, packed_sort=True, **kw)
@@ -89,50 +99,23 @@ def test_packed_sort_matches_variadic(rr_enabled, router_aqm, no_loss):
     assert_runs_equal(packed, ref, kw)
 
 
-@pytest.mark.parametrize("router_aqm", [False, True])
-@pytest.mark.parametrize("no_loss", [False, True])
-def test_pallas_kernel_matches_xla(router_aqm, no_loss):
-    """The fused Pallas egress kernel (interpret mode on CPU) is bitwise
-    the XLA path for FIFO worlds."""
+@pytest.mark.parametrize("kernel", ["pallas", "pallas_fused"])
+def test_window_step_refuses_removed_kernels(kernel):
+    """The window step has one kernel: the names of the removed Pallas
+    kernels are refused at trace time, never run as the XLA path under
+    another name."""
     state, params = busy_world(rr_mix=False)
-    kw = dict(rr_enabled=False, router_aqm=router_aqm, no_loss=no_loss)
-    pal = run_windows(state, params, kernel="pallas", **kw)
-    ref = run_windows(state, params, kernel="xla", **kw)
-    assert_runs_equal(pal, ref, kw)
-
-
-def test_pallas_rejects_rr_and_bad_kernel():
-    state, params = busy_world()
-    key = jax.random.key(0)
-    with pytest.raises(ValueError, match="FIFO"):
-        window_step(state, params, key, jnp.int32(0), jnp.int32(MS),
-                    rr_enabled=True, kernel="pallas")
     with pytest.raises(ValueError, match="unknown plane kernel"):
-        window_step(state, params, key, jnp.int32(0), jnp.int32(MS),
-                    kernel="mosaic")
-
-
-@pytest.mark.parametrize("router_aqm,no_loss",
-                         [(False, False), (True, True)])
-def test_pallas_fused_kernel_matches_xla(router_aqm, no_loss):
-    """The single rank→place→egress pipeline (tpu/pallas_pipeline.py,
-    interpret mode on CPU) is bitwise the XLA path for FIFO worlds —
-    two corners covering both compile switches (the full 2×2 runs on
-    the two-dispatch kernel above; the fused pipeline shares every
-    stage downstream of the fused span)."""
-    state, params = busy_world(rr_mix=False)
-    kw = dict(rr_enabled=False, router_aqm=router_aqm, no_loss=no_loss)
-    fused = run_windows(state, params, kernel="pallas_fused", **kw)
-    ref = run_windows(state, params, kernel="xla", **kw)
-    assert_runs_equal(fused, ref, kw)
+        window_step(state, params, jax.random.key(0), jnp.int32(0),
+                    jnp.int32(MS), rr_enabled=False, kernel=kernel)
 
 
 def test_pallas_fused_overflow_parity():
-    """A deliberately tiny ingress ring: the fused placement's
-    take/overflow arithmetic (route_place kernel B) must be bitwise the
-    XLA counting placement exactly where buckets overflow their free
-    slots — merged columns, valid mask, AND the per-host overflow
-    counter the capacity policy reads."""
+    """A deliberately tiny ingress ring: the counting placement's
+    take/overflow arithmetic must be bitwise the variadic reference
+    exactly where buckets overflow their free slots — merged columns,
+    valid mask, AND the per-host overflow counter the capacity policy
+    reads."""
     rng = np.random.default_rng(11)
     lat = rng.integers(1 * MS, 5 * MS, size=(N, N)).astype(np.int32)
     params = make_params(lat, np.zeros((N, N), np.float32),
@@ -151,33 +134,21 @@ def test_pallas_fused_overflow_parity():
         jnp.asarray(rng.integers(0, 3, b) == 0),
     )
     kw = dict(rr_enabled=False)
-    fused = run_windows(state, params, windows=3, kernel="pallas_fused",
-                        **kw)
-    ref = run_windows(state, params, windows=3, kernel="xla", **kw)
-    assert_runs_equal(fused, ref, kw)
+    packed = run_windows(state, params, windows=3, packed_sort=True, **kw)
+    ref = run_windows(state, params, windows=3, packed_sort=False, **kw)
+    assert_runs_equal(packed, ref, kw)
     drops = int(np.asarray(ref[-1][0].n_overflow_dropped).sum())
     assert drops > 0, "ingress never overflowed — dead test"
 
 
 def test_pallas_fused_non_power_of_two_ingress_matches_xla():
-    """Only the egress ring is a bitonic width: the placement kernel
-    lands any ingress capacity, bitwise the XLA path."""
+    """A FIFO world on a non-power-of-two ingress ring (6 slots): the
+    counting placement lands it bitwise like the variadic reference."""
     state, params = busy_world(rr_mix=False, ingress_cap=6)
     kw = dict(rr_enabled=False)
-    fused = run_windows(state, params, kernel="pallas_fused", **kw)
-    ref = run_windows(state, params, kernel="xla", **kw)
-    assert_runs_equal(fused, ref, kw)
-
-
-def test_pallas_rejects_non_power_of_two_cap():
-    rng = np.random.default_rng(0)
-    lat = np.full((4, 4), 5 * MS, np.int32)
-    params = make_params(lat, np.zeros((4, 4), np.float32),
-                         np.full((4,), 1_000_000_000, np.int64))
-    state = make_state(4, egress_cap=6, ingress_cap=8, params=params)
-    with pytest.raises(ValueError, match="power-of-two"):
-        window_step(state, params, jax.random.key(0), jnp.int32(0),
-                    jnp.int32(MS), rr_enabled=False, kernel="pallas")
+    packed = run_windows(state, params, packed_sort=True, **kw)
+    ref = run_windows(state, params, packed_sort=False, **kw)
+    assert_runs_equal(packed, ref, kw)
 
 
 def test_ingest_rows_packed_and_gate_match_reference():

@@ -61,12 +61,11 @@ def orders():
 
     from shadow_tpu.tpu import plane
 
-    def keys_only(sent, eg_dst, eg_seq, deliver_rel, row_perm, *payload):
+    def keys_only(sent, eg_dst, eg_seq, deliver_rel, *payload):
         """The routing sort over (bucket, deliver, slot) carrying
         `payload` (row-permuted [N, CE] columns)."""
         N, CE = eg_dst.shape
-        if row_perm is None:
-            row_perm = plane._seq_row_perm(eg_seq)
+        row_perm = plane._seq_row_perm(eg_seq)
         take = lambda a: plane._row_take(a, row_perm).reshape(-1)
         bucket = jnp.where(sent & (eg_dst >= 0) & (eg_dst < N), eg_dst, N)
         deliver_key = take(deliver_rel).astype(jnp.uint32) ^ plane._SIGN32
@@ -79,10 +78,9 @@ def orders():
         return (row_perm, o_pos, bounds[:-1], bounds[1:] - bounds[:-1],
                 *o_payload)
 
-    def gathers(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel,
-                row_perm=None):
+    def gathers(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel):
         row_perm, o_pos, offsets, counts = keys_only(
-            sent, eg_dst, eg_seq, deliver_rel, row_perm)
+            sent, eg_dst, eg_seq, deliver_rel)
         CE = row_perm.shape[1]
         flat = lambda a: a.reshape(-1)
         g = (o_pos // CE) * CE + flat(row_perm)[o_pos]
@@ -96,22 +94,20 @@ def orders():
         return (o_pos // eg_seq.shape[1], cols[:, 0], cols[:, 1],
                 cols[:, 2], cols[:, 3])
 
-    def stacked(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel,
-                row_perm=None):
+    def stacked(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel):
         row_perm, o_pos, offsets, counts = keys_only(
-            sent, eg_dst, eg_seq, deliver_rel, row_perm)
+            sent, eg_dst, eg_seq, deliver_rel)
         CE = row_perm.shape[1]
         g = (o_pos // CE) * CE + row_perm.reshape(-1)[o_pos]
         return (stacked_by(o_pos, g, eg_seq, eg_sock, eg_bytes,
                            deliver_rel), offsets, counts)
 
-    def slot_carry(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel,
-                   row_perm=None):
+    def slot_carry(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel):
         N, CE = eg_dst.shape
         slot = (jnp.arange(N, dtype=jnp.int32)[:, None] * CE
                 + jnp.arange(CE, dtype=jnp.int32)[None, :])
         _, o_pos, offsets, counts, g = keys_only(
-            sent, eg_dst, eg_seq, deliver_rel, row_perm, slot)
+            sent, eg_dst, eg_seq, deliver_rel, slot)
         return (stacked_by(o_pos, g, eg_seq, eg_sock, eg_bytes,
                            deliver_rel), offsets, counts)
 

@@ -15,13 +15,8 @@ Usage:
   python tools/chaos_smoke.py ... --resume chaos/ckpt-000000000016
                                                           # continues
 Each invocation prints ONE JSON line with the final state digest,
-drop-bucket totals, and fallback/fault bookkeeping; CI compares the
-digest of the resumed run against the uninterrupted one.
-
-`--kernel pallas` drives the step through the self-healing
-`KernelFallback`: the Pallas egress kernel cannot fuse the fault gate,
-so the driver demotes to the bitwise-identical XLA path, loudly — the
-run completes and the JSON records `fell_back: true`.
+drop-bucket totals, and fault bookkeeping; CI compares the digest of
+the resumed run against the uninterrupted one.
 
 `--guards warn|abort` threads the guard plane (`shadow_tpu/guards/`,
 docs/robustness.md) through every window: the JSON gains a `guards`
@@ -132,7 +127,6 @@ def main(argv=None) -> int:
                     help="exit abruptly (no cleanup) after this window")
     ap.add_argument("--resume", default=None,
                     help="checkpoint directory to restore and continue")
-    ap.add_argument("--kernel", choices=["xla", "pallas"], default="xla")
     ap.add_argument("--no-faults", action="store_true",
                     help="neutral masks only (the overhead-gate twin)")
     ap.add_argument("--guards", choices=["off", "warn", "abort"],
@@ -197,8 +191,8 @@ def main(argv=None) -> int:
     import jax
     import jax.numpy as jnp
 
-    from shadow_tpu.faults import (KernelFallback, load_plane_checkpoint,
-                                   neutral_faults, save_plane_checkpoint)
+    from shadow_tpu.faults import (load_plane_checkpoint, neutral_faults,
+                                   save_plane_checkpoint)
     from shadow_tpu.guards import make_guards, summarize
     from shadow_tpu.guards.plane import GuardState
     from shadow_tpu.telemetry import make_metrics
@@ -217,8 +211,7 @@ def main(argv=None) -> int:
 
         tracer = RunTracer(
             "chaos_smoke",
-            meta={"hosts": N, "windows": R, "kernel": args.kernel,
-                  "capacity": args.capacity,
+            meta={"hosts": N, "windows": R, "capacity": args.capacity,
                   "chain_len": args.chain_len,
                   "faults": not args.no_faults,
                   "memo": bool(args.memo)})
@@ -237,7 +230,7 @@ def main(argv=None) -> int:
             egress_cap=args.egress_cap, ingress_cap=args.ingress_cap,
             plane="chaos_smoke")
 
-    def build_chain(kernel: str):
+    def build_chain():
         def round_fn(carry, xs):
             (state, metrics, guards, hist, fr, spawn_seq, eg_acc,
              in_acc) = carry
@@ -247,9 +240,8 @@ def main(argv=None) -> int:
             shift = jnp.where(round_idx == 0, jnp.int32(0), window)
             out = window_step(state, world["params"], world["rng_root"],
                               shift, window, rr_enabled=False,
-                              kernel=kernel, faults=faults,
-                              metrics=metrics, guards=guards,
-                              hist=hist, flightrec=fr)
+                              faults=faults, metrics=metrics,
+                              guards=guards, hist=hist, flightrec=fr)
             (state, delivered, _next), metrics, guards, hist, fr = \
                 unpack_planes(out, metrics=metrics, guards=guards,
                               hist=hist, flightrec=fr)
@@ -296,7 +288,7 @@ def main(argv=None) -> int:
             return carry
         return chain
 
-    driver = KernelFallback(args.kernel, build_chain)
+    driver = build_chain()
 
     start_w = 0
     state = world["state"]
@@ -330,12 +322,11 @@ def main(argv=None) -> int:
 
         # the static salt folds everything the chain closure captures
         # that the carry cannot show: world shape/caps (the params +
-        # rng root are pure functions of them), the kernel choice, and
-        # the respawn constants
+        # rng root are pure functions of them) and the respawn
+        # constants
         memo_obj = memomod.ChainMemo(salt="|".join([
-            "chaos-memo-v1", f"hosts={N}", f"kernel={args.kernel}",
-            f"egcap={args.egress_cap}", f"incap={args.ingress_cap}",
-            f"faults={int(schedule is not None)}",
+            "chaos-memo-v1", f"hosts={N}", f"egcap={args.egress_cap}",
+            f"incap={args.ingress_cap}", f"faults={int(schedule is not None)}",
         ]).encode())  # default key_extra: folds r0 ALWAYS — respawn
         # traffic is round-indexed, so round translation is never safe
 
@@ -398,7 +389,7 @@ def main(argv=None) -> int:
         if memo_obj is not None and "memo" in restored["meta"]:
             # the recorded spans outlive the kill: absorb the spilled
             # cache so the resumed run reports the same memo census
-            # (salt mismatch — different world/kernel — is refused)
+            # (salt mismatch — a different world — is refused)
             n = memo_obj.absorb(restored["meta"]["memo"],
                                 restored["extra"], prefix="memo.",
                                 source=args.resume, restore=True)
@@ -637,8 +628,6 @@ def main(argv=None) -> int:
         "hosts": N,
         "windows": R,
         "resumed_from": args.resume,
-        "kernel": driver.kernel,
-        "fell_back": driver.fell_back,
         "faults_active": schedule is not None,
         "state_digest": state_digest(state, spawn_seq),
         # dead-lane payload differs between a mid-run-grown world and a
@@ -668,8 +657,7 @@ def main(argv=None) -> int:
         # the jit cache size of the step IS the compile count: one
         # entry per ring shape stepped, so elastic recompiles must stay
         # within 1 + growth events (the log2 bound CI gates on)
-        jit_step = getattr(driver, "_driver", None)
-        cache_size = getattr(jit_step, "_cache_size", lambda: None)()
+        cache_size = getattr(driver, "_cache_size", lambda: None)()
         out["capacity"] = {
             "mode": policy.mode,
             "initial": {"egress_cap": args.egress_cap,

@@ -12,7 +12,6 @@ price the pre-diet variadic sorts against the packed-key default.
     python tools/profile_plane.py                       # default shapes
     python tools/profile_plane.py --hosts 1024 --reps 5
     python tools/profile_plane.py --legacy-sort -o before.json
-    python tools/profile_plane.py --kernel pallas       # fused kernels
     python tools/profile_plane.py \
         --sections routing_scatter,routing_rank,routing_place
 
@@ -44,13 +43,6 @@ def main(argv=None) -> int:
     ap.add_argument("--legacy-sort", action="store_true",
                     help="time the pre-diet variadic sorts "
                          "(packed_sort=False) for before/after comparison")
-    ap.add_argument("--kernel",
-                    choices=("xla", "pallas", "pallas_fused"),
-                    default="xla",
-                    help="window_step plane kernel (default xla; "
-                         "pallas = two-dispatch egress+route fusion, "
-                         "pallas_fused = the single rank→place→egress "
-                         "pipeline)")
     ap.add_argument("--sections", default=None,
                     help="comma-separated subset of sections to time")
     ap.add_argument("-o", "--out", default=None,
@@ -63,7 +55,7 @@ def main(argv=None) -> int:
     for n in (int(h) for h in args.hosts.split(",") if h.strip()):
         shapes.append(profiling.profile_sections(
             n, reps=args.reps, rr_enabled=args.rr,
-            packed_sort=not args.legacy_sort, kernel=args.kernel,
+            packed_sort=not args.legacy_sort,
             n_nodes=args.nodes, egress_cap=args.egress_cap,
             ingress_cap=args.ingress_cap,
             sections=(args.sections.split(",") if args.sections else None),
