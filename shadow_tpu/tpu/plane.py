@@ -284,6 +284,41 @@ def _row_take(a, perm):
     return out
 
 
+def _segment_bounds(keys, n: int):
+    """`jnp.searchsorted(keys, arange(n + 1))` for a sorted int32 [B]
+    `keys`: bounds[q] = the number of keys below q, so bucket q's
+    segment is [bounds[q], bounds[q + 1]). A blocked search in three
+    levels over k-wide rows of the keys (padded with I32_MAX to a
+    multiple of k * k, R rows): a compare-reduce of each query against
+    every k-th row head ([n + 1, R / k]; the R / k heads are one
+    strided take, no per-query gather) picks its block of k rows, a
+    compare-reduce against that block's k heads picks its row, and one
+    against that row's k keys counts within it. Two row gathers of
+    [n + 1, k] per call, where the binary search makes
+    ceil(log2(B + 1)) scalar gathers of [n + 1] (20 at B = 524,288),
+    and XLA's gather costs 5-10 ns per index on a TPU v5e whatever its
+    width (`tools/bench_route_place.py --stage bounds`). Every index
+    is a count clipped at 0, so each lies in its array by construction.
+    k is the least power of two with k * k >= B, at most 128: the
+    blocking follows from B alone, and a small world pads to at most
+    four times its keys."""
+    B = keys.shape[0]
+    k = 1
+    while k < 128 and k * k < B:
+        k *= 2
+    R = max(-(-B // (k * k)), 1) * k
+    if R * k > B:
+        keys = jnp.concatenate(
+            [keys, jnp.full((R * k - B,), I32_MAX, keys.dtype)])
+    rows = keys.reshape(R, k)
+    heads = rows[:, 0]
+    q = jnp.arange(n + 1, dtype=jnp.int32)[:, None]
+    below = lambda a: jnp.sum(a < q, axis=1, dtype=jnp.int32)
+    blk = jnp.maximum(below(heads[::k][None, :]) - 1, 0)
+    row = blk * k + jnp.maximum(below(heads.reshape(-1, k)[blk]) - 1, 0)
+    return row * k + below(rows[row])
+
+
 def _assert_bit_budget(*fields):
     """Trace-time guard: the named (bits, what) fields must fit a single
     32-bit packed sort key. Raises at trace time (shapes and capacities
@@ -430,9 +465,9 @@ def ingest(state: NetPlaneState, src: jax.Array, dst: jax.Array,
     `packed_sort` (static) selects the bucketed flat-append diet: src is
     a bounded bucket id, so the deterministic (src, seq) append order
     needs only ONE diet sort carrying (bucket, sign-biased seq, batch
-    index) plus binary-searched bucket bounds for the counting
-    placement, and the payload columns land via one fused stacked
-    gather straight from the batch layout — vs the 9-array 2-key
+    index) plus blocked-search bucket bounds (`_segment_bounds`) for
+    the counting placement, and the payload columns land via one fused
+    stacked gather straight from the batch layout — vs the 9-array 2-key
     variadic sort it replaces (kept as the parity-test reference under
     `packed_sort=False`, bitwise-identical for in-domain src).
 
@@ -456,18 +491,17 @@ def ingest(state: NetPlaneState, src: jax.Array, dst: jax.Array,
     if packed_sort:
         # bucketed counting placement (same shape as the routing stage,
         # `_routing_rank`/`_routing_place`): ONE diet sort establishes
-        # the (src, seq) append order, binary search bounds each row's
-        # segment, and every payload column lands via one fused stacked
-        # gather — base entries where the row already had them, the
-        # segment's stream items in the appended slots
+        # the (src, seq) append order, `_segment_bounds` bounds each
+        # row's segment, and every payload column lands via one fused
+        # stacked gather — base entries where the row already had them,
+        # the segment's stream items in the appended slots
         B = src.shape[0]
         src_b = jnp.where((src >= 0) & (src < N), src, N)
         pos = jnp.arange(B, dtype=jnp.int32)
         o_src, _, o_pos = jax.lax.sort(
             (src_b, seq.astype(jnp.uint32) ^ _SIGN32, pos),
             dimension=0, is_stable=True, num_keys=2)
-        bounds = jnp.searchsorted(
-            o_src, jnp.arange(N + 1, dtype=jnp.int32)).astype(jnp.int32)
+        bounds = _segment_bounds(o_src, N)
         offsets, counts = bounds[:-1], bounds[1:] - bounds[:-1]
         take_n = jnp.minimum(counts, jnp.int32(CE) - n_valid)
         overflow = jnp.maximum(counts + n_valid - CE, 0)
@@ -1280,8 +1314,12 @@ def _routing_order(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel):
       5-10 ns per index and the sort far less
       (`tools/bench_route_place.py`);
     - each bucket's [start, count) segment of the sorted sequence comes
-      from a binary search of the bucket ids over the sorted keys
-      (O(N log B) — vs an O(B) histogram scatter-add).
+      from a blocked search of the bucket ids over the sorted keys
+      (`_segment_bounds`: a compare-reduce against every 128th row
+      head, then two [N + 1, 128] row gathers, each consumed by a
+      compare-reduce — vs the binary search's 20 scalar gathers of
+      N + 1 indices at B = 524,288, and vs an O(B) histogram
+      scatter-add).
 
     Non-sent slots (and any out-of-domain dst) route to the sentinel
     bucket N, which sorts last and is never placed. Returns (streams —
@@ -1306,8 +1344,7 @@ def _routing_order(sent, eg_dst, eg_seq, eg_sock, eg_bytes, deliver_rel):
          take(eg_bytes)),
         dimension=0, is_stable=False, num_keys=3)
     o_deliver = (o_key ^ _SIGN32).astype(jnp.int32)
-    bounds = jnp.searchsorted(
-        o_dst, jnp.arange(N + 1, dtype=jnp.int32)).astype(jnp.int32)
+    bounds = _segment_bounds(o_dst, N)
     offsets, counts = bounds[:-1], bounds[1:] - bounds[:-1]
     streams = (o_pos // CE, o_seq, o_sock, o_bytes, o_deliver)
     return streams, offsets, counts
@@ -1420,7 +1457,7 @@ def _route_scatter(sent, eg_dst, eg_seq, eg_bytes, eg_sock, deliver_rel,
 
     - `packed_sort=True` (default): BUCKETED counting placement — dst is
       a bounded key, so the rank computation is one diet flat sort over
-      (bucket, deliver, slot-index) plus binary-searched bucket bounds,
+      (bucket, deliver, slot-index) plus blocked-search bucket bounds,
       and the payload columns land via one fused stacked gather
       (`_routing_rank` / `_routing_place`). A hypothetical sent packet
       with an out-of-range dst lands in the not-placeable bucket here;

@@ -30,12 +30,21 @@ rows); `sort_carry`, `stacked` and `slot_carry` use
 `concat_place` and with `column_place` (the streams stacked [5, B],
 gathered along their second axis).
 
+Every order form bounds the buckets with `plane._segment_bounds`.
+`--stage bounds` times that step alone (`bounds()`), over the sorted
+bucket ids of the same busy window: `searchsorted` is
+`jnp.searchsorted` (a binary search, one scalar gather of N + 1
+indices per step) and `blocked` is `plane._segment_bounds` (what the
+plane runs: a compare-reduce against every 128th row head, then two
+row gathers).
+
 For each egress width it prints one JSON line per form: device ms per
 stage (median of `--runs` timed calls, each ending in
 `block_until_ready`), compile seconds and the compiled code's bytes.
 The forms' outputs must be bitwise equal, or the script fails.
 
     python tools/bench_route_place.py --rows 32768 --widths 16,128,256
+    python tools/bench_route_place.py --stage bounds --widths 16,128,256
 
 Timings mean something only on the chip; the script refuses a CPU
 backend unless `--allow-cpu` is given (a rehearsal of the script).
@@ -73,8 +82,7 @@ def orders():
         o_dst, _, o_pos, *o_payload = jax.lax.sort(
             (take(bucket), deliver_key, pos, *map(take, payload)),
             dimension=0, is_stable=False, num_keys=3)
-        bounds = jnp.searchsorted(
-            o_dst, jnp.arange(N + 1, dtype=jnp.int32)).astype(jnp.int32)
+        bounds = plane._segment_bounds(o_dst, N)
         return (row_perm, o_pos, bounds[:-1], bounds[1:] - bounds[:-1],
                 *o_payload)
 
@@ -179,6 +187,21 @@ def stages():
     return {name: _stage(*f) for name, f in forms.items()}
 
 
+def bounds(n: int):
+    """Each form of the bucket-bounds step: sorted [B] bucket ids in,
+    the [n + 1] segment bounds out."""
+    import jax.numpy as jnp
+
+    from shadow_tpu.tpu import plane
+
+    def searchsorted(o_dst):
+        return jnp.searchsorted(
+            o_dst, jnp.arange(n + 1, dtype=jnp.int32)).astype(jnp.int32)
+
+    return {"searchsorted": searchsorted,
+            "blocked": lambda o_dst: plane._segment_bounds(o_dst, n)}
+
+
 def _stage(order, place):
     import jax.numpy as jnp
 
@@ -210,6 +233,16 @@ def _inputs(rng, n: int, ce: int, ci: int):
             i32(n_valid))
 
 
+def _sorted_buckets(inputs):
+    """The routing sort's bucket column of a busy window: each sent
+    slot's destination, N for the rest, sorted."""
+    import jax.numpy as jnp
+
+    sent, eg_dst = inputs[:2]
+    n = eg_dst.shape[0]
+    return jnp.sort(jnp.where(sent, eg_dst, n).reshape(-1)), n
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--rows", type=int, default=32768)
@@ -217,9 +250,12 @@ def main(argv=None) -> int:
                     help="egress ring widths CE")
     ap.add_argument("--ingress", type=int, default=32,
                     help="ingress ring width CI")
+    ap.add_argument("--stage", choices=("route", "bounds"),
+                    default="route",
+                    help="the whole routing stage, or its bucket bounds")
     ap.add_argument("--forms",
-                    default="parent,sort_carry,stacked,slot_carry,"
-                            "carry_concat,carry_columns")
+                    help="comma-separated forms, the first the reference "
+                         "(default: every form of the stage)")
     ap.add_argument("--runs", type=int, default=7, help="timed calls")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--allow-cpu", action="store_true")
@@ -233,16 +269,19 @@ def main(argv=None) -> int:
         print("bench_route_place: no accelerator (pass --allow-cpu to "
               "rehearse)", file=sys.stderr)
         return 2
-    forms = args.forms.split(",")
     rng = np.random.default_rng(args.seed)
     for ce in (int(w) for w in args.widths.split(",")):
         inputs = _inputs(rng, args.rows, ce, args.ingress)
+        if args.stage == "route":
+            table = stages()
+        else:
+            keys, n = _sorted_buckets(inputs)
+            table, inputs = bounds(n), (keys,)
+        forms = args.forms.split(",") if args.forms else list(table)
         outs = {}
-        for name, stage in stages().items():
-            if name not in forms:
-                continue
+        for name in forms:
             t0 = time.perf_counter()
-            compiled = jax.jit(stage).lower(*inputs).compile()
+            compiled = jax.jit(table[name]).lower(*inputs).compile()
             compile_s = time.perf_counter() - t0
             outs[name] = jax.block_until_ready(compiled(*inputs))
             times = []
@@ -251,15 +290,18 @@ def main(argv=None) -> int:
                 jax.block_until_ready(compiled(*inputs))
                 times.append(time.perf_counter() - t0)
             print(json.dumps({
-                "rows": args.rows, "egress": ce, "ingress": args.ingress,
-                "form": name, "ms": statistics.median(times) * 1e3,
+                "stage": args.stage, "rows": args.rows, "egress": ce,
+                "ingress": args.ingress, "form": name,
+                "ms": statistics.median(times) * 1e3,
                 "compile_s": compile_s,
                 "code_bytes": compiled.memory_analysis()
                 .generated_code_size_in_bytes,
                 "device": dev.device_kind,
             }), flush=True)
-        ref = outs.pop(forms[0])
+        as_tuple = lambda o: o if isinstance(o, tuple) else (o,)
+        ref = as_tuple(outs.pop(forms[0]))
         for name, out in outs.items():
+            out = as_tuple(out)
             if not all(np.array_equal(np.asarray(a), np.asarray(b))
                        for a, b in zip(out, ref)):
                 print(f"bench_route_place: {name} differs from "
